@@ -14,9 +14,8 @@ reference, so CPU ratios document the harness, not the TPU win):
   wavefront    gather + repad + jnp anti-diagonal wavefront + mss_scores
                (the baseline ``score_pairs`` path)
   pallas       gather + repad + the blocked Pallas LCS kernel
-  fused        the gather-free fused kernel: scalar-prefetch gather from
-               the resident table, level-fused wavefront, in-block MSS
-               (``exact_mss=False``: the pure-throughput epilogue)
+  fused        the table-indexed kernel path: lane-dense gather from the
+               resident table, in-place repad, level-fused LCS kernel
   fused+prune  MSS upper-bound prune (compaction included in the timing)
                then fused scoring of the survivors only; pairs/sec still
                counts ALL P pairs — the prune win shows up as throughput
@@ -33,7 +32,7 @@ JSON schema (``schema: bench_score/v2``)::
         {"impl": "fused", "dispatch": "kernel" | "interpret" | "ref"
                           | "wavefront",
          "P": int, "H": int, "L": int, "prune_rate": float,
-         "tuned": false, "block_b": int | null,
+         "tuned": false,
          "wavefront_dtype": "int8" | "int32" | null,
          "wall_s": float, "pairs_per_sec": float, "repeats": int}, ...
       ],
@@ -41,8 +40,8 @@ JSON schema (``schema: bench_score/v2``)::
                  "pallas_vs_wavefront": {...}},
       "autotune": {   # tuned params vs library defaults, per tuned cell
         "cells": [{"P": ..., "H": ..., "L": ...,
-                   "default": {"block_b": 512, "wavefront_dtype": "..."},
-                   "tuned": {"block_b": ..., "wavefront_dtype": "..."},
+                   "default": {"wavefront_dtype": "..."},
+                   "tuned": {"wavefront_dtype": "..."},
                    "bit_identical": true, "tuned_vs_default": float}, ...]
       },
       "overlap": {    # shuffle-mode hop/score pipelining on vs off
@@ -58,10 +57,10 @@ JSON schema (``schema: bench_score/v2``)::
 The ``autotune`` section compares the :mod:`repro.perf` table winners
 (swept fresh by ``benchmarks.roofline.tune`` into a throwaway path)
 against the library's built-in defaults — every tuned cell is asserted
-bit-identical before its ratio is reported.  On CPU the default diagonal
-dtype is already int8 and ``block_b`` only reaches the Pallas kernel, so
-the ratio sits near 1.0 there; the section's CPU value is the end-to-end
-sweep -> table -> lookup -> dispatch proof, the ratios matter on TPU.
+bit-identical before its ratio is reported.  The only tuned parameter is
+the jnp wavefront's diagonal dtype, and the default is already int8, so
+the ratio sits near 1.0; the section's value is the end-to-end
+sweep -> table -> lookup -> dispatch proof.
 
 The ``overlap`` section measures the double-buffered owner-hop pipeline
 (``overlap_chunks``) of the sharded shuffle score path against the serial
@@ -73,6 +72,7 @@ zero steady-state recompiles.  Needs >= 2 devices — run under ``run.sh``
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -155,7 +155,8 @@ def _build_call(impl, codes, lengths, left, right, betas, tau):
             a = repad(codes[left], lengths[left], PAD_CODE_A)
             b = repad(codes[right], lengths[right], PAD_CODE_B)
             lv = lcs_ops.lcs(a.reshape(P * H, L), b.reshape(P * H, L),
-                             mode="pallas").reshape(P, H)
+                             mode="pallas" if on_tpu else "interpret",
+                             ).reshape(P, H)
             return mss_scores(lv, betas)
 
         return call, ("kernel" if on_tpu else "interpret")
@@ -164,7 +165,7 @@ def _build_call(impl, codes, lengths, left, right, betas, tau):
         @jax.jit
         def call():
             _, mss = fused_score(codes, lengths, codes, lengths, left, right,
-                                 betas, mode="auto", exact_mss=False)
+                                 betas, mode="auto")
             return mss
 
         return call, ("kernel" if on_tpu else "ref")
@@ -189,7 +190,7 @@ def _build_call(impl, codes, lengths, left, right, betas, tau):
             n_keep = jnp.minimum(jnp.sum(keep), cap)
             sl, sr = left[order][:cap], right[order][:cap]
             _, mss = fused_score(codes, lengths, codes, lengths, sl, sr,
-                                 betas, mode="auto", exact_mss=False)
+                                 betas, mode="auto")
             return jnp.where(jnp.arange(cap) < n_keep, mss, -1.0)
 
         return call, ("kernel" if on_tpu else "ref")
@@ -207,15 +208,14 @@ def _time_call(call, repeats):
     return (time.perf_counter() - t0) / repeats
 
 
-def _default_params(impl):
-    """The (block_b, wavefront_dtype) an UNTUNED row actually ran with."""
+def _default_dtype(impl):
+    """The wavefront_dtype an UNTUNED row actually ran with (None: the
+    row ran no jnp wavefront)."""
     from repro.core.similarity import wavefront_dtype_from_env
 
     if impl == "wavefront":
-        return None, np.dtype(wavefront_dtype_from_env()).name
-    if impl == "pallas":
-        return 512, None  # kernels/lcs/ops.lcs block_b default
-    return None, None     # fused paths tile internally
+        return np.dtype(wavefront_dtype_from_env()).name
+    return None
 
 
 def run_grid(grid, *, repeats=3, impls=IMPLS):
@@ -233,12 +233,11 @@ def run_grid(grid, *, repeats=3, impls=IMPLS):
                 impl, codes, lengths, left, right, betas, tau
             )
             wall = _time_call(call, repeats)
-            block_b, wf_dtype = _default_params(impl)
             rows.append({
                 "impl": impl, "dispatch": dispatch,
                 "P": P, "H": H, "L": L, "prune_rate": prune_rate,
-                "tuned": False, "block_b": block_b,
-                "wavefront_dtype": wf_dtype,
+                "tuned": False,
+                "wavefront_dtype": _default_dtype(impl),
                 "wall_s": wall, "pairs_per_sec": P / wall,
                 "repeats": repeats,
             })
@@ -311,26 +310,23 @@ def _bench_autotune(*, repeats=2):
             a = repad(codes[left], lengths[left], PAD_CODE_A)
             b = repad(codes[right], lengths[right], PAD_CODE_B)
             a, b = a.reshape(P * H, L), b.reshape(P * H, L)
-            default = jax.jit(lcs_ops.lcs)
+            default = jax.jit(functools.partial(lcs_ops.lcs, mode="wavefront"))
 
             tuned_dt = resolve_wavefront_dtype(t)
 
             @jax.jit
-            def tuned(a=a, b=b, t=t, dt=tuned_dt):
-                return lcs_ops.lcs(a, b, block_b=t.block_b,
-                                   wavefront_dtype=dt)
+            def tuned(a=a, b=b, dt=tuned_dt):
+                return lcs_ops.lcs(a, b, mode="wavefront", wavefront_dtype=dt)
 
             ident = bool(np.array_equal(np.asarray(default(a, b)),
                                         np.asarray(tuned())))
             assert ident, f"tuned params diverge at P={P} H={H} L={L}"
             w_def = _time_call(lambda: default(a, b), repeats)
             w_tun = _time_call(tuned, repeats)
-            dflt_bb, dflt_dt = 512, _default_params("wavefront")[1]
             cells.append({
                 "P": P, "H": H, "L": L,
-                "default": {"block_b": dflt_bb, "wavefront_dtype": dflt_dt},
-                "tuned": {"block_b": t.block_b,
-                          "wavefront_dtype": np.dtype(tuned_dt).name},
+                "default": {"wavefront_dtype": _default_dtype("wavefront")},
+                "tuned": {"wavefront_dtype": np.dtype(tuned_dt).name},
                 "bit_identical": ident,
                 "tuned_vs_default": round(w_def / w_tun, 3),
             })
@@ -509,7 +505,6 @@ def main():
             print(f"# {name} {tag}: {v}x")
     for c in report["autotune"]["cells"]:
         print(f"# autotune P={c['P']},H={c['H']},L={c['L']}: "
-              f"block_b={c['tuned']['block_b']} "
               f"dtype={c['tuned']['wavefront_dtype']} "
               f"tuned_vs_default={c['tuned_vs_default']}x "
               f"bit_identical={c['bit_identical']}")

@@ -1,54 +1,43 @@
 """Fig. 14 — scalability with worker count (paper: 1..20 nodes, 1M
-trajectories).  Here: the sharded engine on 1..8 virtual executors
-(subprocesses, since device count binds at jax init).  Speedup saturates
-as shuffle overhead grows — the paper's observed knee.
+trajectories).  Here: the sharded engine on meshes over the first 1, 2,
+4, ... of this process's devices, all in one process (a device belongs to
+one process, so workers cannot be child processes).  On the CPU, fake
+the devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+(``run.sh`` does this under ``JAX_PLATFORMS=cpu``).  Speedup saturates as
+shuffle overhead grows — the paper's observed knee.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import time
 
 from benchmarks.common import Row
 
-_CODE = r"""
-import time, jax
-from repro.api import AnotherMeEngine, EngineConfig, ExecutionPlan
-from repro.data import synthetic_setup
-
-N = int({N})
-n_shards = len(jax.devices())
-batch, forest = synthetic_setup(N, num_types=300, seed=0)
-engine = AnotherMeEngine(
-    forest, EngineConfig(community_mode="components"),
-    ExecutionPlan(n_shards=n_shards))
-engine.run(batch)                     # compile + plan + run once
-t0 = time.perf_counter()
-# warm end-to-end run: the shard_map runner and capacity plan are cached,
-# but host-side encode/key transfer/communities are included — this is the
-# wall time a user of engine.run sees (the paper also times end-to-end)
-engine.run(batch)
-print("TIME", time.perf_counter() - t0)
-"""
-
 
 def run(full: bool = False) -> list[Row]:
+    import jax
+
+    from repro.api import AnotherMeEngine, EngineConfig, ExecutionPlan
+    from repro.data import synthetic_setup
+
     n = 20_000 if full else 4_000
+    batch, forest = synthetic_setup(n, num_types=300, seed=0)
+    n_dev = len(jax.devices())
     rows = []
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for workers in (1, 2, 4, 8):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={workers}"
-        env["PYTHONPATH"] = os.path.join(repo, "src")
-        proc = subprocess.run(
-            [sys.executable, "-c", _CODE.format(N=n)],
-            capture_output=True, text=True, env=env, timeout=1800,
+    workers = 1
+    while workers <= n_dev:
+        engine = AnotherMeEngine(
+            forest, EngineConfig(community_mode="components"),
+            ExecutionPlan(n_shards=workers),
         )
-        if proc.returncode != 0:
-            rows.append(Row(f"fig14/anotherme/workers={workers}", -1,
-                            f"error:{proc.stderr[-120:]}"))
-            continue
-        t = float(proc.stdout.strip().split()[-1])
+        engine.run(batch)                 # compile + plan + run once
+        # warm end-to-end run: the shard_map runner and capacity plan are
+        # cached, but host-side encode/key transfer/communities are
+        # included — the wall time a user of engine.run sees (the paper
+        # also times end-to-end)
+        t0 = time.perf_counter()
+        engine.run(batch)
+        t = time.perf_counter() - t0
         rows.append(Row(f"fig14/anotherme/workers={workers}", t * 1e6,
-                        f"N={n}"))
+                        f"N={n};platform={jax.devices()[0].platform}"))
+        workers *= 2
     return rows

@@ -5,8 +5,7 @@ the analytic 6*N*D model.
 
 ``python -m benchmarks.roofline --tune`` additionally runs the LCS
 autotune sweep: for each (P, H, L) cell it measures every candidate
-``block_b`` x diagonal-dtype combination of the score-stage kernel,
-asserts each candidate's LCS matrix is bit-identical to the untuned
+diagonal dtype of the score stage's jnp wavefront, asserts each candidate's LCS matrix is bit-identical to the untuned
 default, and records the throughput winner into the
 :mod:`repro.perf` tuning table (``TUNING.json`` or
 ``$REPRO_TUNING_PATH``).  The engine consults that table when
@@ -101,18 +100,13 @@ def _tune_grid(smoke: bool):
 
 
 def tune(*, smoke=False, full=False, repeats=3, out_path=None):
-    """Sweep LCS kernel parameters and persist the winners.
+    """Sweep the wavefront's diagonal dtype and persist the winners.
 
     For every grid cell the sweep builds one synthetic score-stage
     workload (same generator as bench_score), computes the untuned
-    reference LCS matrix once, then measures every candidate:
-
-      block_b          batch-tile cap — only swept where the auto
-                       dispatch actually runs the Pallas kernel (TPU);
-                       on CPU the wavefront ignores it, so the default
-                       is kept rather than recording a meaningless win
-      wavefront_dtype  int8 vs int32 anti-diagonal carries (int8 only
-                       where L < 127, where the two are bit-identical)
+    reference LCS matrix once, then measures every candidate
+    ``wavefront_dtype``: int8 vs int32 anti-diagonal carries (int8 only
+    where L < 127, where the two are bit-identical).
 
     Every candidate's output is asserted ``np.array_equal`` to the
     reference BEFORE it may win — the table can never hold a tuning
@@ -124,7 +118,6 @@ def tune(*, smoke=False, full=False, repeats=3, out_path=None):
     import numpy as np
 
     from benchmarks.bench_score import _make_inputs, _time_call
-    from repro.core.compat import on_tpu
     from repro.core.encoding import PAD_CODE_A, PAD_CODE_B
     from repro.core.similarity import repad
     from repro.kernels.lcs import ops as lcs_ops
@@ -132,7 +125,6 @@ def tune(*, smoke=False, full=False, repeats=3, out_path=None):
 
     path = pathlib.Path(out_path) if out_path else tuning_path()
     table = TuningTable.load(path)
-    block_candidates = (128, 256, 512) if on_tpu() else (512,)
     results = []
     for P, H, L in _tune_grid(smoke and not full):
         codes, lengths, left, right, betas = _make_inputs(P, H, L)
@@ -141,27 +133,26 @@ def tune(*, smoke=False, full=False, repeats=3, out_path=None):
         ref = np.asarray(jax.jit(lcs_ops.lcs)(a, b))
         dtype_candidates = ("int8", "int32") if L < 127 else ("int32",)
         best = None
-        for bb in block_candidates:
-            for dt_name in dtype_candidates:
-                dt = jnp.int8 if dt_name == "int8" else jnp.int32
+        for dt_name in dtype_candidates:
+            dt = jnp.int8 if dt_name == "int8" else jnp.int32
 
-                @jax.jit
-                def call(a=a, b=b, bb=bb, dt=dt):
-                    return lcs_ops.lcs(a, b, block_b=bb, wavefront_dtype=dt)
+            @jax.jit
+            def call(a=a, b=b, dt=dt):
+                return lcs_ops.lcs(a, b, mode="wavefront",
+                                   wavefront_dtype=dt)
 
-                got = np.asarray(call())
-                if not np.array_equal(got, ref):
-                    raise AssertionError(
-                        f"candidate block_b={bb} dtype={dt_name} diverges "
-                        f"from the untuned default at P={P} H={H} L={L} — "
-                        "refusing to record it"
-                    )
-                wall = _time_call(call, repeats)
-                pps = P / wall
-                if best is None or pps > best[0]:
-                    best = (pps, bb, dt_name)
-        pps, bb, dt_name = best
-        winner = LCSTuning(block_b=bb, wavefront_dtype=dt_name,
+            got = np.asarray(call())
+            if not np.array_equal(got, ref):
+                raise AssertionError(
+                    f"candidate dtype={dt_name} diverges from the untuned "
+                    f"default at P={P} H={H} L={L} — refusing to record it"
+                )
+            wall = _time_call(call, repeats)
+            pps = P / wall
+            if best is None or pps > best[0]:
+                best = (pps, dt_name)
+        pps, dt_name = best
+        winner = LCSTuning(wavefront_dtype=dt_name,
                            pairs_per_sec=round(pps, 1))
         table.record(P, H, L, winner)
         results.append((P, H, L, winner))
@@ -189,8 +180,7 @@ def _main():
     path, results = tune(smoke=args.smoke, full=args.full,
                          repeats=args.repeats, out_path=args.out)
     for P, H, L, t in results:
-        print(f"P={P:<6d} H={H} L={L:<3d} -> block_b={t.block_b:<4d} "
-              f"dtype={t.wavefront_dtype:<5s} "
+        print(f"P={P:<6d} H={H} L={L:<3d} -> dtype={t.wavefront_dtype:<5s} "
               f"{t.pairs_per_sec:>12.0f} pairs/s")
     print(f"wrote {path}")
 

@@ -11,11 +11,13 @@
 #                    gather-heavy score stage.  Gated on file existence:
 #                    absent (as in the slim CI image) the run proceeds
 #                    on glibc, it is never an error.
-#   XLA_FLAGS        on CPU, fake an 8-device host platform so the
-#                    shard_map paths (sharded parity tests, the overlap
-#                    benchmark section) exercise real collectives.
-#                    An inherited XLA_FLAGS wins — real accelerators
-#                    must not be forced onto the host platform.
+#   XLA_FLAGS        under JAX_PLATFORMS=cpu, fake an 8-device host
+#                    platform so the shard_map paths (sharded parity
+#                    tests, the overlap benchmark section) exercise real
+#                    collectives.  An inherited XLA_FLAGS wins; without
+#                    JAX_PLATFORMS=cpu the run keeps the machine's own
+#                    devices (an accelerator is never forced onto the
+#                    host platform).
 #   REPRO_LCS_DTYPE  pinned (default int8) so the wavefront's diagonal
 #                    carry dtype is an explicit, recorded choice rather
 #                    than the env-probe default.  Inherited values win.
@@ -42,15 +44,11 @@ done
 # silence absl/XLA chatter that would interleave with benchmark output
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# fake 8 host devices unless XLA_FLAGS is already pinned or a non-CPU
-# platform is selected (never force host devices onto an accelerator)
-case "${JAX_PLATFORMS:-cpu}" in
-    cpu|"")
-        if [ -z "${XLA_FLAGS:-}" ]; then
-            export XLA_FLAGS="--xla_force_host_platform_device_count=8"
-        fi
-        ;;
-esac
+# on the CPU backend (JAX_PLATFORMS=cpu), fake 8 host devices unless
+# XLA_FLAGS is already pinned; any other platform keeps its own devices
+if [ "${JAX_PLATFORMS:-}" = "cpu" ] && [ -z "${XLA_FLAGS:-}" ]; then
+    export XLA_FLAGS="--xla_force_host_platform_device_count=8"
+fi
 
 export REPRO_LCS_DTYPE="${REPRO_LCS_DTYPE:-int8}"
 export PYTHONPATH="$PWD/src${PYTHONPATH:+:$PYTHONPATH}"

@@ -62,10 +62,8 @@ class EngineConfig:
     betas: tuple | None = None      # level weights; None -> uniform 1/n
     backend: str = "ssh"            # candidate backend registry name
     backend_options: Mapping | None = None  # kwargs for the backend factory
-    lcs_impl: str = "wavefront"     # "wavefront" | "ref" | "kernel" |
-    #                                 "pallas" | "pallas-interpret" |
-    #                                 "fused" | "fused-pallas" |
-    #                                 "fused-interpret"
+    lcs_impl: str = "wavefront"     # "wavefront" | "ref" | "fused" |
+    #                                 "fused-pallas" | "fused-interpret"
     score_prune: bool = False       # MSS upper-bound pruning before exact
     #                                 scoring (tau = rho); changes the
     #                                 scored buffer (hopeless pairs are

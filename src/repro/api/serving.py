@@ -61,8 +61,7 @@ from repro.api.sharded import (
 from repro.core import compat
 from repro.core.encoding import encode_codes
 from repro.core.similarity import (
-    PRUNE_EPS, mss_scores, mss_upper_bound, multi_level_lcs,
-    wavefront_dtype_from_env,
+    PRUNE_EPS, mss_upper_bound, score_indexed, wavefront_dtype_from_env,
 )
 from repro.core.types import PAD_ID, PAD_KEY, PAD_PLACE
 
@@ -244,7 +243,7 @@ def _merge_topk(rows2d, negs2d, *, k_cap):
 
 def _serve_score_block(
     codes_all, w_len, cand_row, cand_qid, q_places, rho_vec, active,
-    tables, *, plan, betas, fused_mode, impl, phys_of,
+    tables, *, plan, betas, impl, phys_of,
 ):
     """Shared per-device serving stage: encode queries, gate candidates
     by the per-round (query, world-shard) prune mask, score them off the
@@ -263,19 +262,9 @@ def _serve_score_block(
     row = jnp.where(valid & active[qsafe, shard], cand_row, PAD_ID)
     alive = row != PAD_ID
     ri = phys_of(jnp.where(alive, row, 0))
-    if fused_mode is not None:
-        from repro.kernels.lcs.fused import fused_score
-
-        _, mss = fused_score(
-            q_codes, q_len, codes_all, w_len, qsafe, ri, betas,
-            mode=fused_mode,
-        )
-    else:
-        lvl = multi_level_lcs(
-            q_codes[qsafe], q_len[qsafe], codes_all[ri], w_len[ri],
-            impl=impl,
-        )
-        mss = mss_scores(lvl, betas)
+    _, mss = score_indexed(
+        q_codes, q_len, codes_all, w_len, qsafe, ri, betas, impl=impl,
+    )
     mss = jnp.where(alive, mss, jnp.float32(NO_MATCH_MSS))
     return _local_topk(
         cand_qid, row, mss, q_cap=plan.q_cap, k_cap=plan.k_cap,
@@ -321,12 +310,11 @@ def make_query_score_pipeline(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.api.stages import FUSED_MODES, lcs_impl_fn
+    from repro.api.stages import lcs_impl_fn
 
     # resolved HERE, at the eager call boundary (wavefront_dtype_from_env
     # must never run inside a traced body)
-    fused_mode = FUSED_MODES.get(lcs_impl)
-    impl = None if fused_mode is not None else lcs_impl_fn(lcs_impl)
+    impl = lcs_impl_fn(lcs_impl)
 
     if mesh is None:
 
@@ -338,7 +326,7 @@ def make_query_score_pipeline(
             t_row, t_neg = _serve_score_block(
                 codes, w_len, cand_row, cand_qid, q_places, rho_vec,
                 active, tables, plan=plan, betas=betas,
-                fused_mode=fused_mode, impl=impl, phys_of=lambda g: g,
+                impl=impl, phys_of=lambda g: g,
             )
             m_row, m_neg = _merge_topk(
                 jnp.concatenate([t_row, prev_row], axis=1),
@@ -367,7 +355,7 @@ def make_query_score_pipeline(
         t_row, t_neg = _serve_score_block(
             codes_all, w_len, cand_row, cand_qid, q_places, rho_vec,
             active, tables, plan=plan, betas=betas,
-            fused_mode=fused_mode, impl=impl, phys_of=phys_of,
+            impl=impl, phys_of=phys_of,
         )
         g_row = jax.lax.all_gather(t_row, axis_name)  # [S, q_cap, k_cap]
         g_neg = jax.lax.all_gather(t_neg, axis_name)

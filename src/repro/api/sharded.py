@@ -47,9 +47,7 @@ import numpy as np
 from repro.core import compat
 from repro.core.encoding import encode_codes
 from repro.core.shingling import shingles_from_types
-from repro.core.similarity import (
-    PRUNE_EPS, mss_scores, mss_upper_bound, multi_level_lcs,
-)
+from repro.core.similarity import PRUNE_EPS, mss_upper_bound, score_indexed
 from repro.core.ssh import _runs, dedup_pairs, pairs_from_rows
 from repro.core.types import PAD_ID, PAD_KEY
 
@@ -460,12 +458,11 @@ def make_sharded_pipeline(
         regime.
 
     lcs_impl selects the scoring implementation exactly as on the
-    single-device path: "wavefront" / "ref" / "kernel" (auto Pallas) /
-    "pallas" (forced Pallas) / "pallas-interpret", plus the gather-free
-    fused family "fused" / "fused-pallas" / "fused-interpret" — the fused
-    kernel scores pairs straight out of the device-resident code table
-    ("replicate") or the hop-gathered operand stacks ("shuffle") with the
-    MSS epilogue fused in.
+    single-device path: "wavefront" / "ref", or the Pallas kernel through
+    "fused" (auto) / "fused-pallas" (forced) / "fused-interpret" — every impl
+    scores through ``similarity.score_indexed`` (chunked) against the
+    device-resident code table ("replicate") or the hop-gathered operand
+    stacks ("shuffle").
 
     score_prune runs the MSS upper-bound pruning pass IN-MESH, right after
     the pair dedup and before any code row moves for scoring: per-shard
@@ -508,15 +505,14 @@ def make_sharded_pipeline(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.api.stages import FUSED_MODES, lcs_impl_fn
+    from repro.api.stages import lcs_impl_fn
 
     n_shards = plan.n_shards
     if subtraj is not None:
         W, stride, nw = subtraj
     else:
         W, stride, nw = 0, 1, 1
-    fused_mode = FUSED_MODES.get(lcs_impl)
-    impl = None if fused_mode is not None else lcs_impl_fn(lcs_impl, tuning)
+    impl = lcs_impl_fn(lcs_impl, tuning)
     out_cap = (plan.pruned_cap or plan.scored_cap) if score_prune \
         else plan.scored_cap
     n_chunks = plan.n_chunks if score_mode == "shuffle" else 1
@@ -626,45 +622,7 @@ def make_sharded_pipeline(
         if score_mode == "replicate":
             # on-device replication of the in-mesh encodings (never on host)
             codes_all = jax.lax.all_gather(codes, axis_name, axis=0, tiled=True)
-            li = jnp.where(left == PAD_ID, 0, left)
-            ri = jnp.where(right == PAD_ID, 0, right)
-            if subtraj is not None:
-                # window ids -> (traj, offset); score the [H, W] slices
-                ta, oa = li // nw, (li % nw) * stride
-                tb, ob = ri // nw, (ri % nw) * stride
-                len_all = _lengths_of(codes_all)
-                if fused_mode is not None:
-                    from repro.kernels.lcs.fused import fused_windowed_score
-
-                    level_lcs, mss = fused_windowed_score(
-                        codes_all, len_all, codes_all, len_all,
-                        ta, tb, oa, ob, betas, window=W, mode=fused_mode,
-                    )
-                else:
-                    from repro.core.similarity import gather_windows
-
-                    level_lcs = multi_level_lcs(
-                        gather_windows(codes_all[ta], oa, W),
-                        jnp.clip(len_all[ta] - oa, 0, W),
-                        gather_windows(codes_all[tb], ob, W),
-                        jnp.clip(len_all[tb] - ob, 0, W),
-                        impl=impl,
-                    )
-                    mss = mss_scores(level_lcs, betas)
-            elif fused_mode is not None:
-                from repro.kernels.lcs.fused import fused_score
-
-                len_all = _lengths_of(codes_all)
-                level_lcs, mss = fused_score(
-                    codes_all, len_all, codes_all, len_all, li, ri, betas,
-                    mode=fused_mode,
-                )
-            else:
-                level_lcs = multi_level_lcs(
-                    codes_all[li], _lengths_of(codes_all[li]),
-                    codes_all[ri], _lengths_of(codes_all[ri]), impl=impl,
-                )
-                mss = mss_scores(level_lcs, betas)
+            level_lcs, mss = _score(codes_all, codes_all, left, right)
             ovf5 = jnp.zeros((), jnp.int32)
         elif n_chunks == 1:
             left, right, codes_l, codes_r, ovf5 = _gather_pair_codes(
@@ -715,48 +673,30 @@ def make_sharded_pipeline(
         # lengths reconstructed from the padding sentinel in level 0
         return jnp.sum(code_rows[:, 0, :] >= 0, axis=-1).astype(jnp.int32)
 
-    def _score_gathered(codes_l, codes_r, cap, left=None, right=None):
-        """Score one resting operand stack (post-hop) -> (level_lcs, mss).
-
-        The gather already happened via the owner hops, so the fused kernel
-        runs level-fused over the operand stacks via iota indices.  In
-        subtrajectory mode the hops moved FULL trajectory rows and the
-        resting ``left``/``right`` window ids decode each pair's window
-        offsets here, at the point of scoring.
-        """
+    def _score(table_l, table_r, left, right, rows_l=None, rows_r=None):
+        """Score pairs ``left``/``right`` (global ids: trajectories, or
+        windows in subtrajectory mode) against the code tables.  ``rows_*``
+        index each pair's trajectory row in its table (default: the id's
+        own trajectory).  Window ids decode to (traj, offset) here, at the
+        point of scoring; the owner hops always move full rows."""
+        li = jnp.where(left == PAD_ID, 0, left)
+        ri = jnp.where(right == PAD_ID, 0, right)
+        ta, tb = li // nw, ri // nw
+        window = {}
         if subtraj is not None:
-            oa = (jnp.where(left == PAD_ID, 0, left) % nw) * stride
-            ob = (jnp.where(right == PAD_ID, 0, right) % nw) * stride
-            la, lb = _lengths_of(codes_l), _lengths_of(codes_r)
-            if fused_mode is not None:
-                from repro.kernels.lcs.fused import fused_windowed_score
-
-                iota = jnp.arange(cap, dtype=jnp.int32)
-                return fused_windowed_score(
-                    codes_l, la, codes_r, lb, iota, iota, oa, ob, betas,
-                    window=W, mode=fused_mode,
-                )
-            from repro.core.similarity import gather_windows
-
-            lvl = multi_level_lcs(
-                gather_windows(codes_l, oa, W), jnp.clip(la - oa, 0, W),
-                gather_windows(codes_r, ob, W), jnp.clip(lb - ob, 0, W),
-                impl=impl,
-            )
-            return lvl, mss_scores(lvl, betas)
-        if fused_mode is not None:
-            from repro.kernels.lcs.fused import fused_score
-
-            iota = jnp.arange(cap, dtype=jnp.int32)
-            return fused_score(
-                codes_l, _lengths_of(codes_l), codes_r, _lengths_of(codes_r),
-                iota, iota, betas, mode=fused_mode,
-            )
-        lvl = multi_level_lcs(
-            codes_l, _lengths_of(codes_l), codes_r, _lengths_of(codes_r),
-            impl=impl,
+            window = dict(window=W, off_a=(li % nw) * stride,
+                          off_b=(ri % nw) * stride)
+        return score_indexed(
+            table_l, _lengths_of(table_l), table_r, _lengths_of(table_r),
+            ta if rows_l is None else rows_l,
+            tb if rows_r is None else rows_r, betas, impl=impl, **window,
         )
-        return lvl, mss_scores(lvl, betas)
+
+    def _score_gathered(codes_l, codes_r, cap, left, right):
+        """Score one resting operand stack (post-hop) -> (level_lcs, mss):
+        row i of each stack is pair i's trajectory row."""
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        return _score(codes_l, codes_r, left, right, iota, iota)
 
     def _gather_pair_codes(left, right, codes_local, gid0, plan, n, axis,
                            out_cap):
@@ -1186,11 +1126,10 @@ def make_streaming_score_pipeline(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.api.stages import FUSED_MODES, lcs_impl_fn
+    from repro.api.stages import lcs_impl_fn
 
     n_shards = plan.n_shards
-    fused_mode = FUSED_MODES.get(lcs_impl)
-    impl = None if fused_mode is not None else lcs_impl_fn(lcs_impl, tuning)
+    impl = lcs_impl_fn(lcs_impl, tuning)
     out_cap = plan.out_cap
     n_chunks = plan.n_chunks if score_mode == "shuffle" else 1
     if n_chunks > 1:
@@ -1210,19 +1149,11 @@ def make_streaming_score_pipeline(
 
     def _score_gathered(codes_l, codes_r, cap):
         """Score one resting operand stack (post-hop) -> (level_lcs, mss)."""
-        if fused_mode is not None:
-            from repro.kernels.lcs.fused import fused_score
-
-            iota = jnp.arange(cap, dtype=jnp.int32)
-            return fused_score(
-                codes_l, _lengths_of(codes_l), codes_r, _lengths_of(codes_r),
-                iota, iota, betas, mode=fused_mode,
-            )
-        lvl = multi_level_lcs(
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        return score_indexed(
             codes_l, _lengths_of(codes_l), codes_r, _lengths_of(codes_r),
-            impl=impl,
+            iota, iota, betas, impl=impl,
         )
-        return lvl, mss_scores(lvl, betas)
 
     def _phys(g, valid):
         # physical index of global id g in the round-robin world layout:
@@ -1248,20 +1179,11 @@ def make_streaming_score_pipeline(
                 n_pruned = (jnp.sum(valid) - jnp.sum(keep)).astype(jnp.int32)
                 left = jnp.where(keep, left, PAD_ID)
                 right = jnp.where(keep, right, PAD_ID)
-            if fused_mode is not None:
-                from repro.kernels.lcs.fused import fused_score
-
-                len_all = _lengths_of(codes_all)
-                level_lcs, mss = fused_score(
-                    codes_all, len_all, codes_all, len_all, li, ri, betas,
-                    mode=fused_mode,
-                )
-            else:
-                level_lcs = multi_level_lcs(
-                    codes_all[li], _lengths_of(codes_all[li]),
-                    codes_all[ri], _lengths_of(codes_all[ri]), impl=impl,
-                )
-                mss = mss_scores(level_lcs, betas)
+            len_all = _lengths_of(codes_all)
+            level_lcs, mss = score_indexed(
+                codes_all, len_all, codes_all, len_all, li, ri, betas,
+                impl=impl,
+            )
             out_l, out_r = left, right
             ovf = jnp.zeros((), jnp.int32)
         else:

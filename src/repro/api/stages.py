@@ -19,35 +19,16 @@ from repro.api.backends import BackendContext, CandidateBackend
 from repro.api.capacity import CapacityPlanner
 from repro.api.instrumentation import Instrumentation
 import repro.core.communities as comm
-from repro.core.encoding import (
-    PAD_CODE_A, PAD_CODE_B, SemanticForest, encode_batch,
-)
+from repro.core.encoding import SemanticForest, encode_batch
 from repro.core.similarity import (
-    PRUNE_EPS, mss_scores, mss_upper_bound, repad, score_pairs,
-    wavefront_dtype_from_env,
+    PRUNE_EPS, lcs_impl, mss_upper_bound, score_pairs, score_windowed_pairs,
 )
 from repro.core.ssh import ssh_candidates
 from repro.core.types import (
     CandidatePairs, EncodedBatch, PAD_ID, ScoredPairs, TrajectoryBatch,
 )
-from repro.kernels.lcs.fused import FUSED_IMPL_MODES
 
-LCS_IMPLS = (
-    "wavefront", "ref", "kernel", "pallas", "pallas-interpret",
-    "fused", "fused-pallas", "fused-interpret",
-)
-
-# kernel-family impls map to a dispatch mode of kernels/lcs/ops.py:
-#   "kernel"           auto (wavefront for tiny batches off-TPU)
-#   "pallas"           forced Pallas dispatch (interpret off-TPU)
-#   "pallas-interpret" forced Pallas dispatch, interpreter everywhere
-_KERNEL_MODES = {"kernel": "auto", "pallas": "pallas", "pallas-interpret": "interpret"}
-
-# fused-family impls map to a dispatch mode of kernels/lcs/fused.py: the
-# gather-free scalar-prefetch kernel that scores pairs straight out of the
-# resident code table (no [P, H, L] operand materialization).  The mapping
-# lives with the kernel (one place to add a variant); this is a re-export.
-FUSED_MODES = FUSED_IMPL_MODES
+LCS_IMPLS = ("wavefront", "ref", "fused", "fused-pallas", "fused-interpret")
 
 
 def validate_lcs_impl(name: str) -> str:
@@ -59,45 +40,25 @@ def validate_lcs_impl(name: str) -> str:
 
 
 def lcs_impl_fn(name: str, tuning=None):
-    """jax-traceable batched LCS ``(a [B,L], b [B,L]) -> [B]`` for an impl name.
+    """The scoring impl for an ``lcs_impl`` name (``similarity.lcs_impl``):
+    a kernel dispatch mode, or a jax-traceable batched LCS
+    ``(a [B,L], b [B,L]) -> [B]``.
 
-    Shared by the single-device score stage and the sharded shard_map score
-    stage, so ``lcs_impl`` selects the same implementation on both paths.
-    The fused family takes the code table plus pair indices rather than
-    gathered operands, so it has no pairwise form — callers route it through
-    ``kernels/lcs/fused.fused_score`` (see FUSED_MODES) instead.
+    Shared by the sharded, streaming and serving score blocks, so
+    ``lcs_impl`` selects the same implementation on every path.
 
     ``tuning`` is an optional :class:`repro.perf.LCSTuning` record (from
     ``CapacityPlanner.plan_tuning``), resolved HERE — at the call boundary,
-    eagerly, exactly like the REPRO_LCS_DTYPE probe — into static kernel
-    arguments (``block_b`` cap, wavefront dtype).  The returned closure
+    eagerly, exactly like the REPRO_LCS_DTYPE probe — into the static
+    wavefront dtype.  The returned impl
     carries only static values, so a tuned impl traces identically to an
     untuned one modulo those constants.
     """
-    validate_lcs_impl(name)
-    if name in FUSED_MODES:
-        raise ValueError(
-            f"lcs_impl {name!r} is table-indexed (gather-free); it has no "
-            "pairwise (a, b) form — dispatch through "
-            "repro.kernels.lcs.fused.fused_score"
-        )
-    if name in _KERNEL_MODES:
-        from repro.kernels.lcs import ops as lcs_ops
-        from repro.perf import resolve_wavefront_dtype
-
-        mode = _KERNEL_MODES[name]
-        dt = resolve_wavefront_dtype(tuning)  # env pin > tuned > default
-        kwargs = {} if tuning is None else {"block_b": tuning.block_b}
-        return lambda a, b: lcs_ops.lcs(
-            a, b, mode=mode, wavefront_dtype=dt, **kwargs
-        )
-    from repro.core.similarity import lcs_ref, lcs_wavefront
     from repro.perf import resolve_wavefront_dtype
 
-    if name == "ref":
-        return lcs_ref
-    dt = resolve_wavefront_dtype(tuning)
-    return lambda a, b: lcs_wavefront(a, b, dtype=dt)
+    validate_lcs_impl(name)
+    # env pin > tuned > default
+    return lcs_impl(name, resolve_wavefront_dtype(tuning))
 
 
 @dataclasses.dataclass
@@ -221,25 +182,26 @@ class ScoreStage:
         with ctx.instr.phase("score"):
             # tuning is consulted HERE — eager, outside any trace — and
             # becomes static kernel args; None keeps the untuned defaults
+            from repro.perf import resolve_wavefront_dtype
+
             P = int(cand.left.shape[0])
             H = int(ctx.encoded.codes.shape[1])
             tuning = ctx.planner.plan_tuning(P, H, L)
-            if subtraj is not None:
-                level_lcs, mss = _score_windowed(
-                    ctx.encoded, cand, ctx.betas, impl, subtraj, tuning
-                )
-            elif impl in _KERNEL_MODES:
-                level_lcs, mss = _score_with_kernel(
-                    ctx.encoded, cand, ctx.betas,
-                    mode=_KERNEL_MODES[impl], tuning=tuning,
-                )
-            else:
-                from repro.perf import resolve_wavefront_dtype
-
+            static = dict(
+                impl_name=impl,
+                wavefront_dtype=resolve_wavefront_dtype(tuning),
+            )
+            if subtraj is None:
                 level_lcs, mss = score_pairs(
                     ctx.encoded.codes, ctx.encoded.lengths,
-                    cand.left, cand.right, ctx.betas, impl_name=impl,
-                    wavefront_dtype=resolve_wavefront_dtype(tuning),
+                    cand.left, cand.right, ctx.betas, **static,
+                )
+            else:
+                W, stride, nw = subtraj
+                level_lcs, mss = score_windowed_pairs(
+                    ctx.encoded.codes, ctx.encoded.lengths,
+                    cand.left, cand.right, ctx.betas,
+                    nw=nw, window=W, stride=stride, **static,
                 )
             mss.block_until_ready()
 
@@ -370,75 +332,3 @@ def _subtraj_of(cfg, max_len: int):
         min(cfg.subtraj_window, max_len), cfg.subtraj_stride,
         num_windows(max_len, cfg.subtraj_window, cfg.subtraj_stride),
     )
-
-
-def _score_windowed(encoded, cand, betas, impl, subtraj, tuning):
-    """Windowed dispatch: pair ids are window ids; every impl family
-    scores the windowed [H, W] slices (fused masks in-kernel, the kernel
-    family slices via ``lcs_windowed``, jnp impls gather windows)."""
-    from repro.perf import resolve_wavefront_dtype
-
-    if impl in _KERNEL_MODES:
-        return _score_windowed_with_kernel(
-            encoded, cand, betas, subtraj=subtraj,
-            mode=_KERNEL_MODES[impl], tuning=tuning,
-        )
-    from repro.core.similarity import score_windowed_pairs
-
-    W, stride, nw = subtraj
-    return score_windowed_pairs(
-        encoded.codes, encoded.lengths, cand.left, cand.right, betas,
-        nw=nw, window=W, stride=stride, impl_name=impl,
-        wavefront_dtype=resolve_wavefront_dtype(tuning),
-    )
-
-
-def _score_windowed_with_kernel(encoded, cand, betas, *, subtraj,
-                                mode="auto", tuning=None):
-    """Windowed twin of :func:`_score_with_kernel`: decode (traj, offset)
-    from the window ids and run the batched kernel over the sliced
-    ``[P*H, W]`` windows (``kernels/lcs/ops.lcs_windowed``)."""
-    from repro.kernels.lcs import ops as lcs_ops
-    from repro.perf import resolve_wavefront_dtype
-
-    W, stride, nw = subtraj
-    li = jnp.where(cand.left == PAD_ID, 0, cand.left)
-    ri = jnp.where(cand.right == PAD_ID, 0, cand.right)
-    ta, tb = li // nw, ri // nw
-    oa = (li % nw).astype(jnp.int32) * stride
-    ob = (ri % nw).astype(jnp.int32) * stride
-    P = li.shape[0]
-    H, L = encoded.codes.shape[1], encoded.codes.shape[2]
-    rep = lambda x: jnp.repeat(x, H)
-    kwargs = {} if tuning is None else {"block_b": tuning.block_b}
-    level_lcs = lcs_ops.lcs_windowed(
-        encoded.codes[ta].reshape(P * H, L),
-        encoded.codes[tb].reshape(P * H, L),
-        rep(oa), rep(ob),
-        rep(encoded.lengths[ta]), rep(encoded.lengths[tb]),
-        window=W, mode=mode,
-        wavefront_dtype=resolve_wavefront_dtype(tuning), **kwargs,
-    ).reshape(P, H)
-    return level_lcs, mss_scores(level_lcs, betas)
-
-
-def _score_with_kernel(encoded, cand, betas, *, mode="auto", tuning=None):
-    """Score candidates with the Pallas LCS kernel (kernels/lcs).
-
-    ``tuning`` (an optional LCSTuning) supplies a tuned ``block_b`` cap and
-    wavefront dtype as static dispatch args; None keeps the defaults.
-    """
-    from repro.kernels.lcs import ops as lcs_ops
-    from repro.perf import resolve_wavefront_dtype
-
-    li = jnp.where(cand.left == PAD_ID, 0, cand.left)
-    ri = jnp.where(cand.right == PAD_ID, 0, cand.right)
-    P = li.shape[0]
-    H, L = encoded.codes.shape[1], encoded.codes.shape[2]
-    a = repad(encoded.codes[li], encoded.lengths[li], PAD_CODE_A).reshape(P * H, L)
-    b = repad(encoded.codes[ri], encoded.lengths[ri], PAD_CODE_B).reshape(P * H, L)
-    kwargs = {} if tuning is None else {"block_b": tuning.block_b}
-    level_lcs = lcs_ops.lcs(
-        a, b, mode=mode, wavefront_dtype=resolve_wavefront_dtype(tuning), **kwargs
-    ).reshape(P, H)
-    return level_lcs, mss_scores(level_lcs, betas)

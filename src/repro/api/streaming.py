@@ -49,9 +49,11 @@ over the concatenation, on the single-device and sharded paths alike.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -63,7 +65,6 @@ from repro.api.sharded import (
     make_streaming_score_pipeline, plan_stream_capacities, plan_stream_join,
     sticky_join_plan,
 )
-from repro.api.stages import _KERNEL_MODES, _score_with_kernel
 from repro.core import communities as comm
 from repro.core.device_index import (
     ShardSummaries, StreamJoinStats, compact_slab, mark_dead_rows,
@@ -765,6 +766,13 @@ class StreamingEngine:
             self._roll_single_jit = roll
         return self._roll_single_jit
 
+    def _world_sharding(self):
+        """Row-sharded placement of the round-robin places slab: shard s
+        holds physical rows ``[s * cap_local, (s + 1) * cap_local)``."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return NamedSharding(self._eng.mesh(), P(self.plan.axis_name, None))
+
     def _roll_sharded_runner(self):
         import jax
 
@@ -772,7 +780,7 @@ class StreamingEngine:
 
         if not hasattr(self, "_roll_sharded_jit"):
 
-            @jax.jit
+            @functools.partial(jax.jit, out_shardings=self._world_sharding())
             def roll(places, shift_local):
                 cap, L = places.shape
                 cl = cap // n_sh
@@ -868,7 +876,7 @@ class StreamingEngine:
                 # local ids preserve the global round-robin owner: base is
                 # a multiple of n_shards, so g % n_sh == (g + base) % n_sh
                 phys[(g % n_sh) * cl + g // n_sh] = self._places_np[:span]
-                self._places_dev = jnp.asarray(phys)
+                self._places_dev = jax.device_put(phys, self._world_sharding())
                 self._xfer["bytes_in"] += phys.nbytes
             else:
                 g = np.arange(n0l, n0l + a_cap, dtype=np.int64)
@@ -904,7 +912,7 @@ class StreamingEngine:
 
         if not hasattr(self, "_append_sharded_jit"):
 
-            @jax.jit
+            @functools.partial(jax.jit, out_shardings=self._world_sharding())
             def append(places_dev, new_places, idx):
                 return places_dev.at[idx].set(new_places, mode="drop")
 
@@ -975,27 +983,13 @@ class StreamingEngine:
         self._xfer["bytes_in"] += left_l.nbytes + right_l.nbytes
         jl, jr = jnp.asarray(left_l), jnp.asarray(right_l)
         tuning = self.planner.plan_tuning(p_cap, self._H, self.L)
-        if impl in _KERNEL_MODES:
-            from repro.core.types import CandidatePairs
+        from repro.perf import resolve_wavefront_dtype
 
-            enc = EncodedBatch(codes=self._codes_dev, lengths=self._len_dev)
-            cand = CandidatePairs(
-                left=jl, right=jr,
-                count=jnp.asarray(lo.shape[0], jnp.int32),
-                overflow=jnp.asarray(0, jnp.int32),
-            )
-            lvl, mss = _score_with_kernel(
-                enc, cand, self.betas, mode=_KERNEL_MODES[impl],
-                tuning=tuning,
-            )
-        else:
-            from repro.perf import resolve_wavefront_dtype
-
-            lvl, mss = score_pairs(
-                self._codes_dev, self._len_dev, jl, jr, self.betas,
-                impl_name=impl,
-                wavefront_dtype=resolve_wavefront_dtype(tuning),
-            )
+        lvl, mss = score_pairs(
+            self._codes_dev, self._len_dev, jl, jr, self.betas,
+            impl_name=impl,
+            wavefront_dtype=resolve_wavefront_dtype(tuning),
+        )
         k = lo.shape[0]
         return (left[:k], right[:k], np.asarray(lvl)[:k],
                 np.asarray(mss)[:k])

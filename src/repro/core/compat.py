@@ -1,12 +1,12 @@
-"""jax version compatibility (0.4.x .. 0.6+) for meshes and shard_map.
+"""Platform probes and the mesh / shard_map constructors.
 
-The repo targets the current jax API (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh`` with ``axis_types``); older versions spell these
-``jax.experimental.shard_map.shard_map(check_rep=...)`` and have no
-``axis_types``/``AxisType``.  Every mesh/shard_map construction in the repo
-goes through these two helpers so the whole pipeline runs on either API.
+Every mesh/shard_map construction in the repo goes through these helpers,
+and every platform-dependent choice (kernel dispatch, score chunk sizes,
+the tuning table) reads the platform through them.
 """
 from __future__ import annotations
+
+import os
 
 import jax
 
@@ -28,25 +28,28 @@ def on_tpu() -> bool:
     return backend_name() == "tpu"
 
 
+def device_memory_bytes() -> int:
+    """Memory of one local device: the accelerator's allocator limit, or
+    the host's physical memory for the CPU backend (which reports none)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+
+
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = list(devices)
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs,
+    )
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` without replication/VMA checking, any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` without replication/VMA checking."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )

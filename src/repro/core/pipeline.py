@@ -45,8 +45,8 @@ class AnotherMeConfig:
     k: int = 3                      # shingle order (paper default 3)
     rho: float = 2.0                # similarity threshold (paper default 2)
     betas: tuple | None = None      # level weights; None -> uniform 1/n
-    lcs_impl: str = "wavefront"     # "wavefront" | "ref" | "kernel" |
-    #                                 "pallas" | "pallas-interpret"
+    lcs_impl: str = "wavefront"     # "wavefront" | "ref" | "fused" |
+    #                                 "fused-pallas" | "fused-interpret"
     pair_capacity: int | None = None  # None -> plan from exact join size
     capacity_slack: float = 1.10
     community_mode: str = "cliques"  # "cliques" | "components"

@@ -175,63 +175,6 @@ def gather_windows(codes: jnp.ndarray, off: jnp.ndarray, window: int) -> jnp.nda
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("nw", "window", "stride", "impl_name", "wavefront_dtype"),
-)
-def score_windowed_pairs(
-    codes: jnp.ndarray,
-    lengths: jnp.ndarray,
-    left: jnp.ndarray,
-    right: jnp.ndarray,
-    betas: jnp.ndarray,
-    *,
-    nw: int,
-    window: int,
-    stride: int = 1,
-    impl_name: str = "wavefront",
-    wavefront_dtype: jnp.dtype | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Windowed ``score_pairs``: pair ids are WINDOW ids, not row ids.
-
-    codes [N, H, L], lengths [N], left/right [P] global window ids
-    (``traj = w // nw``, ``offset = (w % nw) * stride``) -> (level_lcs
-    [P, H], mss [P]) of the windowed slices.  The fused impls route to the
-    offset-aware fused kernel (the slices never materialize); the jnp
-    impls gather the [P, H, W] windows and reuse the batched LCS over
-    length-W rows (2W-1 wavefront steps instead of 2L-1).
-    """
-    from repro.core.types import PAD_ID
-
-    li = jnp.where(left == PAD_ID, 0, left)
-    ri = jnp.where(right == PAD_ID, 0, right)
-    ta, tb = li // nw, ri // nw
-    oa = (li % nw).astype(jnp.int32) * stride
-    ob = (ri % nw).astype(jnp.int32) * stride
-    if impl_name.startswith("fused"):
-        from repro.kernels.lcs import fused
-
-        mode = fused.FUSED_IMPL_MODES[impl_name]
-        return fused.fused_windowed_score(
-            codes, lengths, codes, lengths, ta, tb, oa, ob, betas,
-            window=window, mode=mode,
-        )
-    L = codes.shape[-1]
-    W = min(window, L)
-    wla = jnp.clip(lengths[ta] - oa, 0, W)
-    wlb = jnp.clip(lengths[tb] - ob, 0, W)
-    if impl_name == "wavefront":
-        dt = jnp.int8 if wavefront_dtype is None else wavefront_dtype
-        impl = functools.partial(lcs_wavefront, dtype=dt)
-    else:
-        impl = {"ref": lcs_ref}[impl_name]
-    lv = multi_level_lcs(
-        gather_windows(codes[ta], oa, window), wla,
-        gather_windows(codes[tb], ob, window), wlb, impl=impl,
-    )
-    return lv, mss_scores(lv, betas)
-
-
 def mss_scores(level_lcs: jnp.ndarray, betas: jnp.ndarray) -> jnp.ndarray:
     """MSS = sum_h beta_h * |M_h| (Definition 4). level_lcs [P, H] -> [P]."""
     return jnp.einsum("ph,h->p", level_lcs.astype(jnp.float32), betas)
@@ -265,6 +208,129 @@ def mss_upper_bound(len_a, len_b, betas_sum):
 PRUNE_EPS = 1e-5
 
 
+def lcs_impl(name: str, wavefront_dtype=None):
+    """The scoring impl for an ``lcs_impl`` name, as :func:`score_indexed`
+    takes it: a kernel dispatch mode (a string, see
+    ``kernels/lcs/fused.FUSED_IMPL_MODES``), or a pairwise LCS callable
+    ``(a [B, L], b [B, L]) -> [B]`` for "ref" and "wavefront".
+
+    ``wavefront_dtype`` must be resolved eagerly by the caller
+    (REPRO_LCS_DTYPE, the autotune table); None keeps the default.
+    """
+    from repro.kernels.lcs.fused import FUSED_IMPL_MODES
+
+    if name in FUSED_IMPL_MODES:
+        return FUSED_IMPL_MODES[name]
+    if name == "ref":
+        return lcs_ref
+    if name != "wavefront":
+        raise ValueError(f"unknown lcs_impl {name!r}")
+    dt = jnp.int8 if wavefront_dtype is None else wavefront_dtype
+    return functools.partial(lcs_wavefront, dtype=dt)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def score_chunk(H: int, L: int, *, lane_dense: bool) -> int:
+    """Pairs per scoring chunk: the largest power of two (at least 1,024)
+    whose score temporaries fit an eighth of one device's memory.
+
+    Bytes per pair are bounded as if every gathered row were padded to
+    whole 128-lane int32 tiles.  The row path holds ``[P*H, L]`` operands
+    and their repadded copies (4 rows of H codes per pair); the lane-dense
+    kernel path (kernels/lcs/fused.py) holds one ``[H*L]`` gathered row per
+    side plus three dense ``[H, L, P]`` operand copies.  The result
+    depends only on the shapes and the device, so a compiled shape always
+    has the same chunk count.
+    """
+    from repro.core.compat import device_memory_bytes
+
+    if lane_dense:
+        per_pair = 2 * (_round_up(H * L, 128) + 3 * H * L) * 4
+    else:
+        per_pair = 4 * H * _round_up(L, 128) * 4
+    fit = device_memory_bytes() // 8 // per_pair
+    return 1 << max(10, fit.bit_length() - 1)
+
+
+def _in_chunks(fn, chunk: int, *xs):
+    """``fn`` over the leading axis of ``xs`` in static chunks of ``chunk``.
+
+    ``fn`` maps ``[c]`` inputs to a ``[H, c]`` output, pairs on the minor
+    axis (a small minor axis, ``[c, H]``, would be padded to whole TPU
+    tiles).  Each chunk's output is written in place into one ``[H, P]``
+    buffer, returned as ``[P, H]``.  The last chunk starts at
+    ``P - chunk`` and rescores a few pairs of its predecessor, so no input
+    is padded and no output is sliced.
+    """
+    P = xs[0].shape[0]
+    if P <= chunk:
+        return fn(*xs).T
+    part = jax.eval_shape(fn, *(x[:chunk] for x in xs))
+
+    def body(i, out):
+        start = jnp.minimum(i * chunk, P - chunk)
+        lvl = fn(*(jax.lax.dynamic_slice_in_dim(x, start, chunk) for x in xs))
+        return jax.lax.dynamic_update_slice_in_dim(out, lvl, start, axis=1)
+
+    out = jnp.zeros((part.shape[0], P), part.dtype)
+    return jax.lax.fori_loop(0, -(-P // chunk), body, out).T
+
+
+def score_indexed(table_a, len_a, table_b, len_b, left, right, betas, *,
+                  impl, window: int | None = None, off_a=None, off_b=None):
+    """(level_lcs [P, H], mss [P]) of the pairs ``(table_a[left],
+    table_b[right])`` — the one scoring block every path goes through.
+
+    table_* [N, H, L] code rows, len_* [N], left/right [P] row indices
+    (valid: callers clamp PAD_ID), ``impl`` from :func:`lcs_impl`.  With
+    ``window`` the pairs are (row, offset) window coordinates: each side
+    scores ``rows[:, off : off + clip(len - off, 0, W)]``,
+    ``W = min(window, L)``.
+
+    Scoring runs in chunks of :func:`score_chunk` pairs, so any pair count
+    fits the device; the MSS is taken once over the whole ``level_lcs``.
+    """
+    from repro.core.compat import on_tpu
+    from repro.kernels.lcs import fused
+
+    H, L = table_a.shape[1], table_a.shape[2]
+    if off_a is None:
+        off_a = off_b = jnp.zeros_like(left)
+    W = None if window is None else min(window, L)
+
+    if isinstance(impl, str):
+        lane_dense = impl != "ref" and (impl != "auto" or on_tpu())
+
+        def chunk_lcs(ia, ib, oa, ob):
+            if W is None:
+                return fused.fused_score(table_a, len_a, table_b, len_b,
+                                         ia, ib, betas, mode=impl)[0].T
+            return fused.fused_windowed_score(
+                table_a, len_a, table_b, len_b, ia, ib, oa, ob, betas,
+                window=W, mode=impl,
+            )[0].T
+    else:
+        lane_dense = False
+
+        def chunk_lcs(ia, ib, oa, ob):
+            if W is None:
+                return multi_level_lcs(table_a[ia], len_a[ia], table_b[ib],
+                                       len_b[ib], impl=impl).T
+            return multi_level_lcs(
+                gather_windows(table_a[ia], oa, W),
+                jnp.clip(len_a[ia] - oa, 0, W),
+                gather_windows(table_b[ib], ob, W),
+                jnp.clip(len_b[ib] - ob, 0, W), impl=impl,
+            ).T
+
+    chunk = score_chunk(H, L, lane_dense=lane_dense)
+    lvl = _in_chunks(chunk_lcs, chunk, left, right, off_a, off_b)
+    return lvl, mss_scores(lvl, betas)
+
+
 @functools.partial(jax.jit, static_argnames=("impl_name", "wavefront_dtype"))
 def score_pairs(
     codes: jnp.ndarray,
@@ -279,27 +345,53 @@ def score_pairs(
 
     codes [N, H, L], lengths [N], left/right [P] -> (level_lcs [P, H], mss [P]).
     Invalid slots (PAD_ID) are clamped to row 0; callers mask by pair validity.
-
-    ``impl_name="fused"`` (and the forced "fused-pallas"/"fused-interpret"
-    variants) routes to the gather-free fused Pallas kernel
-    (kernels/lcs/fused.py), which never materializes the [P, H, L] operand
-    copies this gather path builds.
+    Any ``lcs_impl`` name works (see :func:`lcs_impl`); the fused names
+    score on the Pallas kernel over lane-dense gathered operands
+    (kernels/lcs/fused.py).
     """
     from repro.core.types import PAD_ID
 
     li = jnp.where(left == PAD_ID, 0, left)
     ri = jnp.where(right == PAD_ID, 0, right)
-    if impl_name.startswith("fused"):
-        from repro.kernels.lcs import fused
+    return score_indexed(
+        codes, lengths, codes, lengths, li, ri, betas,
+        impl=lcs_impl(impl_name, wavefront_dtype),
+    )
 
-        mode = fused.FUSED_IMPL_MODES[impl_name]
-        return fused.fused_score(
-            codes, lengths, codes, lengths, li, ri, betas, mode=mode
-        )
-    if impl_name == "wavefront":
-        dt = jnp.int8 if wavefront_dtype is None else wavefront_dtype
-        impl = functools.partial(lcs_wavefront, dtype=dt)
-    else:
-        impl = {"ref": lcs_ref}[impl_name]
-    lv = multi_level_lcs(codes[li], lengths[li], codes[ri], lengths[ri], impl=impl)
-    return lv, mss_scores(lv, betas)
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("nw", "window", "stride", "impl_name", "wavefront_dtype"),
+)
+def score_windowed_pairs(
+    codes: jnp.ndarray,
+    lengths: jnp.ndarray,
+    left: jnp.ndarray,
+    right: jnp.ndarray,
+    betas: jnp.ndarray,
+    *,
+    nw: int,
+    window: int,
+    stride: int = 1,
+    impl_name: str = "wavefront",
+    wavefront_dtype: jnp.dtype | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Windowed ``score_pairs``: pair ids are WINDOW ids, not row ids.
+
+    codes [N, H, L], lengths [N], left/right [P] global window ids
+    (``traj = w // nw``, ``offset = (w % nw) * stride``) -> (level_lcs
+    [P, H], mss [P]) of the windowed slices.  The fused impls mask the
+    windows in place; the row impls gather the [P, H, W] windows and run
+    the batched LCS over length-W rows (2W-1 wavefront steps instead of
+    2L-1).
+    """
+    from repro.core.types import PAD_ID
+
+    li = jnp.where(left == PAD_ID, 0, left)
+    ri = jnp.where(right == PAD_ID, 0, right)
+    return score_indexed(
+        codes, lengths, codes, lengths, li // nw, ri // nw, betas,
+        impl=lcs_impl(impl_name, wavefront_dtype), window=window,
+        off_a=(li % nw).astype(jnp.int32) * stride,
+        off_b=(ri % nw).astype(jnp.int32) * stride,
+    )

@@ -1,41 +1,33 @@
-"""Fused gather-and-score Pallas TPU kernel: table -> (level_lcs, MSS).
+"""Table-indexed scoring on the LCS kernel: table + pair ids -> (level_lcs, MSS).
 
 The hot path of the pipeline is exact pair scoring: for every surviving
 candidate pair (l, r), the LCS of the two trajectories' encodings at every
-semantic level, beta-combined into the MSS (paper section IV.3).  The
-baseline path (``score_pairs`` -> ``multi_level_lcs``) first materializes
-TWO full ``[P, H, L]`` gathered-and-repadded operand copies in HBM before
-any kernel runs, so scoring is memory-bound long before it is compute-bound.
+semantic level, beta-combined into the MSS (paper section IV.3).
 
-This kernel makes scoring gather-free and level-fused:
+The wrappers here take the resident code table(s) and the pair indices
+directly and build the kernel's lane-dense operands in one XLA pass:
 
-* **Scalar-prefetched gather** — the pair index arrays ``left/right [P]``
-  (plus the length tables) ride in SMEM via
-  ``pltpu.PrefetchScalarGridSpec``; the operand BlockSpec index maps read
-  ``left[p]`` / ``right[p]`` so grid block ``p`` DMAs its own two
-  ``[H, L]`` rows straight out of the resident code table.  The gathered
-  ``[P, H, L]`` copies never exist in HBM, and the grid pipeline overlaps
-  each block's row DMA with the previous block's wavefront.
-* **In-register repad** — rows arrive with whatever padding the table
-  carries; the kernel masks positions ``>= length`` to the standard
-  sentinels (side A: -1, side B: -2, exactly ``similarity.repad``) in
-  VREGs, so the host-side repad round trip disappears too.
-* **Level fusion** — all H levels of a pair run through the rolling-window
-  wavefront (see kernels/lcs/kernel.py for the window scheme) in ONE block
-  as an [H, L+1] tile, with the two rolling diagonals carried in int8
-  (LCS <= L < 127).
-* **Fused MSS** — the block emits ``level_lcs [1, H]`` AND the
-  beta-weighted ``mss [1, 1]`` (``sum_h beta_h * |M_h|``), fusing
-  ``mss_scores`` into the kernel epilogue.  The in-block float32 sum can
-  differ from the XLA lowering of ``mss_scores`` by 1 ulp (XLA may
-  FMA-contract the batched multiply+reduce), so the dispatch wrapper
-  recomputes the authoritative ``mss`` from the integer ``level_lcs``
-  through ``mss_scores`` itself by default (``exact_mss=True``) — an O(PH)
-  epilogue that keeps every ``lcs_impl`` bit-identical — and returns the
-  kernel's own epilogue with ``exact_mss=False`` (the pure-throughput
-  path, e.g. benchmarking).
+* **Lane-dense gather** — each pair's ``[H, L]`` rows are gathered as one
+  flat ``[H*L]`` row, and the gathered block is transposed to
+  ``[H, L, P]`` so pairs sit on the vector lanes (kernels/lcs/kernel.py).
+  Gathering ``[P, H, L]`` instead would pad every pair's ``(H, L)`` minor
+  tile to a full TPU tile, many times its logical size.
+* **Repad and windowing in the same pass** — positions ``>= length``
+  become the side sentinels (side A: -1, side B: -2, exactly
+  ``similarity.repad``); the windowed variant keeps only
+  ``[off, off + clip(len - off, 0, W))``.  Sentinels never match, so the
+  masked full-row LCS IS the windowed LCS, and the windowed slices are
+  never moved to the front of the row.
+* **Level fusion** — all H levels of a pair run in one kernel call.
 
-Two tables are taken (``table_a``/``table_b``) so the same kernel serves
+The MSS is ``similarity.mss_scores`` over the integer ``level_lcs``, the
+same lowering every other ``lcs_impl`` uses, so scores are bit-identical
+across impls.  Callers bound the pair count per call
+(``similarity.score_indexed`` scores in chunks), which bounds the gathered
+operands; nothing is held in SMEM, so no table or pair count is limited by
+it.
+
+Two tables are taken (``table_a``/``table_b``) so the same wrappers serve
 both sharded score modes: "replicate" passes the all_gathered code table
 twice with real pair indices, "shuffle" passes the two per-shard gathered
 operand stacks with iota indices (the gather there already happened via
@@ -47,16 +39,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.compat import on_tpu as _on_tpu
 from repro.core.encoding import PAD_CODE_A, PAD_CODE_B
-from repro.kernels.lcs.kernel import SENT_SHIFT, SENT_WINDOW
+from repro.kernels.lcs.kernel import lcs_lanes
 
-# the canonical lcs_impl-name -> dispatch-mode mapping for the fused family;
-# every registration point (stages, score_pairs, the sharded pipeline)
-# imports THIS dict so a new variant is added in exactly one place
+# the canonical lcs_impl-name -> dispatch-mode mapping for the names that
+# score on the Pallas kernel; every registration point (stages,
+# score_pairs, the sharded pipeline) reads THIS dict
 FUSED_IMPL_MODES = {
     "fused": "auto",
     "fused-pallas": "pallas",
@@ -66,216 +56,81 @@ FUSED_IMPL_MODES = {
 _DISPATCH_MODES = ("auto", "pallas", "interpret", "ref")
 
 
-def _masked_rows_lcs(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """In-block multi-level LCS of sentinel-masked [H, L] rows -> [H] int8.
+def kernel_interpret(mode: str) -> bool:
+    """Whether a forced kernel mode runs interpreted.
 
-    Rolling-window wavefront over all H levels at once (kernel.py scheme),
-    diagonals carried in int8 (LCS values <= L < 127).  The DP is position
-    agnostic: any masked-out entry (the side sentinels never equal each
-    other or a valid code) simply cannot contribute a match, so the LCS of
-    masked full rows equals the LCS of the surviving subsequences — which
-    is what lets the windowed kernel score a mid-row slice without moving
-    it to the front.
+    "pallas" is the compiled kernel and refuses to run off the TPU rather
+    than quietly falling back to the interpreter; "interpret" is the
+    interpreter everywhere.
     """
-    H, L = a.shape
-    a_ext = jnp.concatenate(
-        [jnp.full((H, 1), SENT_SHIFT, jnp.int32), a], axis=1
-    )
-    window = jnp.concatenate(
-        [
-            jnp.full((H, L), SENT_WINDOW, jnp.int32),
-            b[:, ::-1],
-            jnp.full((H, L - 1), SENT_WINDOW, jnp.int32),
-        ],
-        axis=1,
-    )
-    window = jnp.roll(window, -(2 * L - 2), axis=1)
-    zeros = jnp.zeros((H, L + 1), jnp.int8)
-
-    def shift_right(x):
-        return jnp.concatenate([jnp.zeros((H, 1), jnp.int8), x[:, :-1]], axis=1)
-
-    def step(_, carry):
-        d2, d1, win = carry
-        match = a_ext == win[:, : L + 1]
-        new = jnp.where(
-            match, shift_right(d2) + jnp.ones((), jnp.int8),
-            jnp.maximum(d1, shift_right(d1)),
+    if mode == "interpret":
+        return True
+    if not _on_tpu():
+        raise RuntimeError(
+            f"LCS kernel mode {mode!r} compiles for the TPU and the default "
+            "backend is not one; use the '-interpret' variant off the TPU"
         )
-        return d1, new, jnp.roll(win, 1, axis=1)
-
-    _, d1, _ = jax.lax.fori_loop(0, 2 * L - 1, step, (zeros, zeros, window))
-    return d1[:, L]  # dp[L, L] per level
+    return False
 
 
-def _fused_kernel(li_ref, ri_ref, lena_ref, lenb_ref,
-                  a_ref, b_ref, betas_ref, lvl_ref, mss_ref):
-    p = pl.program_id(0)
-    la = lena_ref[li_ref[p]]
-    lb = lenb_ref[ri_ref[p]]
-    a = a_ref[0]  # [H, L] int32 — our pair's left row, DMA'd by index map
-    b = b_ref[0]
-    H, L = a.shape
-
-    # in-register repad: positions >= length become the side sentinels
-    pos = jax.lax.broadcasted_iota(jnp.int32, (H, L), 1)
-    a = jnp.where(pos < la, a, PAD_CODE_A)
-    b = jnp.where(pos < lb, b, PAD_CODE_B)
-
-    lvl = _masked_rows_lcs(a, b).astype(jnp.int32)
-    lvl_ref[0, :] = lvl
-    # fused mss_scores epilogue: sum_h beta_h * |M_h| in float32
-    mss_ref[0, 0] = jnp.sum(lvl.astype(jnp.float32) * betas_ref[0])
+def _lane_operand(table, lengths, idx, pad_code, off=None, window=None):
+    """Gather ``table[idx]`` as masked lane-dense operands [H, L, P]."""
+    n, H, L = table.shape
+    rows = table.reshape(n, H * L)[idx]          # [P, H*L]: one row per pair
+    x = rows.T.reshape(H, L, idx.shape[0])       # pairs on the minor axis
+    pos = jnp.arange(L, dtype=jnp.int32)[None, :, None]
+    length = lengths[idx][None, None, :]
+    if off is None:
+        keep = pos < length
+    else:
+        o = off.astype(jnp.int32)[None, None, :]
+        keep = (pos >= o) & (pos < o + jnp.clip(length - o, 0, window))
+    return jnp.where(keep, x, pad_code)
 
 
-def _fused_windowed_kernel(li_ref, ri_ref, lena_ref, lenb_ref,
-                           offa_ref, offb_ref, a_ref, b_ref, betas_ref,
-                           lvl_ref, mss_ref, *, window):
-    """Subtrajectory variant: the scalar-prefetch tuple grows from
-    ``(left, right, len_a, len_b)`` to include per-side window offsets.
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _gather_score(table_a, len_a, table_b, len_b, left, right, off_a, off_b,
+                  betas, *, window, interpret):
+    from repro.core.similarity import mss_scores
 
-    BlockSpec index maps are block granular, so the windowed [H, W] slice
-    cannot be DMA'd at an element offset directly — instead the block DMAs
-    its pair's full [H, L] rows (same traffic as the whole-trajectory
-    kernel) and masks everything OUTSIDE ``[off, off + wlen)`` to the side
-    sentinels in VREGs.  Sentinels never match, so the masked full-row LCS
-    IS the windowed LCS (see :func:`_masked_rows_lcs`), the wavefront
-    stays 2L-1 steps, and the gathered windowed operand copies never
-    exist in HBM.
-    """
-    p = pl.program_id(0)
-    la = lena_ref[li_ref[p]]
-    lb = lenb_ref[ri_ref[p]]
-    oa = offa_ref[p]
-    ob = offb_ref[p]
-    a = a_ref[0]
-    b = b_ref[0]
-    H, L = a.shape
-    # window lengths in-kernel: clip(len - off, 0, W) with W static
-    wla = jnp.clip(la - oa, 0, window)
-    wlb = jnp.clip(lb - ob, 0, window)
-
-    pos = jax.lax.broadcasted_iota(jnp.int32, (H, L), 1)
-    a = jnp.where((pos >= oa) & (pos < oa + wla), a, PAD_CODE_A)
-    b = jnp.where((pos >= ob) & (pos < ob + wlb), b, PAD_CODE_B)
-
-    lvl = _masked_rows_lcs(a, b).astype(jnp.int32)
-    lvl_ref[0, :] = lvl
-    mss_ref[0, 0] = jnp.sum(lvl.astype(jnp.float32) * betas_ref[0])
+    L = table_a.shape[-1]
+    assert L < 127 and table_b.shape[1:] == table_a.shape[1:]
+    W = None if window is None else min(window, L)
+    a = _lane_operand(table_a, len_a, left, PAD_CODE_A, off_a, W)
+    b = _lane_operand(table_b, len_b, right, PAD_CODE_B, off_b, W)
+    lvl = lcs_lanes(a, b, interpret=interpret).T
+    return lvl, mss_scores(lvl, betas)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_gather_score(
-    table_a: jnp.ndarray,
-    len_a: jnp.ndarray,
-    table_b: jnp.ndarray,
-    len_b: jnp.ndarray,
-    left: jnp.ndarray,
-    right: jnp.ndarray,
-    betas: jnp.ndarray,
-    *,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+def fused_gather_score(table_a, len_a, table_b, len_b, left, right, betas,
+                       *, interpret: bool = False):
     """The raw kernel call: tables + pair indices -> (level_lcs, mss).
 
     table_a [Na, H, L] int32, len_a [Na] int32 (idem _b), left/right [P]
     int32 indices into the respective tables (pre-clamped: no PAD_ID), betas
     [H] float32 -> (level_lcs [P, H] int32, mss [P] float32).
     """
-    P = left.shape[0]
-    _, H, L = table_a.shape
-    assert L < 127 and table_b.shape[1:] == (H, L)
-    betas_row = betas.reshape(1, H).astype(jnp.float32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # left, right, len_a, len_b
-        grid=(P,),
-        in_specs=[
-            pl.BlockSpec((1, H, L), lambda p, li, ri, la, lb: (li[p], 0, 0)),
-            pl.BlockSpec((1, H, L), lambda p, li, ri, la, lb: (ri[p], 0, 0)),
-            pl.BlockSpec((1, H), lambda p, li, ri, la, lb: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H), lambda p, li, ri, la, lb: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, li, ri, la, lb: (p, 0)),
-        ],
+    return _gather_score(
+        table_a, len_a, table_b, len_b, left, right, None, None, betas,
+        window=None, interpret=interpret,
     )
-    lvl, mss = pl.pallas_call(
-        _fused_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((P, H), jnp.int32),
-            jax.ShapeDtypeStruct((P, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
-        left.astype(jnp.int32), right.astype(jnp.int32),
-        len_a.astype(jnp.int32), len_b.astype(jnp.int32),
-        table_a, table_b, betas_row,
-    )
-    return lvl, mss[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def fused_windowed_gather_score(
-    table_a: jnp.ndarray,
-    len_a: jnp.ndarray,
-    table_b: jnp.ndarray,
-    len_b: jnp.ndarray,
-    left: jnp.ndarray,
-    right: jnp.ndarray,
-    off_a: jnp.ndarray,
-    off_b: jnp.ndarray,
-    betas: jnp.ndarray,
-    *,
-    window: int,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+def fused_windowed_gather_score(table_a, len_a, table_b, len_b, left, right,
+                                off_a, off_b, betas, *, window: int,
+                                interpret: bool = False):
     """The raw windowed kernel call: tables + (traj, offset) coordinates.
 
     Identical to :func:`fused_gather_score` except pairs carry per-side
     window offsets: left/right [P] are TRAJECTORY indices into the tables,
     off_a/off_b [P] the window start offsets, and the scored operand is
-    the [H, W] slice ``rows[:, off : off + clip(len - off, 0, window)]``.
-    The prefetch tuple is (left, right, len_a, len_b, off_a, off_b); each
-    grid block still DMAs its pair's [H, L] rows straight off the resident
-    table and windows them in-register.
+    the [H, W] slice ``rows[:, off : off + clip(len - off, 0, window)]``,
+    masked in place.
     """
-    P = left.shape[0]
-    _, H, L = table_a.shape
-    assert L < 127 and table_b.shape[1:] == (H, L)
-    betas_row = betas.reshape(1, H).astype(jnp.float32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,  # left, right, len_a, len_b, off_a, off_b
-        grid=(P,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, H, L), lambda p, li, ri, la, lb, oa, ob: (li[p], 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, H, L), lambda p, li, ri, la, lb, oa, ob: (ri[p], 0, 0)
-            ),
-            pl.BlockSpec((1, H), lambda p, li, ri, la, lb, oa, ob: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H), lambda p, li, ri, la, lb, oa, ob: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, li, ri, la, lb, oa, ob: (p, 0)),
-        ],
+    return _gather_score(
+        table_a, len_a, table_b, len_b, left, right, off_a, off_b, betas,
+        window=window, interpret=interpret,
     )
-    lvl, mss = pl.pallas_call(
-        functools.partial(_fused_windowed_kernel, window=min(window, L)),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((P, H), jnp.int32),
-            jax.ShapeDtypeStruct((P, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
-        left.astype(jnp.int32), right.astype(jnp.int32),
-        len_a.astype(jnp.int32), len_b.astype(jnp.int32),
-        off_a.astype(jnp.int32), off_b.astype(jnp.int32),
-        table_a, table_b, betas_row,
-    )
-    return lvl, mss[:, 0]
 
 
 def fused_score_ref(
@@ -302,23 +157,15 @@ def fused_score(
     betas: jnp.ndarray,
     *,
     mode: str = "auto",
-    exact_mss: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Dispatch wrapper mirroring kernels/lcs/ops.lcs:
 
       "auto"       the kernel on TPU, the jnp reference elsewhere (the
                    interpreter would be orders of magnitude slower than the
                    wavefront on CPU) — the production default.
-      "pallas"     always the kernel (interpret off-TPU); parity tests that
-                   must prove the kernel really runs.
+      "pallas"     always the compiled kernel; refuses off the TPU.
       "interpret"  always the kernel with interpret=True, even on TPU.
       "ref"        always the jnp gather-then-score reference.
-
-    ``exact_mss=True`` (default) recomputes the returned mss from the
-    kernel's integer level_lcs through ``mss_scores`` — the same lowering
-    every other lcs_impl uses, so scores stay bit-identical across impls.
-    ``exact_mss=False`` returns the kernel's fused in-block epilogue
-    (within 1 ulp; saves the O(PH) recompute on the throughput path).
     """
     if mode not in _DISPATCH_MODES:
         raise ValueError(
@@ -327,15 +174,10 @@ def fused_score(
         )
     if mode == "ref" or (mode == "auto" and not _on_tpu()):
         return fused_score_ref(table_a, len_a, table_b, len_b, left, right, betas)
-    interpret = True if mode == "interpret" else not _on_tpu()
-    lvl, mss = fused_gather_score(
-        table_a, len_a, table_b, len_b, left, right, betas, interpret=interpret
+    return fused_gather_score(
+        table_a, len_a, table_b, len_b, left, right, betas,
+        interpret=mode != "auto" and kernel_interpret(mode),
     )
-    if exact_mss:
-        from repro.core.similarity import mss_scores
-
-        mss = mss_scores(lvl, betas)
-    return lvl, mss
 
 
 def fused_windowed_score_ref(
@@ -373,10 +215,9 @@ def fused_windowed_score(
     *,
     window: int,
     mode: str = "auto",
-    exact_mss: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Windowed twin of :func:`fused_score`: same dispatch modes, same
-    ``exact_mss`` contract, pairs carry (traj, offset) coordinates."""
+    """Windowed twin of :func:`fused_score`: same dispatch modes, pairs
+    carry (traj, offset) coordinates."""
     if mode not in _DISPATCH_MODES:
         raise ValueError(
             f"unknown fused dispatch mode {mode!r}; "
@@ -387,13 +228,7 @@ def fused_windowed_score(
             table_a, len_a, table_b, len_b, left, right, off_a, off_b,
             betas, window=window,
         )
-    interpret = True if mode == "interpret" else not _on_tpu()
-    lvl, mss = fused_windowed_gather_score(
+    return fused_windowed_gather_score(
         table_a, len_a, table_b, len_b, left, right, off_a, off_b, betas,
-        window=window, interpret=interpret,
+        window=window, interpret=mode != "auto" and kernel_interpret(mode),
     )
-    if exact_mss:
-        from repro.core.similarity import mss_scores
-
-        mss = mss_scores(lvl, betas)
-    return lvl, mss

@@ -1,26 +1,28 @@
-"""Pallas TPU kernel: batched LCS via anti-diagonal wavefront.
+"""Pallas TPU kernel: batched LCS with pairs on the vector lanes.
 
 TPU-native rewrite of the paper's CPU dynamic program (section IV.3).  The
-classic dp[i][j] recurrence is re-laid along anti-diagonals t = i + j so the
-inner dimension vectorizes on the VPU:
+classic row recurrence
 
-    d_t[i] = d_{t-2}[i-1] + 1                      if a[i-1] == b[t-i-1]
-             max(d_{t-1}[i-1], d_{t-1}[i])         otherwise
+    dp[i][j] = dp[i-1][j-1] + 1                  if a[i-1] == b[j-1]
+               max(dp[i-1][j], dp[i][j-1])       otherwise
 
-Two rolling diagonals of shape [TB, L+1] live in VREGs; the b-operand is
-accessed through a **rolling window**: a sentinel-padded reversed copy of b
-is rolled right by one lane per step, so the wavefront's diagonal gather
-becomes a static [:, :L+1] slice — no dynamic lane indexing, no gathers, no
-data-dependent control flow.  2L-1 steps total.
+is evaluated for a whole tile of pairs at once: operands arrive
+**lane-dense**, ``[H, L, R, 128]`` int32 with one pair per (row, lane)
+slot, so every DP cell is one elementwise op on an ``[8, 128]`` vreg that
+advances 1,024 pairs.  Nothing in the body moves data across lanes or
+sublanes — no reversal, no roll, no concatenation, no unaligned slice —
+which is what Mosaic lowers without relayouts.  The cells are int32 (Mosaic
+has no int8 vector compares); values never exceed L.
 
-Sentinels: the wrapper pads side A with -1, side B with -2; the window pad
-is -3 and the a-shift pad is -4, so no padding combination ever "matches"
-and out-of-range wavefront cells provably stay at 0 (see DESIGN.md).
+The body runs a ``fori_loop`` over the L rows of ``a`` carrying the previous
+DP row as L+1 tiles, with the L columns unrolled, so code size is O(L) and
+work O(L^2) per level.  Side-A pads (-1) and side-B pads (-2) never compare
+equal, so a sentinel-masked row's LCS is the LCS of its valid entries,
+wherever they sit in the row (the windowed path relies on this).
 
-Block shape: [TB, L] int32 tiles of both operands in VMEM; VMEM footprint
-is ~5 * TB * (3L) * 4 bytes (a, window, two diagonals, scratch) — for the
-default TB=512, L=32: ~1 MB, far under the ~16 MB/core budget, letting the
-grid pipeline overlap HBM loads with compute.
+Both public LCS kernels share this body: :func:`lcs_pallas` over
+pre-padded ``[B, L]`` row pairs, and the table-indexed kernels of
+``kernels/lcs/fused.py`` over gathered-and-masked operands.
 """
 from __future__ import annotations
 
@@ -30,82 +32,93 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-SENT_WINDOW = -3
-SENT_SHIFT = -4
+LANES = 128
+SUBLANES = 8
 
 
 def _lcs_kernel(a_ref, b_ref, o_ref):
-    a = a_ref[...]  # [TB, L] int32
-    b = b_ref[...]
-    tb, L = a.shape
+    """a_ref/b_ref [H, L, tr, 128] -> o_ref [H, tr, 128] LCS per pair.
 
-    # a_ext[i] = a[i-1] with sentinel shift-in: [TB, L+1]
-    a_ext = jnp.concatenate(
-        [jnp.full((tb, 1), SENT_SHIFT, jnp.int32), a], axis=1
-    )
-    # rolling window over reversed b: width W = 3L-1; at step t the live
-    # slice [:, :L+1] equals b[t-1-i] for i = 0..L (sentinel out of range).
-    window = jnp.concatenate(
-        [
-            jnp.full((tb, L), SENT_WINDOW, jnp.int32),
-            b[:, ::-1],
-            jnp.full((tb, L - 1), SENT_WINDOW, jnp.int32),
-        ],
-        axis=1,
-    )
-    # pre-align for t = 2: roll left by (2L - 2)
-    window = jnp.roll(window, -(2 * L - 2), axis=1)
+    ``tr`` is at most one vreg of rows (:func:`block_rows`), so each level
+    is one pass of [tr, 128] tiles.
+    """
+    H, L, tr, _ = a_ref.shape
 
-    zeros = jnp.zeros((tb, L + 1), jnp.int32)
+    def level(h, _):
+        b = [b_ref[h, j] for j in range(L)]
+        zero = jnp.zeros((tr, LANES), jnp.int32)
 
-    def shift_right(x):  # x[i-1] with 0 fill
-        return jnp.concatenate([jnp.zeros((tb, 1), jnp.int32), x[:, :-1]], axis=1)
+        def dp_row(i, prev):
+            ai = a_ref[h, i]
+            cur = [zero]
+            for j in range(1, L + 1):
+                cur.append(jnp.where(
+                    ai == b[j - 1], prev[j - 1] + 1,
+                    jnp.maximum(prev[j], cur[j - 1]),
+                ))
+            return tuple(cur)
 
-    def step(_, carry):
-        d2, d1, win = carry
-        bj = win[:, : L + 1]
-        match = a_ext == bj
-        up = d1
-        left = shift_right(d1)
-        diag = shift_right(d2)
-        new = jnp.where(match, diag + 1, jnp.maximum(up, left))
-        return d1, new, jnp.roll(win, 1, axis=1)
+        last = jax.lax.fori_loop(0, L, dp_row, (zero,) * (L + 1))
+        o_ref[h] = last[L]
+        return 0
 
-    _, d1, _ = jax.lax.fori_loop(0, 2 * L - 1, step, (zeros, zeros, window))
-    o_ref[...] = d1[:, L:]  # dp[L, L], shape [TB, 1]
+    jax.lax.fori_loop(0, H, level, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+def block_rows(pairs: int) -> tuple[int, int]:
+    """(rows per grid block, padded rows) for ``pairs`` lane-dense pairs.
+
+    A block is one [8, 128] vreg (1,024 pairs).  A batch of at most 8
+    rows is one block of exactly its own rows, which Mosaic accepts
+    since the block then spans the whole array; a larger batch pads its
+    rows to whole vregs.
+    """
+    rows = -(-max(pairs, 1) // LANES)
+    if rows <= SUBLANES:
+        return rows, rows
+    return SUBLANES, -(-rows // SUBLANES) * SUBLANES
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lcs_lanes(
+    a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool = False
+) -> jnp.ndarray:
+    """Lane-dense LCS: a, b int32 [H, L, P] (sentinel-masked) -> int32 [H, P].
+
+    P is padded to whole blocks with never-matching sentinels and the
+    result sliced back, so any P works.
+    """
+    H, L, P = a.shape
+    assert b.shape == (H, L, P) and L < 127
+    tr, rows = block_rows(P)
+    pad = rows * LANES - P
+    if pad:
+        a = jnp.pad(a, ((0, 0), (0, 0), (0, pad)), constant_values=-1)
+        b = jnp.pad(b, ((0, 0), (0, 0), (0, pad)), constant_values=-2)
+    a = a.reshape(H, L, rows, LANES)
+    b = b.reshape(H, L, rows, LANES)
+    operand = pl.BlockSpec((H, L, tr, LANES), lambda r: (0, 0, r, 0))
+    out = pl.pallas_call(
+        _lcs_kernel,
+        grid=(rows // tr,),
+        in_specs=[operand, operand],
+        out_specs=pl.BlockSpec((H, tr, LANES), lambda r: (0, r, 0)),
+        out_shape=jax.ShapeDtypeStruct((H, rows, LANES), jnp.int32),
+        interpret=interpret,
+    )(a, b)
+    return out.reshape(H, rows * LANES)[:, :P]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def lcs_pallas(
-    a: jnp.ndarray,
-    b: jnp.ndarray,
-    *,
-    block_b: int = 512,
-    interpret: bool = False,
+    a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool = False
 ) -> jnp.ndarray:
     """a, b: int32 [B, L] (pre-padded, distinct sentinels) -> int32 [B].
 
-    Any batch size works: a trailing partial tile is padded up to the next
-    ``block_b`` multiple with the standard (-1, -2) sentinels — which can
-    never match each other — and the result is sliced back to ``B``, so
-    callers no longer over-pad pair buffers to tile multiples themselves.
+    The row pairs are transposed to the lane-dense layout and scored by
+    :func:`lcs_lanes`; any batch size works.
     """
     B, L = a.shape
     assert b.shape == (B, L)
-    pad = (-B) % block_b
-    if pad:
-        a = jnp.concatenate([a, jnp.full((pad, L), -1, jnp.int32)])
-        b = jnp.concatenate([b, jnp.full((pad, L), -2, jnp.int32)])
-    grid = ((B + pad) // block_b,)
-    out = pl.pallas_call(
-        _lcs_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, L), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, L), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B + pad, 1), jnp.int32),
-        interpret=interpret,
-    )(a, b)
-    return out[:B, 0]
+    out = lcs_lanes(a.T[None], b.T[None], interpret=interpret)
+    return out[0]

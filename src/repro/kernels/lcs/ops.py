@@ -1,70 +1,39 @@
-"""Public jit'd wrapper for the batched LCS kernel.
+"""Public wrapper for the batched LCS kernel.
 
-Pads the batch to the block size and dispatches to the Pallas kernel
-(interpret=True off-TPU so CPU tests execute the same kernel body).  The
-wrapper is shard-local-shape aware: it is traceable inside a shard_map
-program, where the batch is the per-shard pair buffer — the block size is
-chosen to minimize padded waste (see :func:`_block_for`) so a small or
-just-past-a-boundary shard never pads up to a full 512-row tile, and any
-remainder rows are sentinel-padded so they can never contribute a match.
+Dispatches a batch of sentinel-padded row pairs to the Pallas kernel
+(kernels/lcs/kernel.py) or the jnp wavefront.  The wrapper is
+shard-local-shape aware: it is traceable inside a shard_map program, where
+the batch is the per-shard pair buffer; the kernel pads any remainder to
+whole blocks with never-matching sentinels.
 
 ``mode`` selects the dispatch policy:
 
-  "auto"       wavefront for tiny batches off-TPU (kernel launch overhead
-               dominates), Pallas otherwise — the production default.
-  "pallas"     always the Pallas kernel (interpret off-TPU); used by parity
-               tests that must prove the kernel really runs.
+  "auto"       the Pallas kernel on TPU, the jnp wavefront elsewhere — the
+               production default.
+  "pallas"     always the compiled Pallas kernel; refuses off the TPU.
   "interpret"  always the Pallas kernel with interpret=True, even on TPU.
   "wavefront"  always the jnp anti-diagonal wavefront.
 
-``block_b`` is the tile-size CAP, not the tile size: the dispatcher picks
-the waste-minimizing power of two at or under it.  Callers holding a tuned
-block size (repro.perf's autotune table, resolved eagerly at the call
-boundary — never inside a trace) pass it here and the same waste rule
-applies under the tuned cap.
+The kernel tiles pairs in whole ``[8, 128]`` vregs
+(:func:`repro.kernels.lcs.kernel.block_rows`); there is no tile knob.
+The engine's ``lcs_impl`` names do not come through here: they score by
+table index through ``kernels/lcs/fused.py``, which lays the operands out
+lane-dense directly.  This wrapper serves callers that hold row pairs.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from repro.core.compat import on_tpu as _on_tpu
-from repro.core.encoding import PAD_CODE_A, PAD_CODE_B
+from repro.kernels.lcs.fused import kernel_interpret
 from repro.kernels.lcs.kernel import lcs_pallas
 from repro.core.similarity import lcs_wavefront, wavefront_dtype_from_env
-
-# smallest tile worth launching a grid step for: below this, per-block
-# launch overhead dominates the padded-row waste the block would save
-_BLOCK_FLOOR = 128
-
-
-def _block_for(batch: int, block_b: int, *, floor: int = _BLOCK_FLOOR) -> int:
-    """Power-of-two block <= block_b minimizing padded rows, over a floor.
-
-    The old rule ("largest power of two <= batch") over-pads just past a
-    boundary: B=513 picked block 512, padding to 1024 (~50% wasted rows),
-    when block 128 pads only to 640.  Instead, every candidate power of two
-    in [min(floor, block_b), block_b] is scored by its padded batch size
-    ``ceil(B / b) * b``; the smallest padding wins, and ties go to the
-    LARGER block (fewer grid steps for the same rows).
-    """
-    cap = max(1, block_b)
-    lo = min(floor, cap)
-    best_b, best_padded = None, None
-    b = 1
-    while b <= cap:
-        if b >= lo:
-            padded = -(-batch // b) * b  # ceil(batch / b) * b
-            if best_padded is None or padded <= best_padded:
-                best_b, best_padded = b, padded
-        b *= 2
-    return best_b
 
 
 def lcs(
     a: jnp.ndarray,
     b: jnp.ndarray,
     *,
-    block_b: int = 512,
     mode: str = "auto",
     wavefront_dtype: jnp.dtype | None = None,
 ) -> jnp.ndarray:
@@ -77,9 +46,8 @@ def lcs(
     and the wavefront are jitted themselves), and it is the call boundary
     where the REPRO_LCS_DTYPE probe is resolved into the wavefront's static
     ``dtype`` argument (``wavefront_dtype=None`` -> read the env var here,
-    never inside a trace).  Tuned parameters flow in the same way: the
-    engine resolves the autotune table eagerly and passes ``block_b`` /
-    ``wavefront_dtype`` as static arguments.
+    never inside a trace).  A tuned dtype flows in the same way: the
+    autotune table is resolved eagerly and passed as ``wavefront_dtype``.
     """
     if mode not in ("auto", "pallas", "interpret", "wavefront"):
         raise ValueError(
@@ -88,51 +56,9 @@ def lcs(
         )
     B, L = a.shape
     assert b.shape == (B, L)
-    if mode == "wavefront" or (mode == "auto" and B < block_b and not _on_tpu()):
+    if mode == "wavefront" or (mode == "auto" and not _on_tpu()):
         if wavefront_dtype is None:
             wavefront_dtype = wavefront_dtype_from_env()
         return lcs_wavefront(a, b, dtype=wavefront_dtype)
-    interpret = True if mode == "interpret" else not _on_tpu()
-    # lcs_pallas auto-pads any remainder rows up to the block multiple
-    return lcs_pallas(a, b, block_b=_block_for(B, block_b), interpret=interpret)
-
-
-def lcs_windowed(
-    a: jnp.ndarray,
-    b: jnp.ndarray,
-    off_a: jnp.ndarray,
-    off_b: jnp.ndarray,
-    len_a: jnp.ndarray,
-    len_b: jnp.ndarray,
-    *,
-    window: int,
-    block_b: int = 512,
-    mode: str = "auto",
-    wavefront_dtype: jnp.dtype | None = None,
-) -> jnp.ndarray:
-    """Subtrajectory LCS: full rows + per-row window coordinates -> [B].
-
-    a/b int32 [B, L] code rows with the table's native padding (no repad
-    needed), off_a/off_b [B] window start offsets, len_a/len_b [B] the
-    rows' TRUE lengths.  Each row is sliced to its
-    ``[off, off + clip(len - off, 0, window))`` window, sentinel-repadded
-    to width ``min(window, L)``, and dispatched through :func:`lcs` — so
-    the batched kernel runs 2W-1 wavefront steps over width-W tiles
-    instead of 2L-1 over the full rows, and the same ``mode``/``block_b``
-    tuning surface applies.
-    """
-    B, L = a.shape
-    W = min(window, L)
-    pos = jnp.arange(W, dtype=jnp.int32)
-
-    def slice_side(x, off, length, pad_code):
-        wlen = jnp.clip(length - off, 0, W)
-        p = jnp.clip(off[:, None] + pos[None, :], 0, L - 1)
-        win = jnp.take_along_axis(x, p, axis=1)
-        return jnp.where(pos[None, :] < wlen[:, None], win, pad_code)
-
-    return lcs(
-        slice_side(a, off_a, len_a, PAD_CODE_A),
-        slice_side(b, off_b, len_b, PAD_CODE_B),
-        block_b=block_b, mode=mode, wavefront_dtype=wavefront_dtype,
-    )
+    interpret = mode != "auto" and kernel_interpret(mode)
+    return lcs_pallas(a, b, interpret=interpret)

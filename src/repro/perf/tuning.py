@@ -1,9 +1,9 @@
 """Cached autotune table for the LCS score stage.
 
-The score stage's free parameters — the Pallas wavefront's batch tile
-``block_b`` and the anti-diagonal carry dtype (int8 rolling diagonals vs
-int32) — were guessed until now.  This module stores measured winners in a
-small JSON table keyed per ``(P, H, L, backend)`` so the engine can look
+The score stage's free parameter — the jnp wavefront's anti-diagonal
+carry dtype (int8 rolling diagonals vs int32) — was guessed until now
+(the Pallas kernel has no tile knob: it tiles pairs in whole [8, 128]
+vregs).  This module stores measured winners in a small JSON table keyed per ``(P, H, L, backend)`` so the engine can look
 them up instead, the same discipline REPOSE applies to its distributed
 top-k search layout: tune once against the roofline harness, replay the
 winner everywhere.
@@ -18,9 +18,9 @@ Three rules keep the table safe to consult from the hot path:
    data dependence or steady-state recompiles (the runner cache keys on
    the resolved values).
 2. **Bit-identical candidates only.**  Every candidate the sweep measures
-   produces bit-identical scores by construction (``block_b`` only changes
-   tiling; int8 vs int32 diagonals agree for L < 127, asserted at record
-   time), so consulting the table can change throughput but never results.
+   produces bit-identical scores by construction (int8 vs int32
+   diagonals agree for L < 127, asserted at record time), so consulting
+   the table can change throughput but never results.
 3. **Environment pins win.**  An explicit ``REPRO_LCS_DTYPE`` pin
    overrides the tuned dtype — the reproducibility knob outranks the
    performance knob.
@@ -29,7 +29,7 @@ Keys quantize ``P`` (the pair-buffer size) to its ceiling power of two
 because that is the granularity the capacity planner pads buffers to: two
 workloads the planner maps to the same padded buffer get the same tuned
 parameters.  Misses fall back to the nearest recorded ``P`` for the same
-``(H, L, backend)`` (tile choice varies slowly in P), then to ``None`` —
+``(H, L, backend)`` (the winner varies slowly in P), then to ``None`` —
 callers keep their current defaults on a total miss.
 
 The table is populated by ``python -m benchmarks.roofline --tune`` and
@@ -48,7 +48,7 @@ import jax
 
 from repro.core.compat import backend_name
 
-SCHEMA = "repro-tuning/v1"
+SCHEMA = "repro-tuning/v2"
 
 # default on-disk location; override with REPRO_TUNING_PATH
 DEFAULT_PATH = Path(__file__).resolve().parents[3] / "TUNING.json"
@@ -76,8 +76,6 @@ def quantize_pairs(pairs: int) -> int:
 class LCSTuning:
     """Measured winner for one (P, H, L, backend) cell.
 
-    ``block_b``           batch-tile cap handed to kernels/lcs/ops.lcs
-                          (the waste-minimizing rule still applies under it).
     ``wavefront_dtype``   "int8" | "int32" diagonal carry for the jnp
                           wavefront (overridden by REPRO_LCS_DTYPE).
     ``pairs_per_sec``     throughput of the winner when measured — carried
@@ -85,13 +83,10 @@ class LCSTuning:
                           dispatch time.
     """
 
-    block_b: int
     wavefront_dtype: str
     pairs_per_sec: float = 0.0
 
     def __post_init__(self):
-        if self.block_b < 1 or (self.block_b & (self.block_b - 1)):
-            raise ValueError(f"block_b must be a power of two, got {self.block_b}")
         if self.wavefront_dtype not in _DTYPES:
             raise ValueError(
                 f"wavefront_dtype must be one of {_DTYPES}, "

@@ -3,7 +3,7 @@
 Pins the contract of ISSUEs 2 and 3: every cell of
 
     {ssh, minhash, brp, udf} x {1, 2, 4 shards} x {replicate, shuffle}
-                 x {wavefront, pallas-interpret, fused-interpret}
+                 x {wavefront, ref, fused-interpret}
 
 produces identical similar pairs, identical communities and bit-identical
 per-pair scores to the single-device engine (and, at n_shards=1, to the
@@ -14,8 +14,9 @@ while still compiling every (shards, mode, impl) program.
 Also proves the structural claims:
 * with n_shards>1 the engine has NO host EncodeStage (encoding runs inside
   the shard_map program) and reports no ``t_encode`` phase;
-* ``lcs_impl="pallas-interpret"`` really dispatches ``lcs_pallas`` inside
-  the shard_map score stage (counted via monkeypatch at trace time);
+* ``lcs_impl="fused-interpret"`` really dispatches the Pallas kernel body
+  (``fused.lcs_lanes``) inside the shard_map score stage (counted via
+  monkeypatch at trace time);
 * ``lcs_impl="fused-interpret"`` really dispatches the gather-free
   ``fused_gather_score`` kernel, on the single-device AND sharded paths.
 
@@ -33,7 +34,7 @@ monkeypatched with a counter) shows the device path keeps the join state
 in-mesh: the driver-resident bucket table is NEVER consulted.
 
 ISSUE 9 adds the AUTOTUNE + OVERLAP axis: a tuning table with NON-default
-parameters (block_b=128, int32 diagonals) plus ``overlap_chunks`` in
+parameters (int32 diagonals) plus ``overlap_chunks`` in
 {2, 4} must stay bit-identical to the untuned serial defaults across
 {wavefront, fused-interpret} x SHARDS x {replicate, shuffle}, one-shot
 and streaming — with a real-dispatch proof that the tuned record reaches
@@ -77,7 +78,7 @@ from repro.data import fig1_world
 backend = "%(backend)s"
 batch, forest = fig1_world()
 RHO = 3.0
-IMPLS = ("wavefront", "pallas-interpret", "fused-interpret")
+IMPLS = ("wavefront", "ref", "fused-interpret")
 
 
 def score_map(res):
@@ -99,7 +100,7 @@ for impl in IMPLS:
 
 # engine vs engine across impls: integer LCS (and a fixed-order float32
 # MSS epilogue in the fused kernel) => bit-identical scores
-assert score_map(base["wavefront"]) == score_map(base["pallas-interpret"])
+assert score_map(base["wavefront"]) == score_map(base["ref"])
 assert score_map(base["wavefront"]) == score_map(base["fused-interpret"])
 
 # engine vs legacy (single device, ssh/udf share the lossless shingle join)
@@ -140,25 +141,25 @@ def test_parity_matrix(backend):
 
 PALLAS_DISPATCH_CODE = r"""
 import numpy as np
-import repro.kernels.lcs.ops as lcs_ops
+import repro.kernels.lcs.fused as fused
 from repro.api import AnotherMeEngine, EngineConfig, ExecutionPlan
 from repro.data import fig1_world
 
 calls = []
-real = lcs_ops.lcs_pallas
+real = fused.lcs_lanes
 
 def counting(*args, **kwargs):
     calls.append(kwargs.get("interpret"))
     return real(*args, **kwargs)
 
-lcs_ops.lcs_pallas = counting
+fused.lcs_lanes = counting
 batch, forest = fig1_world()
 cfg = EngineConfig(rho=3.0)
 single = AnotherMeEngine(forest, cfg).run(batch)
 assert not calls  # default wavefront impl never touches the kernel
 
 sharded = AnotherMeEngine(
-    forest, cfg, ExecutionPlan(n_shards=4, lcs_impl="pallas-interpret"),
+    forest, cfg, ExecutionPlan(n_shards=4, lcs_impl="fused-interpret"),
 ).run(batch)
 # traced (and therefore executed) inside the shard_map score stage
 assert calls and all(interp is True for interp in calls), calls
@@ -384,8 +385,8 @@ from repro.api import AnotherMeEngine, EngineConfig, ExecutionPlan
 from repro.core.types import PAD_ID
 from repro.data import fig1_world
 
-# a throwaway tuning table with NON-default parameters: block_b=128
-# (default cap 512) and int32 diagonals (env default int8) — parity must
+# a throwaway tuning table with a NON-default parameter: int32
+# diagonals (env default int8) — parity must
 # hold precisely because tuned values may only change throughput
 os.environ.pop("REPRO_LCS_DTYPE", None)
 os.environ["REPRO_TUNING_PATH"] = os.path.join(
@@ -395,7 +396,7 @@ from repro.perf import LCSTuning, TuningTable
 
 batch, forest = fig1_world()
 L = int(np.asarray(batch.places).shape[1])
-TUNED = LCSTuning(block_b=128, wavefront_dtype="int32")
+TUNED = LCSTuning(wavefront_dtype="int32")
 table = TuningTable()
 table.record(1024, forest.num_levels, L, TUNED)  # nearest-P covers all P
 table.save()
@@ -485,7 +486,7 @@ batch, forest = synthetic_setup(24, num_types=6, classes_per_type=3,
 L = int(np.asarray(batch.places).shape[1])
 table = TuningTable()
 table.record(1024, forest.num_levels, L,
-             LCSTuning(block_b=128, wavefront_dtype="int32"))
+             LCSTuning(wavefront_dtype="int32"))
 table.save()
 
 RHO = 2.0
@@ -682,9 +683,9 @@ def test_plan_lcs_impl_override_folds_into_config():
     _, forest = fig1_world()
     eng = AnotherMeEngine(
         forest, EngineConfig(lcs_impl="wavefront"),
-        ExecutionPlan(lcs_impl="pallas"),
+        ExecutionPlan(lcs_impl="fused-pallas"),
     )
-    assert eng.config.lcs_impl == "pallas"
+    assert eng.config.lcs_impl == "fused-pallas"
     import pytest as _pytest
 
     with _pytest.raises(ValueError, match="lcs_impl"):

@@ -20,7 +20,7 @@ class TestLCS:
         b = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         a[np.arange(L)[None, :] >= la[:, None]] = -1
         b[np.arange(L)[None, :] >= lb[:, None]] = -2
-        got = np.asarray(lcs(jnp.asarray(a), jnp.asarray(b), block_b=256))
+        got = np.asarray(lcs(jnp.asarray(a), jnp.asarray(b)))
         want = np.asarray(ref(jnp.asarray(a), jnp.asarray(b)))
         np.testing.assert_array_equal(got, want)
 
@@ -33,7 +33,7 @@ class TestLCS:
         a = rng.integers(0, 4, size=(B, L)).astype(np.int32)
         b = rng.integers(0, 4, size=(B, L)).astype(np.int32)
         got = np.asarray(
-            lcs_pallas(jnp.asarray(a), jnp.asarray(b), block_b=128, interpret=True)
+            lcs_pallas(jnp.asarray(a), jnp.asarray(b), interpret=True)
         )
         want = np.asarray(ref(jnp.asarray(a), jnp.asarray(b)))
         np.testing.assert_array_equal(got, want)

@@ -27,14 +27,11 @@ def _sentinel_pad(a, b, la, lb):
 
 
 class TestLCSGolden:
-    def _check(self, a, b, block_b=64):
+    def _check(self, a, b):
         from repro.kernels.lcs.ops import lcs
         from repro.kernels.lcs.ref import lcs as ref
 
-        got = np.asarray(
-            lcs(jnp.asarray(a), jnp.asarray(b), block_b=block_b,
-                mode="interpret")
-        )
+        got = np.asarray(lcs(jnp.asarray(a), jnp.asarray(b), mode="interpret"))
         want = np.asarray(ref(jnp.asarray(a), jnp.asarray(b)))
         np.testing.assert_array_equal(got, want)
 
@@ -51,7 +48,7 @@ class TestLCSGolden:
         # L == 1: the rolling window degenerates to a single lane
         a = np.asarray([[2], [3], [4]], np.int32)
         b = np.asarray([[2], [5], [4]], np.int32)
-        self._check(a, b, block_b=2)
+        self._check(a, b)
 
     @pytest.mark.parametrize("B", [5, 130, 300])
     def test_non_multiple_of_block_batches(self, B):
@@ -62,7 +59,7 @@ class TestLCSGolden:
         a = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         b = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         a, b = _sentinel_pad(a, b, la, lb)
-        self._check(a, b, block_b=128)
+        self._check(a, b)
 
     def test_all_identical_inputs(self):
         B, L = 64, 10
@@ -75,11 +72,12 @@ class TestLCSGolden:
 
 
 class TestLCSBlockPad:
-    """The lcs_pallas wrapper auto-pads non-block-multiple batches (ISSUE 3
-    satellite: the hard ``B %% block_b == 0`` assert is gone)."""
+    """The lcs_pallas wrapper pads any batch to whole blocks: one block of
+    its own rows up to 1,024 pairs, whole [8, 128] vregs past that (1,500
+    pairs are 12 rows, padded to 16)."""
 
-    @pytest.mark.parametrize("B,block_b", [(1, 4), (5, 4), (7, 8), (130, 64)])
-    def test_direct_kernel_any_batch(self, B, block_b):
+    @pytest.mark.parametrize("B", [1, 5, 7, 130, 1500])
+    def test_direct_kernel_any_batch(self, B):
         from repro.kernels.lcs.kernel import lcs_pallas
         from repro.kernels.lcs.ref import lcs as ref
 
@@ -91,34 +89,36 @@ class TestLCSBlockPad:
         b = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         a, b = _sentinel_pad(a, b, la, lb)
         got = np.asarray(
-            lcs_pallas(jnp.asarray(a), jnp.asarray(b), block_b=block_b,
-                       interpret=True)
+            lcs_pallas(jnp.asarray(a), jnp.asarray(b), interpret=True)
         )
         want = np.asarray(ref(jnp.asarray(a), jnp.asarray(b)))
         np.testing.assert_array_equal(got, want)
 
 
 class TestBlockFor:
-    """ops._block_for picks the pow2 tile minimizing padded batch (ISSUE 9
-    satellite: 513 rows under block_b=512 used to pad to 1024 — one whole
-    wasted block — instead of 5 x 128 = 640)."""
+    """kernel.block_rows sizes the lane-dense grid: a batch of at most one
+    vreg of rows is one block of exactly its own [*, 128] rows (no padding
+    to a full vreg tile); a larger batch is one [8, 128] vreg per block,
+    its rows padded to whole vregs."""
 
     def test_waste_minimization(self):
-        from repro.kernels.lcs.ops import _block_for
+        from repro.kernels.lcs.kernel import block_rows
 
-        assert _block_for(513, 512) == 128   # 640 padded, not 1024
-        assert _block_for(512, 512) == 512   # exact fit keeps the big tile
-        assert _block_for(1024, 512) == 512  # ties resolve to the largest
-        assert _block_for(640, 512) == 128   # 640 exact under 128
-        assert _block_for(100, 512) == 128   # floor: one 128 block
+        assert block_rows(513) == (5, 5)        # 640 lanes, one block
+        assert block_rows(1) == (1, 1)          # one row of 128
+        assert block_rows(1024) == (8, 8)       # exact fit
+        assert block_rows(1025) == (8, 16)      # second block
+        assert block_rows(8192) == (8, 64)      # exact multiple
 
-    def test_block_b_is_a_cap(self):
-        from repro.kernels.lcs.ops import _block_for
+    @pytest.mark.parametrize("pairs,padded", [
+        (1500, 16), (9000, 72), (10_000, 80), (1 << 20, 8192),
+    ])
+    def test_rows_pad_to_whole_vregs(self, pairs, padded):
+        from repro.kernels.lcs.kernel import SUBLANES, block_rows
 
-        # a small explicit cap (e.g. a tuned value) lowers the floor too
-        assert _block_for(1000, 64) == 64
-        assert _block_for(3, 4) == 4
-        assert _block_for(1, 1) == 1
+        tr, rows = block_rows(pairs)
+        assert (tr, rows) == (SUBLANES, padded)
+        assert rows * 128 >= pairs and rows % tr == 0
 
     @pytest.mark.parametrize("B", [513, 640, 1000])
     def test_golden_at_non_pow2_batches(self, B):
@@ -133,12 +133,34 @@ class TestBlockFor:
         a = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         b = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         a, b = _sentinel_pad(a, b, la, lb)
-        got = np.asarray(
-            lcs(jnp.asarray(a), jnp.asarray(b), block_b=512,
-                mode="interpret")
-        )
+        got = np.asarray(lcs(jnp.asarray(a), jnp.asarray(b), mode="interpret"))
         want = np.asarray(ref(jnp.asarray(a), jnp.asarray(b)))
         np.testing.assert_array_equal(got, want)
+
+
+class TestKernelImplNames:
+    """Only the fused names reach the Pallas kernel, through the
+    lane-dense table-indexed wrappers (kernels/lcs/fused.py); the removed
+    row-path names are refused, not silently mapped."""
+
+    @pytest.mark.parametrize("name,mode", [
+        ("fused", "auto"), ("fused-pallas", "pallas"),
+        ("fused-interpret", "interpret"),
+    ])
+    def test_kernel_names_are_dispatch_modes(self, name, mode):
+        from repro.core.similarity import lcs_impl
+
+        assert lcs_impl(name) == mode
+
+    @pytest.mark.parametrize("name", ["kernel", "pallas", "pallas-interpret"])
+    def test_row_path_names_are_refused(self, name):
+        from repro.api import validate_lcs_impl
+        from repro.core.similarity import lcs_impl
+
+        with pytest.raises(ValueError, match="unknown lcs_impl"):
+            validate_lcs_impl(name)
+        with pytest.raises(ValueError, match="unknown lcs_impl"):
+            lcs_impl(name)
 
 
 class TestFusedGolden:
@@ -452,3 +474,93 @@ class TestSortedSlabGolden:
             self._check_probe(slab_k, slab_r, keys, rows,
                               nn_cap=256, no_cap=256)
             self._check_merge(slab_k, slab_r, keys, rows)
+
+
+def _ref_scores(codes, lengths, left, right, betas, ta=None, tb=None,
+                oa=None, ob=None, W=None):
+    """Unchunked textbook-DP scores (``lcs_ref``) of table-indexed pairs."""
+    from repro.core.similarity import (
+        gather_windows, lcs_ref, mss_scores, multi_level_lcs,
+    )
+
+    if W is None:
+        lvl = multi_level_lcs(codes[left], lengths[left], codes[right],
+                              lengths[right], impl=lcs_ref)
+    else:
+        lvl = multi_level_lcs(
+            gather_windows(codes[ta], oa, W),
+            jnp.clip(lengths[ta] - oa, 0, W),
+            gather_windows(codes[tb], ob, W),
+            jnp.clip(lengths[tb] - ob, 0, W), impl=lcs_ref,
+        )
+    return lvl, mss_scores(lvl, betas)
+
+
+class TestScoreChunks:
+    """Chunked scoring (``similarity.score_indexed``) at the chunk
+    boundaries, and table-indexed scoring with N + P past the 1 MiB SMEM
+    bound that used to cap the fused kernel's scalar-prefetched tables:
+    bit-identical to unchunked ``lcs_ref`` scores for every impl family."""
+
+    N, H, L = 140_000, 3, 10
+
+    @pytest.fixture(scope="class")
+    def world(self):
+        rng = np.random.default_rng(7)
+        lengths = rng.integers(1, self.L + 1, size=self.N).astype(np.int32)
+        codes = rng.integers(0, 4, size=(self.N, self.H, self.L))
+        pad = np.arange(self.L)[None, None, :] >= lengths[:, None, None]
+        codes = np.where(pad, -1, codes).astype(np.int32)
+        betas = np.asarray([0.5, 0.25, 0.25], np.float32)
+        return jnp.asarray(codes), jnp.asarray(lengths), jnp.asarray(betas)
+
+    def _run(self, world, P, impl_name, windowed):
+        import jax
+
+        from repro.core.similarity import lcs_impl, score_indexed
+
+        codes, lengths, betas = world
+        rng = np.random.default_rng(P)
+        left = jnp.asarray(rng.integers(0, self.N, size=P), jnp.int32)
+        right = jnp.asarray(rng.integers(0, self.N, size=P), jnp.int32)
+        kw, ref_kw = {}, {}
+        if windowed:
+            W = 4
+            oa = jnp.asarray(rng.integers(0, self.L - W + 1, size=P),
+                             jnp.int32)
+            ob = jnp.asarray(rng.integers(0, self.L - W + 1, size=P),
+                             jnp.int32)
+            kw = dict(window=W, off_a=oa, off_b=ob)
+            ref_kw = dict(ta=left, tb=right, oa=oa, ob=ob, W=W)
+        fn = jax.jit(lambda c, n, l, r, b: score_indexed(
+            c, n, c, n, l, r, b, impl=lcs_impl(impl_name), **kw))
+        got = fn(codes, lengths, left, right, betas)
+        want = _ref_scores(codes, lengths, left, right, betas, **ref_kw)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        return str(jax.make_jaxpr(fn)(codes, lengths, left, right, betas))
+
+    @pytest.mark.parametrize("windowed", [False, True])
+    @pytest.mark.parametrize(
+        "impl_name", ["fused-interpret", "wavefront", "ref"]
+    )
+    @pytest.mark.parametrize("delta", [-1, 0, 1])
+    def test_chunk_boundaries(self, world, monkeypatch, delta, impl_name,
+                              windowed):
+        from repro.core import compat
+        from repro.core.similarity import score_chunk
+
+        # no device memory to spare: score_chunk falls to its 1,024 floor
+        monkeypatch.setattr(compat, "device_memory_bytes", lambda: 0)
+        chunk = score_chunk(self.H, self.L, lane_dense=True)
+        assert chunk == score_chunk(self.H, self.L, lane_dense=False) == 1024
+        jaxpr = self._run(world, chunk + delta, impl_name, windowed)
+        # the chunk loop slices chunk-sized pair windows exactly when P
+        # exceeds one chunk
+        assert (f"slice_sizes=({chunk},)" in jaxpr) == (delta > 0)
+
+    @pytest.mark.parametrize("windowed", [False, True])
+    def test_tables_past_old_smem_bound(self, world, windowed):
+        # N + P = 143,000 > 131,072: the int32 tables the old kernel
+        # prefetched into SMEM would not fit its 1 MiB
+        self._run(world, 3_000, "fused-interpret", windowed)

@@ -39,41 +39,37 @@ class TestQuantize:
 
 
 class TestLCSTuningValidation:
-    def test_rejects_non_pow2_block(self):
-        with pytest.raises(ValueError, match="power of two"):
-            LCSTuning(block_b=96, wavefront_dtype="int32")
-
     def test_rejects_unknown_dtype(self):
         with pytest.raises(ValueError, match="wavefront_dtype"):
-            LCSTuning(block_b=128, wavefront_dtype="float32")
+            LCSTuning(wavefront_dtype="float32")
 
     def test_record_rejects_int8_at_long_lengths(self):
         # int8 diagonals saturate at 127: recording one for L >= 127 could
         # make a tuned run diverge from the int32 default
         t = TuningTable()
         with pytest.raises(ValueError, match="unsafe"):
-            t.record(1024, 3, 127, LCSTuning(128, "int8"))
-        t.record(1024, 3, 127, LCSTuning(128, "int32"))  # int32 fine
-        t.record(1024, 3, 126, LCSTuning(128, "int8"))   # short L fine
+            t.record(1024, 3, 127, LCSTuning("int8"))
+        t.record(1024, 3, 127, LCSTuning("int32"))  # int32 fine
+        t.record(1024, 3, 126, LCSTuning("int8"))   # short L fine
 
 
 class TestRoundTrip:
     def test_save_load(self, tmp_path):
         path = tmp_path / "TUNING.json"
         t = _table_with({
-            (4096, 3, 32): LCSTuning(256, "int8", pairs_per_sec=1e5),
-            (1024, 3, 16): LCSTuning(512, "int32"),
+            (4096, 3, 32): LCSTuning("int8", pairs_per_sec=1e5),
+            (1024, 3, 16): LCSTuning("int32"),
         })
         t.save(path)
         back = TuningTable.load(path)
         assert back.entries == t.entries
-        assert back.lookup(4096, 3, 32) == LCSTuning(256, "int8", 1e5)
+        assert back.lookup(4096, 3, 32) == LCSTuning("int8", 1e5)
 
     def test_env_path_override(self, tmp_path, monkeypatch):
         p = tmp_path / "elsewhere.json"
         monkeypatch.setenv("REPRO_TUNING_PATH", str(p))
         assert tuning_path() == p
-        _table_with({(64, 3, 16): LCSTuning(128, "int32")}).save()
+        _table_with({(64, 3, 16): LCSTuning("int32")}).save()
         assert p.exists()
         assert TuningTable.load().lookup(64, 3, 16) is not None
 
@@ -83,7 +79,7 @@ class TestInvalidation:
 
     def _saved(self, tmp_path):
         path = tmp_path / "TUNING.json"
-        _table_with({(4096, 3, 32): LCSTuning(256, "int8")}).save(path)
+        _table_with({(4096, 3, 32): LCSTuning("int8")}).save(path)
         return path
 
     def test_missing_file(self, tmp_path):
@@ -111,8 +107,7 @@ class TestInvalidation:
         path = self._saved(tmp_path)
         raw = json.loads(path.read_text())
         key = next(iter(raw["entries"]))
-        raw["entries"]["P64-H3-L16-cpu"] = {"block_b": 96,
-                                            "wavefront_dtype": "int32"}
+        raw["entries"]["P64-H3-L16-cpu"] = {"wavefront_dtype": "int16"}
         path.write_text(json.dumps(raw))
         t = TuningTable.load(path)
         assert t.entries == {}          # the GOOD cell is gone too
@@ -121,20 +116,20 @@ class TestInvalidation:
 
 class TestLookup:
     def test_exact_hit_is_p_quantized(self):
-        t = _table_with({(4096, 3, 32): LCSTuning(256, "int8")})
+        t = _table_with({(4096, 3, 32): LCSTuning("int8")})
         # 3000 quantizes to the same P4096 buffer the planner would pad to
-        assert t.lookup(3000, 3, 32) == LCSTuning(256, "int8")
+        assert t.lookup(3000, 3, 32) == LCSTuning("int8")
 
     def test_nearest_p_fallback(self):
         t = _table_with({
-            (1024, 3, 32): LCSTuning(128, "int8"),
-            (65536, 3, 32): LCSTuning(512, "int8"),
+            (1024, 3, 32): LCSTuning("int8", pairs_per_sec=1.0),
+            (65536, 3, 32): LCSTuning("int8", pairs_per_sec=2.0),
         })
-        assert t.lookup(2048, 3, 32) == LCSTuning(128, "int8")
-        assert t.lookup(32768, 3, 32) == LCSTuning(512, "int8")
+        assert t.lookup(2048, 3, 32) == LCSTuning("int8", pairs_per_sec=1.0)
+        assert t.lookup(32768, 3, 32) == LCSTuning("int8", pairs_per_sec=2.0)
 
     def test_miss_on_different_shape(self):
-        t = _table_with({(4096, 3, 32): LCSTuning(256, "int8")})
+        t = _table_with({(4096, 3, 32): LCSTuning("int8")})
         assert t.lookup(4096, 5, 32) is None   # H differs
         assert t.lookup(4096, 3, 64) is None   # L differs
 
@@ -148,15 +143,15 @@ class TestDtypeResolution:
 
     def test_tuned_dtype_wins_when_unpinned(self, monkeypatch):
         monkeypatch.delenv("REPRO_LCS_DTYPE", raising=False)
-        assert resolve_wavefront_dtype(LCSTuning(128, "int32")) == jnp.int32
-        assert resolve_wavefront_dtype(LCSTuning(128, "int8")) == jnp.int8
+        assert resolve_wavefront_dtype(LCSTuning("int32")) == jnp.int32
+        assert resolve_wavefront_dtype(LCSTuning("int8")) == jnp.int8
 
     def test_env_pin_outranks_tuned(self, monkeypatch):
         # the reproducibility knob beats the performance knob
         monkeypatch.setenv("REPRO_LCS_DTYPE", "int32")
-        assert resolve_wavefront_dtype(LCSTuning(128, "int8")) == jnp.int32
+        assert resolve_wavefront_dtype(LCSTuning("int8")) == jnp.int32
         monkeypatch.setenv("REPRO_LCS_DTYPE", "int8")
-        assert resolve_wavefront_dtype(LCSTuning(128, "int32")) == jnp.int8
+        assert resolve_wavefront_dtype(LCSTuning("int32")) == jnp.int8
 
 
 class TestPlannerPlumbing:
@@ -165,16 +160,16 @@ class TestPlannerPlumbing:
 
         # even with a live table on disk: plans must not probe it unasked
         monkeypatch.setenv("REPRO_TUNING_PATH", str(tmp_path / "T.json"))
-        _table_with({(4096, 3, 32): LCSTuning(256, "int8")}).save()
+        _table_with({(4096, 3, 32): LCSTuning("int8")}).save()
         assert CapacityPlanner().plan_tuning(4096, 3, 32) is None
 
     def test_autotune_on_reads_table(self, tmp_path, monkeypatch):
         from repro.api import CapacityPlanner
 
         monkeypatch.setenv("REPRO_TUNING_PATH", str(tmp_path / "T.json"))
-        _table_with({(4096, 3, 32): LCSTuning(256, "int8")}).save()
+        _table_with({(4096, 3, 32): LCSTuning("int8")}).save()
         planner = CapacityPlanner(autotune=True)
-        assert planner.plan_tuning(4096, 3, 32) == LCSTuning(256, "int8")
+        assert planner.plan_tuning(4096, 3, 32) == LCSTuning("int8")
         assert planner.plan_tuning(4096, 9, 32) is None  # miss -> defaults
 
     def test_execution_plan_flags(self):
@@ -191,7 +186,7 @@ class TestPlannerPlumbing:
 
 class TestTunedDispatchParity:
     def test_tuned_lcs_bit_identical(self):
-        """A tuned (block_b, dtype) through ops.lcs matches the default."""
+        """A tuned dtype through ops.lcs matches the default."""
         import numpy as np
 
         from repro.kernels.lcs import ops as lcs_ops
@@ -201,9 +196,9 @@ class TestTunedDispatchParity:
         a = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         b = rng.integers(0, 6, size=(B, L)).astype(np.int32)
         base = np.asarray(lcs_ops.lcs(jnp.asarray(a), jnp.asarray(b)))
-        for t in (LCSTuning(128, "int8"), LCSTuning(256, "int32")):
+        for t in (LCSTuning("int8"), LCSTuning("int32")):
             got = np.asarray(lcs_ops.lcs(
-                jnp.asarray(a), jnp.asarray(b), block_b=t.block_b,
+                jnp.asarray(a), jnp.asarray(b), mode="wavefront",
                 wavefront_dtype=resolve_wavefront_dtype(t),
             ))
             np.testing.assert_array_equal(got, base)

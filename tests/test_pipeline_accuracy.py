@@ -84,7 +84,9 @@ def test_brp_worst_accuracy(small_world):
 
 def test_kernel_backed_pipeline_identical(small_world):
     batch, forest, enc, cen_pairs, _ = small_world
-    res = run_anotherme(batch, forest, AnotherMeConfig(lcs_impl="kernel"))
+    res = run_anotherme(
+        batch, forest, AnotherMeConfig(lcs_impl="fused-interpret")
+    )
     assert res.similar_pairs == cen_pairs
 
 

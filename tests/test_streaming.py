@@ -163,7 +163,7 @@ def test_streaming_lcs_impls_and_cliques_bit_identical():
     """lcs_impl routes the same dispatch as the one-shot stage; cliques
     mode re-runs the Bron-Kerbosch oracle over the accumulated edges."""
     batch, forest = random_world(5)
-    for impl in ("wavefront", "fused-interpret", "pallas-interpret"):
+    for impl in ("wavefront", "fused-interpret"):
         cfg = EngineConfig(rho=2.0, lcs_impl=impl)  # cliques mode default
         want = AnotherMeEngine(forest, cfg).run(batch)
         res = StreamingEngine(forest, cfg).update_many(

@@ -13,8 +13,8 @@ brute-force windowed oracle.
   has type-LCS >= k, hence shares a shingle, hence is a candidate).
 * ``W >= L`` degenerates to the whole-trajectory engine bit-exactly;
   ``stride > 1`` restricts the oracle's offsets and still matches.
-* The windowed kernels (``lcs_windowed``, ``fused_windowed_score``) match
-  the numpy DP / the jnp reference exactly.
+* Windowed scoring (``score_indexed(window=...)``, ``fused_windowed_score``)
+  matches the numpy DP / the jnp reference exactly for every impl family.
 * The capacity planners accept window-id coordinates
   (``windows_per_row``) with per-TRAJECTORY shard ownership.
 * ``StreamingEngine`` rejects subtrajectory mode loudly (a growing world
@@ -253,7 +253,7 @@ def test_stride_gt_one_matches_strided_oracle(world):
 def test_lcs_windowed_matches_numpy_dp():
     import jax.numpy as jnp
 
-    from repro.kernels.lcs.ops import lcs_windowed
+    from repro.core.similarity import lcs_impl, score_indexed
 
     rng = np.random.default_rng(0)
     B, L, window = 33, 12, 5
@@ -270,14 +270,16 @@ def test_lcs_windowed_matches_numpy_dp():
         )
         for i in range(B)
     ], np.int32)
-    for mode in ("wavefront", "interpret"):
-        got = np.asarray(lcs_windowed(
-            jnp.asarray(a), jnp.asarray(b),
-            jnp.asarray(off_a), jnp.asarray(off_b),
-            jnp.asarray(len_a), jnp.asarray(len_b),
-            window=window, mode=mode,
-        ))
-        np.testing.assert_array_equal(got, want, err_msg=mode)
+    iota = jnp.arange(B, dtype=jnp.int32)
+    for impl in ("wavefront", "fused-interpret"):
+        lvl, _ = score_indexed(
+            jnp.asarray(a)[:, None, :], jnp.asarray(len_a),
+            jnp.asarray(b)[:, None, :], jnp.asarray(len_b), iota, iota,
+            jnp.ones((1,), jnp.float32), impl=lcs_impl(impl), window=window,
+            off_a=jnp.asarray(off_a), off_b=jnp.asarray(off_b),
+        )
+        np.testing.assert_array_equal(np.asarray(lvl)[:, 0], want,
+                                      err_msg=impl)
 
 
 def test_fused_windowed_kernel_matches_ref():
