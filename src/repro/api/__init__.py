@@ -24,7 +24,8 @@ Components (all replaceable independently):
   CapacityExceeded                                 typed admission refusal: an
       update/query over the max_resident_bytes budget (or past the retry
       doublings) is refused with the world state untouched
-  Instrumentation                                  phase timing/stats wrapper
+  Instrumentation                                  phase times, profiler spans,
+      compile counts per phase and stats of one run
   make_sharded_pipeline / plan_capacities / DistributedPlan
       the shard_map building blocks (for dry-runs and custom meshes)
 

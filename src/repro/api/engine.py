@@ -223,18 +223,19 @@ class AnotherMeEngine:
         """Run the full pipeline on one batch; same signature either way."""
         if self.plan.n_shards > 1:
             batch = self._padded(batch)
-        ctx = PipelineContext(
-            batch=batch, forest=self.forest, tables=self.tables,
-            betas=self.betas, config=self.config, backend=self.backend,
-            backend_ctx=self.backend_ctx, planner=self.planner,
-            instr=Instrumentation(),
-        )
-        for stage in self._stages:
-            stage.run(ctx)
-        return EngineResult(
-            scored=ctx.scored, similar_pairs=ctx.similar_pairs,
-            communities=ctx.communities, stats=ctx.instr.finalize(),
-        )
+        with Instrumentation() as instr:
+            ctx = PipelineContext(
+                batch=batch, forest=self.forest, tables=self.tables,
+                betas=self.betas, config=self.config, backend=self.backend,
+                backend_ctx=self.backend_ctx, planner=self.planner,
+                instr=instr,
+            )
+            for stage in self._stages:
+                stage.run(ctx)
+            return EngineResult(
+                scored=ctx.scored, similar_pairs=ctx.similar_pairs,
+                communities=ctx.communities, stats=instr.finalize(),
+            )
 
     # -- sharded-execution plumbing ------------------------------------------
 
@@ -389,53 +390,54 @@ class _ShardedEncodeJoinScoreStage:
         if config.score_prune:
             instr.record(num_pruned=int(np.asarray(out["pruned"]).sum()))
 
-        left = np.asarray(out["left"]).reshape(-1)
-        right = np.asarray(out["right"]).reshape(-1)
-        mss = np.asarray(out["mss"]).reshape(-1)
-        level_lcs = np.asarray(out["level_lcs"])
-        level_lcs = level_lcs.reshape(-1, level_lcs.shape[-1])
-        valid = left != PAD_ID
-        if subtraj is not None:
-            # fold scored window pairs to trajectory pairs (max-over-
-            # windows) before anything downstream sees them — communities,
-            # similar_pairs, and the returned scored buffer all speak
-            # trajectory ids
-            from repro.core.subtraj import aggregate_window_pairs
+        with instr.phase("results"):
+            left = np.asarray(out["left"]).reshape(-1)
+            right = np.asarray(out["right"]).reshape(-1)
+            mss = np.asarray(out["mss"]).reshape(-1)
+            level_lcs = np.asarray(out["level_lcs"])
+            level_lcs = level_lcs.reshape(-1, level_lcs.shape[-1])
+            valid = left != PAD_ID
+            overflow = jnp.asarray(int(np.asarray(out["overflow"]).sum()),
+                                   jnp.int32)
+            if subtraj is not None:
+                # fold scored window pairs to trajectory pairs (max-over-
+                # windows) before anything downstream sees them —
+                # communities, similar_pairs, and the returned scored
+                # buffer all speak trajectory ids
+                from repro.core.subtraj import aggregate_window_pairs
 
-            tl, tr, tlvl, tmss = aggregate_window_pairs(
-                left, right, level_lcs, mss, nw=subtraj[2]
-            )
-            ctx.scored = ScoredPairs(
-                left=jnp.asarray(tl), right=jnp.asarray(tr),
-                level_lcs=jnp.asarray(tlvl), mss=jnp.asarray(tmss),
-                count=jnp.asarray(tl.shape[0], jnp.int32),
-                overflow=jnp.asarray(
-                    int(np.asarray(out["overflow"]).sum()), jnp.int32),
-            )
-            ctx.similar_pairs = {
-                (int(a), int(b))
-                for a, b, m in zip(tl, tr, tmss)
-                if m > np.float32(config.rho)
-            }
-            instr.record(
-                num_candidates=int(valid.sum()),
-                num_window_pairs=int(valid.sum()),
-                num_traj_pairs=int(tl.shape[0]),
-                num_similar=len(ctx.similar_pairs),
-                subtraj_windows=subtraj[2],
-            )
-            return
-        ctx.scored = ScoredPairs(
-            left=jnp.asarray(left), right=jnp.asarray(right),
-            level_lcs=jnp.asarray(level_lcs), mss=jnp.asarray(mss),
-            count=jnp.asarray(int(valid.sum()), jnp.int32),
-            overflow=jnp.asarray(int(np.asarray(out["overflow"]).sum()), jnp.int32),
-        )
-        ctx.similar_pairs = gather_similar_pairs(out, rho=config.rho)
+                tl, tr, tlvl, tmss = aggregate_window_pairs(
+                    left, right, level_lcs, mss, nw=subtraj[2]
+                )
+                ctx.scored = ScoredPairs(
+                    left=jnp.asarray(tl), right=jnp.asarray(tr),
+                    level_lcs=jnp.asarray(tlvl), mss=jnp.asarray(tmss),
+                    count=jnp.asarray(tl.shape[0], jnp.int32),
+                    overflow=overflow,
+                )
+                ctx.similar_pairs = {
+                    (int(a), int(b))
+                    for a, b, m in zip(tl, tr, tmss)
+                    if m > np.float32(config.rho)
+                }
+            else:
+                ctx.scored = ScoredPairs(
+                    left=jnp.asarray(left), right=jnp.asarray(right),
+                    level_lcs=jnp.asarray(level_lcs), mss=jnp.asarray(mss),
+                    count=jnp.asarray(int(valid.sum()), jnp.int32),
+                    overflow=overflow,
+                )
+                ctx.similar_pairs = gather_similar_pairs(out, rho=config.rho)
         instr.record(
             num_candidates=int(valid.sum()),
             num_similar=len(ctx.similar_pairs),
         )
+        if subtraj is not None:
+            instr.record(
+                num_window_pairs=int(valid.sum()),
+                num_traj_pairs=int(ctx.scored.left.shape[0]),
+                subtraj_windows=subtraj[2],
+            )
 
     def _execute(self, ctx, dplan, key_fn, keys_np, subtraj=None):
         eng = self.engine

@@ -210,20 +210,21 @@ class ScoreStage:
             # windows); downstream stages and the result speak traj ids
             from repro.core.subtraj import aggregate_window_pairs
 
-            tl, tr, tlvl, tmss = aggregate_window_pairs(
-                cand.left, cand.right, level_lcs, mss, nw=subtraj[2]
-            )
-            ctx.similar_pairs = {
-                (int(a), int(b))
-                for a, b, m in zip(tl, tr, tmss)
-                if m > np.float32(cfg.rho)
-            }
-            ctx.scored = ScoredPairs(
-                left=jnp.asarray(tl), right=jnp.asarray(tr),
-                level_lcs=jnp.asarray(tlvl), mss=jnp.asarray(tmss),
-                count=jnp.asarray(tl.shape[0], jnp.int32),
-                overflow=cand.overflow,
-            )
+            with ctx.instr.phase("results"):
+                tl, tr, tlvl, tmss = aggregate_window_pairs(
+                    cand.left, cand.right, level_lcs, mss, nw=subtraj[2]
+                )
+                ctx.similar_pairs = {
+                    (int(a), int(b))
+                    for a, b, m in zip(tl, tr, tmss)
+                    if m > np.float32(cfg.rho)
+                }
+                ctx.scored = ScoredPairs(
+                    left=jnp.asarray(tl), right=jnp.asarray(tr),
+                    level_lcs=jnp.asarray(tlvl), mss=jnp.asarray(tmss),
+                    count=jnp.asarray(tl.shape[0], jnp.int32),
+                    overflow=cand.overflow,
+                )
             ctx.instr.record(
                 num_window_pairs=int(cand.count),
                 num_traj_pairs=int(tl.shape[0]),
@@ -232,13 +233,14 @@ class ScoreStage:
             )
             return
 
-        left_np = np.asarray(cand.left)
-        right_np = np.asarray(cand.right)
-        similar_mask = (left_np != PAD_ID) & (np.asarray(mss) > cfg.rho)
-        ctx.similar_pairs = {
-            (int(a), int(b))
-            for a, b in zip(left_np[similar_mask], right_np[similar_mask])
-        }
+        with ctx.instr.phase("results"):
+            left_np = np.asarray(cand.left)
+            right_np = np.asarray(cand.right)
+            similar_mask = (left_np != PAD_ID) & (np.asarray(mss) > cfg.rho)
+            ctx.similar_pairs = {
+                (int(a), int(b))
+                for a, b in zip(left_np[similar_mask], right_np[similar_mask])
+            }
         ctx.scored = ScoredPairs(
             left=cand.left, right=cand.right, level_lcs=level_lcs, mss=mss,
             count=cand.count, overflow=cand.overflow,

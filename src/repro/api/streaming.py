@@ -268,7 +268,11 @@ class StreamingEngine:
         engine-level ``window=N`` acts as a ceiling: rows expire after
         ``min(ttl, window)`` updates when both are set.
         """
-        instr = Instrumentation()
+        with Instrumentation() as instr:
+            return self._update(batch, ttl, instr)
+
+    def _update(self, batch: TrajectoryBatch, ttl: int | None,
+                instr: Instrumentation) -> EngineResult:
         self._xfer = {"bytes_in": 0, "pair_rows": 0, "key_rows": 0}
         places = np.asarray(batch.places, np.int32)
         if places.ndim != 2:

@@ -77,10 +77,11 @@ def connected_components(
         _, changed, it = state
         return changed & (it < max_iters)
 
-    labels, _, _ = jax.lax.while_loop(
-        cond, body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32))
-    )
-    return labels[:num_nodes]
+    with jax.named_scope("communities/labels"):
+        labels, _, _ = jax.lax.while_loop(
+            cond, body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32))
+        )
+        return labels[:num_nodes]
 
 
 def components_as_sets(labels: np.ndarray, min_size: int = 2) -> set[frozenset]:

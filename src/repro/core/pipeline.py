@@ -13,8 +13,8 @@ Behavioural fixes folded into the shim (ISSUE 1 satellites):
   silently rewritten to "wavefront"), and unknown impl names raise a
   ValueError listing the valid options.
 * The ``candidate_fn`` branch reports ``t_candidates`` (and no longer books
-  the baseline's hash cost under ``t_shingle``), so Fig. 9-style breakdowns
-  attribute hash cost correctly for every approach.
+  the baseline's hash cost under the key phase, ``t_keys``), so Fig. 9-style
+  breakdowns attribute hash cost correctly for every approach.
 """
 from __future__ import annotations
 

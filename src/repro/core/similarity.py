@@ -292,6 +292,8 @@ def score_indexed(table_a, len_a, table_b, len_b, left, right, betas, *,
 
     Scoring runs in chunks of :func:`score_chunk` pairs, so any pair count
     fits the device; the MSS is taken once over the whole ``level_lcs``.
+    The device work of each chunk is named ``score/gather`` and
+    ``score/lcs`` (the kernel impls name theirs in kernels/lcs/fused.py).
     """
     from repro.core.compat import on_tpu
     from repro.kernels.lcs import fused
@@ -316,15 +318,16 @@ def score_indexed(table_a, len_a, table_b, len_b, left, right, betas, *,
         lane_dense = False
 
         def chunk_lcs(ia, ib, oa, ob):
-            if W is None:
-                return multi_level_lcs(table_a[ia], len_a[ia], table_b[ib],
-                                       len_b[ib], impl=impl).T
-            return multi_level_lcs(
-                gather_windows(table_a[ia], oa, W),
-                jnp.clip(len_a[ia] - oa, 0, W),
-                gather_windows(table_b[ib], ob, W),
-                jnp.clip(len_b[ib] - ob, 0, W), impl=impl,
-            ).T
+            with jax.named_scope("score/gather"):
+                if W is None:
+                    ops = (table_a[ia], len_a[ia], table_b[ib], len_b[ib])
+                else:
+                    ops = (gather_windows(table_a[ia], oa, W),
+                           jnp.clip(len_a[ia] - oa, 0, W),
+                           gather_windows(table_b[ib], ob, W),
+                           jnp.clip(len_b[ib] - ob, 0, W))
+            with jax.named_scope("score/lcs"):
+                return multi_level_lcs(*ops, impl=impl).T
 
     chunk = score_chunk(H, L, lane_dense=lane_dense)
     lvl = _in_chunks(chunk_lcs, chunk, left, right, off_a, off_b)

@@ -50,23 +50,26 @@ def pairs_from_rows(
     (the same pair may appear under several shared shingles).  Shared by the
     single-device join and the distributed post-shuffle local join.
     """
-    keys, ids = jax.lax.sort((keys, ids), num_keys=1)
-    rank, valid = _runs(keys)
-    contrib = jnp.where(valid, rank, 0)
-    excl = jnp.cumsum(contrib) - contrib  # exclusive prefix
-    total = excl[-1] + contrib[-1]
+    with jax.named_scope("ssh/sort"):
+        keys, ids = jax.lax.sort((keys, ids), num_keys=1)
+    with jax.named_scope("ssh/runs"):
+        rank, valid = _runs(keys)
+        contrib = jnp.where(valid, rank, 0)
+        excl = jnp.cumsum(contrib) - contrib  # exclusive prefix
+        total = excl[-1] + contrib[-1]
 
-    p = jnp.arange(pair_capacity, dtype=jnp.int32)
-    row = jnp.searchsorted(excl, p, side="right").astype(jnp.int32) - 1
-    row = jnp.clip(row, 0, keys.shape[0] - 1)
-    t = p - excl[row]
-    partner = row - rank[row] + t
-    partner = jnp.clip(partner, 0, keys.shape[0] - 1)
-    ok = p < total
-    a = jnp.where(ok, ids[row], PAD_ID)
-    b = jnp.where(ok, ids[partner], PAD_ID)
-    overflow = jnp.maximum(total - pair_capacity, 0)
-    return jnp.minimum(a, b), jnp.maximum(a, b), overflow
+    with jax.named_scope("ssh/pairs_from_rows"):
+        p = jnp.arange(pair_capacity, dtype=jnp.int32)
+        row = jnp.searchsorted(excl, p, side="right").astype(jnp.int32) - 1
+        row = jnp.clip(row, 0, keys.shape[0] - 1)
+        t = p - excl[row]
+        partner = row - rank[row] + t
+        partner = jnp.clip(partner, 0, keys.shape[0] - 1)
+        ok = p < total
+        a = jnp.where(ok, ids[row], PAD_ID)
+        b = jnp.where(ok, ids[partner], PAD_ID)
+        overflow = jnp.maximum(total - pair_capacity, 0)
+        return jnp.minimum(a, b), jnp.maximum(a, b), overflow
 
 
 @functools.partial(jax.jit, static_argnames=("pair_capacity",))
@@ -89,7 +92,8 @@ def ssh_candidates(
         jnp.arange(n, dtype=jnp.int32) + jnp.asarray(id_offset, jnp.int32), s
     )
     lo, hi, overflow = pairs_from_rows(keys, ids, pair_capacity=pair_capacity)
-    return dedup_pairs(lo, hi, overflow=overflow)
+    with jax.named_scope("ssh/dedup"):
+        return dedup_pairs(lo, hi, overflow=overflow)
 
 
 @jax.jit

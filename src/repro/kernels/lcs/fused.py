@@ -96,9 +96,11 @@ def _gather_score(table_a, len_a, table_b, len_b, left, right, off_a, off_b,
     L = table_a.shape[-1]
     assert L < 127 and table_b.shape[1:] == table_a.shape[1:]
     W = None if window is None else min(window, L)
-    a = _lane_operand(table_a, len_a, left, PAD_CODE_A, off_a, W)
-    b = _lane_operand(table_b, len_b, right, PAD_CODE_B, off_b, W)
-    lvl = lcs_lanes(a, b, interpret=interpret).T
+    with jax.named_scope("score/gather"):
+        a = _lane_operand(table_a, len_a, left, PAD_CODE_A, off_a, W)
+        b = _lane_operand(table_b, len_b, right, PAD_CODE_B, off_b, W)
+    with jax.named_scope("score/lcs"):
+        lvl = lcs_lanes(a, b, interpret=interpret).T
     return lvl, mss_scores(lvl, betas)
 
 

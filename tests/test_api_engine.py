@@ -139,9 +139,9 @@ def test_candidate_timing_reported_in_both_branches(world):
         assert res.stats["t_candidates"] == pytest.approx(
             res.stats["t_keys"] + res.stats["t_join"]
         )
-    # the baseline's hash cost must NOT be booked under the shingle phase
+    # the baseline's hash cost must NOT be booked under the key phase
     # (a key-less backend leaves only context-manager noise there)
-    assert baseline.stats["t_shingle"] < baseline.stats["t_join"]
+    assert baseline.stats["t_keys"] < baseline.stats["t_join"]
 
 
 SHARDED_CODE = r"""
@@ -162,6 +162,9 @@ for backend in ("ssh", "minhash", "brp", "udf"):
 ssh = AnotherMeEngine(forest, EngineConfig(rho=3.0),
                       ExecutionPlan(n_shards=8)).run(batch)
 assert (0, 1) in ssh.similar_pairs
+# the fused program's score cost is inside t_execute: no made-up t_score
+assert "t_score" not in ssh.stats, sorted(ssh.stats)
+assert {"t_execute", "t_results", "compiles"} <= set(ssh.stats)
 
 # a denser world: ssh + minhash, sharded == single == legacy shard_map
 import numpy as np, jax.numpy as jnp
