@@ -6,9 +6,12 @@ TPU pipeline:    sort-merge join — one ``lax.sort`` by shingle key, then
 
   each sorted row r with in-run rank k contributes exactly k pairs (with the
   k earlier members of its run).  An exclusive cumsum of ranks assigns every
-  pair a unique output slot; a vectorized ``searchsorted`` inverts slot ->
-  (row, partner).  Total work O(R log R + P), zero data-dependent shapes,
-  zero wasted slots — the static-shape analogue of Spark's shuffle join.
+  pair a unique output slot, row-major.  Rows are expanded to their slots
+  by one merge sort of a marker per row with an entry per slot; running
+  scans carry each row's id, index and end onto its slots, and a second
+  sort brings the slots to the front in order.  Three sorts, running
+  scans and one gather (the partner's id), zero data-dependent shapes, zero
+  wasted slots — the static-shape analogue of Spark's shuffle join.
 
 Pairs appearing under multiple shingles are deduplicated with a second sort
 on the canonical (lo, hi) key, honouring the paper's "each pair is scored
@@ -47,8 +50,12 @@ def pairs_from_rows(
     """Exact-compact pair enumeration over flat (key, id) rows.
 
     Returns (lo [P_cap], hi [P_cap], overflow) — canonical but NOT deduped
-    (the same pair may appear under several shared shingles).  Shared by the
-    single-device join and the distributed post-shuffle local join.
+    (the same pair may appear under several shared shingles).  Slot ``p``
+    holds the ``t``-th pair of the row whose slots ``[excl, excl + rank)``
+    contain it: that row with the ``t``-th member of its run, ``t = p -
+    excl``.  Past ``pair_capacity`` the row-major tail is cut and counted
+    in ``overflow``.  Shared by the single-device join and the distributed
+    post-shuffle local join.
     """
     with jax.named_scope("ssh/sort"):
         keys, ids = jax.lax.sort((keys, ids), num_keys=1)
@@ -59,16 +66,43 @@ def pairs_from_rows(
         total = excl[-1] + contrib[-1]
 
     with jax.named_scope("ssh/pairs_from_rows"):
-        p = jnp.arange(pair_capacity, dtype=jnp.int32)
-        row = jnp.searchsorted(excl, p, side="right").astype(jnp.int32) - 1
-        row = jnp.clip(row, 0, keys.shape[0] - 1)
-        t = p - excl[row]
-        partner = row - rank[row] + t
-        partner = jnp.clip(partner, 0, keys.shape[0] - 1)
-        ok = p < total
-        a = jnp.where(ok, ids[row], PAD_ID)
+        r, cap = keys.shape[0], pair_capacity
+        # Merge one marker per row, keyed 2 * excl, with one entry per slot
+        # p, keyed 2p + 1 (uint32: neither key wraps).  The markers before
+        # slot p are rows 0..row(p), as excl rises with the row, and the
+        # first marker after it is row(p) + 1, at excl[row(p)] + rank[row(p)]
+        # (or total).  So running scans give each slot its row's id (a
+        # running sum of id steps), its row (a running count of markers)
+        # and that row's end; its partner is row + p - end.
+        step = ids - jnp.concatenate([jnp.zeros((1,), ids.dtype), ids[:-1]])
+        key, step = jax.lax.sort(
+            (
+                jnp.concatenate([
+                    2 * excl.astype(jnp.uint32),
+                    2 * jnp.arange(cap, dtype=jnp.uint32) + 1,
+                ]),
+                jnp.concatenate([step, jnp.zeros((cap,), step.dtype)]),
+            ),
+            num_keys=1,
+            is_stable=False,  # ties are markers at one key: any order
+        )
+        is_slot = (key & 1) == 1
+        half = (key >> 1).astype(jnp.int32)
+        a = jnp.cumsum(step)  # wraps in int32 and still telescopes exactly
+        row = jnp.cumsum(~is_slot, dtype=jnp.int32) - 1
+        end = jax.lax.cummin(jnp.where(is_slot, total, half), reverse=True)
+        partner = row + half - end
+        # the slot entries, to the front in slot order
+        _, a, partner = jax.lax.sort(
+            (jnp.where(is_slot, key, jnp.uint32(2**32 - 1)), a, partner),
+            num_keys=1,
+            is_stable=False,
+        )
+        ok = jnp.arange(cap, dtype=jnp.int32) < total
+        a = jnp.where(ok, a[:cap], PAD_ID)
+        partner = jnp.clip(partner[:cap], 0, r - 1)
         b = jnp.where(ok, ids[partner], PAD_ID)
-        overflow = jnp.maximum(total - pair_capacity, 0)
+        overflow = jnp.maximum(total - cap, 0)
         return jnp.minimum(a, b), jnp.maximum(a, b), overflow
 
 

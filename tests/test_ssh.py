@@ -1,5 +1,7 @@
+import functools
 import itertools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -96,3 +98,82 @@ def test_dedup_pairs_idempotent_and_canonical():
     }
     assert pairs == {(1, 2), (3, 5)}
     assert int(out.count) == 2
+
+
+def row_major_pairs(keys, ids, cap):
+    """Numpy model of the join's slot order: rows sorted stably by key; row
+    r with in-run rank k fills the next k slots with its pairs with the
+    run's members 0..k-1; the first ``cap`` slots are kept."""
+    order = np.argsort(keys, kind="stable")
+    keys, ids = keys[order], ids[order]
+    pairs, start = [], 0
+    for r in range(keys.shape[0]):
+        if r and keys[r] != keys[r - 1]:
+            start = r
+        if keys[r] != PAD_KEY:
+            pairs += [(ids[r], ids[j]) for j in range(start, r)]
+    lo = np.full(cap, PAD_ID, np.int32)
+    hi = np.full(cap, PAD_ID, np.int32)
+    kept = np.asarray(pairs[:cap], np.int32).reshape(-1, 2)
+    lo[: len(kept)] = kept.min(axis=1)
+    hi[: len(kept)] = kept.max(axis=1)
+    return lo, hi, max(len(pairs) - cap, 0)
+
+
+def _random_rows(seed, n, s, q):
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, q, size=(n, s)).astype(np.int32)
+    keys[rng.random((n, s)) < 0.3] = PAD_KEY
+    return keys.reshape(-1), np.repeat(np.arange(n, dtype=np.int32), s)
+
+
+def _case(name):
+    """(keys, ids, pair_capacity) of one named enumeration case."""
+    if name == "no_valid_key":
+        return np.full(24, PAD_KEY, np.int32), np.arange(24, dtype=np.int32), 16
+    if name == "all_singletons":
+        return np.arange(24, dtype=np.int32)[::-1].copy(), np.arange(24, dtype=np.int32), 16
+    if name == "one_run":  # C(24, 2) = 276 pairs, all in one run
+        return np.full(24, 5, np.int32), np.arange(24, dtype=np.int32) * 3, 512
+    if name == "total_eq_capacity":
+        return np.full(24, 5, np.int32), np.arange(24, dtype=np.int32), 276
+    if name == "total_over_capacity":
+        keys, ids = _random_rows(7, 40, 4, 6)
+        return keys, ids, 64
+    if name == "one_run_over_capacity":
+        return np.full(24, 5, np.int32), np.arange(24, dtype=np.int32), 100
+    if name == "id_offset":  # the sharded local join: global ids, PAD rows
+        keys, ids = _random_rows(3, 40, 4, 12)
+        ids = ids + np.int32(2**31 - 1000)
+        ids[keys == PAD_KEY] = PAD_ID
+        return keys, ids, 1 << 10
+    kind, seed = name.split("_")
+    keys, ids = _random_rows(int(seed), 40, 4, 10)
+    rng = np.random.default_rng(int(seed))
+    if kind == "shuffled":  # ids in no order, negative ones too
+        ids = rng.permutation(ids.shape[0]).astype(np.int32) - 50
+    cap = int(rng.integers(1, 1 << 9)) if kind == "cut" else 1 << 10
+    return keys, ids, cap
+
+
+@functools.partial(jax.jit, static_argnames="pair_capacity")
+def _pairs(keys, ids, pair_capacity):
+    return pairs_from_rows(keys, ids, pair_capacity=pair_capacity)
+
+
+@pytest.mark.parametrize(
+    "name",
+    ["no_valid_key", "all_singletons", "one_run", "total_eq_capacity",
+     "total_over_capacity", "one_run_over_capacity", "id_offset"]
+    + [f"{kind}_{seed}" for kind in ("random", "shuffled", "cut")
+       for seed in range(4)],
+)
+def test_pairs_from_rows_slot_for_slot(name):
+    """Every slot holds the pair the row-major enumeration puts there, the
+    tail past the capacity is cut, and the overflow counts it exactly."""
+    keys, ids, cap = _case(name)
+    lo, hi, overflow = _pairs(jnp.asarray(keys), jnp.asarray(ids), cap)
+    want_lo, want_hi, want_overflow = row_major_pairs(keys, ids, cap)
+    np.testing.assert_array_equal(np.asarray(lo), want_lo)
+    np.testing.assert_array_equal(np.asarray(hi), want_hi)
+    assert int(overflow) == want_overflow

@@ -1,10 +1,11 @@
-"""Compile the LCS scoring kernels for a described TPU v5e chip.
+"""Compile the LCS scoring kernels and the SSH join for a described TPU v5e.
 
 Nothing runs: each test lowers and compiles at real widths for one chip of
 a described ``v5e:2x2`` topology, so Mosaic refusals (block shapes,
 unsupported primitives, vector dtypes, relayouts, SMEM and HBM limits)
 surface here instead of on the chip.  Each kernel test asserts the Pallas
-kernel is in the compiled program (``tpu_custom_call``).
+kernel is in the compiled program (``tpu_custom_call``); the join test
+reads which ops its pair enumeration compiled to.
 
 The topology is described inside a fixture (never at import), so every
 test worker collects the same tests and only the worker that runs this
@@ -12,6 +13,7 @@ file loads the TPU compiler.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -115,3 +117,20 @@ def test_chunked_score_pairs_fits_one_chip(one_chip, monkeypatch, impl_name):
             + mem.output_size_in_bytes)
     assert used < V5E_HBM // 4, used
     assert ("tpu_custom_call" in compiled.as_text()) == (impl_name == "fused")
+
+
+def test_ssh_pair_enumeration_has_no_binary_search(one_chip):
+    """The join expands rows to pair slots with sorts and running scans: no
+    ``searchsorted`` loop in ``ssh/pairs_from_rows``, and one gather left
+    there (the partner's id).  The shape is small: on a CPU host the TPU
+    compiler takes tens of seconds for each sort of more than 16K elements."""
+    from repro.core.ssh import ssh_candidates
+
+    compiled = ssh_candidates.lower(
+        _spec(one_chip, (64, 120)), pair_capacity=1 << 13
+    ).compile()
+    ops = [line for line in compiled.as_text().splitlines()
+           if "ssh/pairs_from_rows" in line]
+    assert any(re.search(r"\) sort\(", line) for line in ops)
+    assert not any("searchsorted" in line or " while(" in line for line in ops)
+    assert sum(bool(re.search(r"= \S+ gather\(", line)) for line in ops) <= 1
