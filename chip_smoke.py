@@ -327,16 +327,6 @@ def show_spread(jax, what: str, arrays: dict, chips: int) -> None:
         f"{[u.get('peak_bytes_in_use') for u in used]}")
 
 
-def mesh_outputs(eng, batch) -> dict:
-    """The candidate/scored buffers of ``eng``'s mesh program, as it left
-    them on the devices: its cached program run once more on ``batch``
-    (the code table is built inside the program and never leaves it)."""
-    runner = list(eng._runner_cache.values())[-1]
-    padded = eng._padded(batch)
-    out = runner(padded.places, padded.places, padded.lengths, eng.tables)
-    return {k: out[k] for k in ("left", "right", "level_lcs", "mss")}
-
-
 def run_four_chips(jax, chips: int) -> None:
     from repro.api import AnotherMeEngine, ExecutionPlan, StreamingEngine
 
@@ -358,7 +348,11 @@ def run_four_chips(jax, chips: int) -> None:
         mesh_ids = sorted(d.id for d in eng.mesh().devices.flat)
         check(len(set(mesh_ids)) == chips,
               f"{mode}: mesh spans {chips} distinct devices {mesh_ids}")
-        show_spread(jax, mode, mesh_outputs(eng, batch), chips)
+        # the scored buffers as the mesh left them on the devices
+        sc = got.scored
+        show_spread(jax, mode, dict(left=sc.left, right=sc.right,
+                                    level_lcs=sc.level_lcs, mss=sc.mss),
+                    chips)
         check(got.similar_pairs == want.similar_pairs
               and got.communities == want.communities,
               f"{chips} shards, {mode}: similar pairs and communities equal "

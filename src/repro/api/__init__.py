@@ -26,8 +26,10 @@ Components (all replaceable independently):
       doublings) is refused with the world state untouched
   Instrumentation                                  phase times, profiler spans,
       compile counts per phase and stats of one run
-  make_sharded_pipeline / plan_capacities / DistributedPlan
-      the shard_map building blocks (for dry-runs and custom meshes)
+  MeshStages                                       the one-shot job as
+      staged shard_map programs, each planned from counts made in the mesh
+  plan_capacities / DistributedPlan                 a plan's capacities;
+      the host-numpy planner is the oracle of the in-mesh counts
 
 The legacy ``repro.core.run_anotherme`` / ``AnotherMeConfig`` remain as a
 deprecation shim over this API.
@@ -44,8 +46,8 @@ from repro.api.engine import (
 from repro.api.errors import CapacityExceeded
 from repro.api.instrumentation import Instrumentation
 from repro.api.sharded import (
-    DistributedPlan, StreamJoinPlan, StreamShardPlan, gather_similar_pairs,
-    make_distributed_anotherme, make_sharded_pipeline,
+    DistributedPlan, MeshStages, StreamJoinPlan, StreamShardPlan,
+    gather_similar_pairs,
     make_streaming_join_pipeline, make_streaming_score_pipeline,
     pad_to_shards, plan_capacities, plan_stream_capacities,
     plan_stream_join, sticky_join_plan,

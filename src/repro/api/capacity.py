@@ -104,47 +104,6 @@ class CapacityPlanner:
 
         return TuningTable.load().lookup(pairs, levels, length)
 
-    def plan_sharded(
-        self,
-        keys_np,
-        n_shards: int,
-        *,
-        slack: float | None = None,
-        score_mode: str = "replicate",
-        lengths_np=None,
-        prune_tau: float | None = None,
-        betas_sum: float = 1.0,
-        overlap_chunks: int = 1,
-        windows_per_row: int = 1,
-    ):
-        """Exact per-bucket capacity plan for the sharded (shard_map) path.
-
-        Delegates to :func:`repro.api.sharded.plan_capacities`, which sizes
-        every stage — shuffle 1, the local join, the pair-dedup shuffle and
-        (for ``score_mode="shuffle"``) the per-owner code-gather hops — from
-        actual per-destination loads under the device's own hashes, not a
-        uniform-hash bound.  ``slack`` defaults to this planner's slack.
-
-        With ``prune_tau``/``lengths_np`` the plan additionally sizes the
-        post-prune pair buffer (``DistributedPlan.pruned_cap``) from the
-        exact per-shard survivor counts of the MSS upper-bound pruning
-        pass.
-
-        ``windows_per_row > 1`` declares subtrajectory keys (one key row
-        per sliding window, ``nw`` windows per trajectory): loads stay
-        per-window, shard ownership stays per-trajectory, and
-        ``lengths_np`` must then be per-window lengths.
-        """
-        from repro.api.sharded import plan_capacities
-
-        return plan_capacities(
-            keys_np, n_shards,
-            slack=self.slack if slack is None else slack,
-            score_mode=score_mode,
-            lengths_np=lengths_np, prune_tau=prune_tau, betas_sum=betas_sum,
-            overlap_chunks=overlap_chunks, windows_per_row=windows_per_row,
-        )
-
     def plan_stream_join(
         self, keys_flat, n_shards: int, stats, *, floor_pow2: int = 4
     ):

@@ -40,7 +40,8 @@ from repro.api.backends import (
 from repro.api.capacity import CapacityPlanner
 from repro.api.instrumentation import Instrumentation
 from repro.api.sharded import (
-    gather_similar_pairs, make_sharded_pipeline, pad_to_shards,
+    DistributedPlan, MeshStages, pow2_capacity,
+    pad_to_shards, plan_join, plan_score, sticky_plan, unpack_stats,
 )
 from repro.api.stages import (
     CandidateStage, CommunitiesStage, EncodeStage, PipelineContext, ScoreStage,
@@ -50,7 +51,7 @@ from repro.core import compat
 from repro.core.encoding import SemanticForest, encode_types, forest_tables
 from repro.core.pipeline import AnotherMeResult as EngineResult
 from repro.core.similarity import default_betas
-from repro.core.types import EncodedBatch, PAD_ID, ScoredPairs, TrajectoryBatch
+from repro.core.types import EncodedBatch, ScoredPairs, TrajectoryBatch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,9 +115,13 @@ class ExecutionPlan:
     #                                 kernel parameters; resolved eagerly,
     #                                 bit-identical results guaranteed
     overlap_chunks: int = 1         # shuffle-mode gather/score overlap:
-    #                                 split the pair buffer into this many
-    #                                 chunks (power of two) so chunk i+1's
-    #                                 owner hops run while chunk i scores;
+    #                                 the least number of chunks (power of
+    #                                 two) the pair buffer is split into so
+    #                                 chunk i+1's owner hops run while chunk
+    #                                 i scores; AnotherMeEngine.run raises
+    #                                 it to what keeps one chunk's hop
+    #                                 buffers within a quarter of a
+    #                                 device's memory (sharded.plan_score);
     #                                 ignored in "replicate" mode and on
     #                                 the delta_join="device" scoring path
     #                                 (its pairs rest in-mesh under the
@@ -150,8 +155,8 @@ class AnotherMeEngine:
     ):
         if plan.lcs_impl is not None:
             # the plan's override folds into the config so every stage —
-            # single-device ScoreStage or the fused shard_map stage — reads
-            # one authoritative lcs_impl
+            # single-device ScoreStage or the staged shard_map programs —
+            # reads one authoritative lcs_impl
             config = dataclasses.replace(config, lcs_impl=plan.lcs_impl)
         validate_lcs_impl(config.lcs_impl)
         if plan.n_shards < 1:
@@ -214,8 +219,8 @@ class AnotherMeEngine:
                 _ShardedEncodeJoinScoreStage(self), CommunitiesStage(),
             )
         self._mesh = None
-        self._runner_cache: dict = {}
-        self._plan_cache: dict = {}
+        self._stages_cache: dict = {}
+        self._sticky: dict = {}        # batch shape -> sticky plan
 
     # -- public entry point --------------------------------------------------
 
@@ -265,50 +270,36 @@ class AnotherMeEngine:
             )
         return self._mesh
 
-    def _sharded_runner(self, dplan, key_fn, shapes, subtraj=None):
-        from repro.core.similarity import wavefront_dtype_from_env
-
-        # tuning resolves HERE — eagerly, at runner-build time — into
-        # static kernel args (never inside the trace); a miss (autotune
-        # off, no table, no matching cell) is None = untuned defaults
-        tuning = self.planner.plan_tuning(
-            dplan.pruned_cap or dplan.scored_cap,
-            self.forest.num_levels, shapes[1][1],
-        )
-        # the runner build resolves REPRO_LCS_DTYPE (lcs_impl_fn); keying
-        # the cache on the resolved dtype AND the tuning record keeps the
-        # A/B probe and the tuning table live across runs of one engine,
-        # matching the single-device path
-        cache_key = (
-            dplan, self.plan.score_mode, self.config.lcs_impl,
-            self.config.score_prune, key_fn is None, shapes,
-            wavefront_dtype_from_env(), tuning, subtraj,
-        )
-        runner = self._runner_cache.get(cache_key)
-        if runner is None:
-            runner = make_sharded_pipeline(
-                self.mesh(), dplan, betas=self.betas, key_fn=key_fn,
-                axis_name=self.plan.axis_name, score_mode=self.plan.score_mode,
+    def _mesh_stages(self, key_fn, local_n: int, subtraj=None) -> MeshStages:
+        """The staged shard_map programs for one batch shape (cached, so
+        repeated runs reuse every compiled program)."""
+        key = (key_fn is None, local_n, subtraj)
+        stages = self._stages_cache.get(key)
+        if stages is None:
+            stages = MeshStages(
+                self.mesh(), n_shards=self.plan.n_shards, local_n=local_n,
+                betas=self.betas, key_fn=key_fn,
+                axis_name=self.plan.axis_name,
+                score_mode=self.plan.score_mode,
                 lcs_impl=self.config.lcs_impl,
                 score_prune=self.config.score_prune,
-                prune_tau=self.config.rho,
-                tuning=tuning,
-                subtraj=subtraj,
+                prune_tau=self.config.rho, subtraj=subtraj,
+                rho=self.config.rho,
             )
-            self._runner_cache[cache_key] = runner
-        return runner
+            self._stages_cache[key] = stages
+        return stages
 
 
 class _ShardedEncodeJoinScoreStage:
-    """Encode + Candidate + Score fused into one shard_map program (Fig. 5).
+    """Encode + Candidate + Score as staged shard_map programs (Fig. 5).
 
-    The device program is fully resident: raw places are sharded once,
-    encoding runs in-mesh, and the code table never transits the host.
-    Capacity planning works from the coarsest-level ("type") view only — a
-    single [N, L] host gather, the driver's statistics pass — from which the
-    backend's actual join keys are built (plan_sharded); key-producing
-    backends rebuild keys on-device per shard, key-less ones ("udf") have
-    their host keys shuffled in.  A capacity bust retries with doubled
+    The device work is fully resident: raw places are sharded once,
+    encoding runs in-mesh, and neither the code table nor the join keys
+    transit the host (keyless backends, "udf", build their keys on the
+    host and have them shuffled in).  Every capacity is planned from
+    counts the stage before made in the mesh (:class:`MeshStages`), as a
+    power of two kept sticky per batch shape, so jobs of one size run the
+    programs the first one compiled.  A capacity bust retries with larger
     buffers, like the single-device planner.
     """
 
@@ -320,6 +311,7 @@ class _ShardedEncodeJoinScoreStage:
     def run(self, ctx: PipelineContext) -> None:
         eng = self.engine
         plan, config, instr = eng.plan, eng.config, ctx.instr
+        batch, n = ctx.batch, plan.n_shards
 
         # subtrajectory mode: (window, stride, nw) from the PADDED length —
         # static shape facts every layer below keys its caches on
@@ -327,139 +319,201 @@ class _ShardedEncodeJoinScoreStage:
         if config.subtraj_window is not None:
             from repro.core.subtraj import num_windows
 
-            L = int(ctx.batch.places.shape[1])
+            L = int(batch.places.shape[1])
             subtraj = (
                 min(config.subtraj_window, L), config.subtraj_stride,
                 num_windows(L, config.subtraj_window, config.subtraj_stride),
             )
 
-        with instr.phase("keys"):
-            # coarsest-level view for planning only: [N, L], not the
-            # [N, n_levels, L] code table (which stays device-resident)
-            types = encode_types(ctx.batch.places, ctx.tables)
-            plan_encoded = EncodedBatch(codes=types[:, None, :],
-                                        lengths=ctx.batch.lengths)
-            keys = ctx.backend.join_keys(plan_encoded, ctx.batch,
-                                         ctx.backend_ctx)
-            keys_np = np.asarray(keys)
-        ctx.keys = keys
-
-        # plan capacities host-side once per distinct key matrix; warm runs
-        # (same data) skip the numpy planning pass and any retry doublings
-        with instr.phase("plan"):
-            plan_key = (keys_np.shape, hash(keys_np.tobytes()),
-                        plan.score_mode, subtraj)
-            dplan = eng._plan_cache.get(plan_key)
-            if dplan is None:
-                prune_kw = {}
-                if config.score_prune:
-                    # windowed pairs prune on per-WINDOW lengths: the key
-                    # matrix has one row per window, and the MSS bound of a
-                    # window pair is betas_sum * min of the window lengths
-                    if subtraj is None:
-                        lengths_np = np.asarray(ctx.batch.lengths)
-                    else:
-                        from repro.core.subtraj import window_lengths
-
-                        lengths_np = window_lengths(
-                            np.asarray(ctx.batch.lengths),
-                            max_len=int(ctx.batch.places.shape[1]),
-                            window=subtraj[0], stride=subtraj[1],
-                        )
-                    prune_kw = dict(
-                        lengths_np=lengths_np,
-                        prune_tau=config.rho,
-                        betas_sum=float(np.asarray(eng.betas, np.float32).sum()),
-                    )
-                dplan = eng.planner.plan_sharded(
-                    keys_np, plan.n_shards, slack=plan.shard_slack,
-                    score_mode=plan.score_mode,
-                    overlap_chunks=plan.overlap_chunks,
-                    windows_per_row=1 if subtraj is None else subtraj[2],
-                    **prune_kw,
-                )
         key_fn = ctx.backend.shard_key_fn(ctx.backend_ctx)
+        first = batch.places
+        if key_fn is None:
+            with instr.phase("keys"):
+                # a keyless backend's keys exist only on the host
+                types = encode_types(batch.places, ctx.tables)
+                ctx.keys = ctx.backend.join_keys(
+                    EncodedBatch(codes=types[:, None, :],
+                                 lengths=batch.lengths),
+                    batch, ctx.backend_ctx)
+                first = jnp.asarray(ctx.keys)
 
-        with instr.phase("execute"):
-            out, dplan = self._execute(ctx, dplan, key_fn, keys_np, subtraj)
-        eng._plan_cache[plan_key] = dplan
-        instr.record(
-            shard_plan=dataclasses.asdict(dplan),
-            join_overflow=int(np.asarray(out["overflow"]).sum()),
-        )
-        if config.score_prune:
-            instr.record(num_pruned=int(np.asarray(out["pruned"]).sum()))
+        local_n = batch.num_trajectories // n
+        stages = eng._mesh_stages(key_fn, local_n, subtraj)
+        shape = (first.shape, batch.places.shape, ctx.tables.shape, subtraj)
+        prev = eng._sticky.get(shape)
+        dplan, out, counts = self._execute(
+            ctx, stages, prev, first, local_n, instr)
+        overflow = np.int32(self._overflow(counts))
+        count = np.int32(counts["score"]["resting"].sum())
 
         with instr.phase("results"):
-            left = np.asarray(out["left"]).reshape(-1)
-            right = np.asarray(out["right"]).reshape(-1)
-            mss = np.asarray(out["mss"]).reshape(-1)
-            level_lcs = np.asarray(out["level_lcs"])
-            level_lcs = level_lcs.reshape(-1, level_lcs.shape[-1])
-            valid = left != PAD_ID
-            overflow = jnp.asarray(int(np.asarray(out["overflow"]).sum()),
-                                   jnp.int32)
-            if subtraj is not None:
+            if subtraj is None:
+                dplan = self._similar(ctx, stages, dplan, out)
+                # the scored buffers stay on the devices
+                ctx.scored = ScoredPairs(
+                    left=out[0], right=out[1], level_lcs=out[2], mss=out[3],
+                    count=count, overflow=overflow,
+                )
+            else:
                 # fold scored window pairs to trajectory pairs (max-over-
                 # windows) before anything downstream sees them —
                 # communities, similar_pairs, and the returned scored
                 # buffer all speak trajectory ids
                 from repro.core.subtraj import aggregate_window_pairs
 
+                left, right, level_lcs, mss = (np.asarray(x) for x in out)
                 tl, tr, tlvl, tmss = aggregate_window_pairs(
                     left, right, level_lcs, mss, nw=subtraj[2]
                 )
                 ctx.scored = ScoredPairs(
-                    left=jnp.asarray(tl), right=jnp.asarray(tr),
-                    level_lcs=jnp.asarray(tlvl), mss=jnp.asarray(tmss),
-                    count=jnp.asarray(tl.shape[0], jnp.int32),
-                    overflow=overflow,
+                    left=tl, right=tr, level_lcs=tlvl, mss=tmss,
+                    count=np.int32(tl.shape[0]), overflow=overflow,
                 )
                 ctx.similar_pairs = {
                     (int(a), int(b))
                     for a, b, m in zip(tl, tr, tmss)
                     if m > np.float32(config.rho)
                 }
-            else:
-                ctx.scored = ScoredPairs(
-                    left=jnp.asarray(left), right=jnp.asarray(right),
-                    level_lcs=jnp.asarray(level_lcs), mss=jnp.asarray(mss),
-                    count=jnp.asarray(int(valid.sum()), jnp.int32),
-                    overflow=overflow,
-                )
-                ctx.similar_pairs = gather_similar_pairs(out, rho=config.rho)
+        eng._sticky[shape] = dplan
+        self._record(instr, dplan, counts, overflow)
         instr.record(
-            num_candidates=int(valid.sum()),
+            num_candidates=int(count),
             num_similar=len(ctx.similar_pairs),
         )
         if subtraj is not None:
             instr.record(
-                num_window_pairs=int(valid.sum()),
+                num_window_pairs=int(count),
                 num_traj_pairs=int(ctx.scored.left.shape[0]),
                 subtraj_windows=subtraj[2],
             )
 
-    def _execute(self, ctx, dplan, key_fn, keys_np, subtraj=None):
+    def _similar(self, ctx, stages, dplan, out):
+        """The similar-pair set on the host: each shard's similar pairs are
+        counted and moved to the front of a sticky power-of-two buffer in
+        the mesh, and only that buffer is copied.  Returns the plan with
+        the buffer's capacity."""
+        n = dplan.n_shards
+        sims = np.asarray(stages.similar_count()(out[3])[0])
+        # the count is exact, so the slack only keeps the buffer sticky
+        slack = max(self.engine.plan.shard_slack, 1.0)
+        cap = max(pow2_capacity(sims.max(), slack), dplan.similar_cap)
+        left, right = (np.asarray(x).reshape(n, cap)
+                       for x in stages.similar(cap)(out[0], out[1], out[3]))
+        ctx.similar_pairs = set(zip(*(
+            np.concatenate([x[s, :k] for s, k in enumerate(sims)]).tolist()
+            for x in (left, right))))
+        return dataclasses.replace(dplan, similar_cap=cap)
+
+    @staticmethod
+    def _overflow(counts) -> int:
+        return int(sum(counts[stage][key].sum() for stage, key in (
+            ("route", "overflow"), ("join", "overflow"),
+            ("join", "route_overflow"), ("score", "overflow"))))
+
+    def _execute(self, ctx, stages, prev, first, local_n, instr):
+        """Plan and run the route, join and score programs.  Returns (the
+        plan, the resting (left, right, level_lcs, mss), each stage's
+        counts)."""
         eng = self.engine
-        batch = ctx.batch
-        first = jnp.asarray(keys_np) if key_fn is None else batch.places
-        shapes = (first.shape, batch.places.shape, ctx.tables.shape)
-        for attempt in range(eng.planner.max_retries + 1):
-            runner = eng._sharded_runner(dplan, key_fn, shapes, subtraj)
-            out = runner(first, batch.places, batch.lengths, ctx.tables)
-            out["mss"].block_until_ready()
-            if int(np.asarray(out["overflow"]).sum()) == 0:
-                break
-            if attempt < eng.planner.max_retries:
-                dplan = dataclasses.replace(
-                    dplan,
-                    shingle_route_cap=dplan.shingle_route_cap * 2,
-                    local_pair_cap=dplan.local_pair_cap * 2,
-                    pair_route_cap=dplan.pair_route_cap * 2,
-                    scored_cap=dplan.scored_cap * 2,
-                    owner_route_cap=dplan.owner_route_cap * 2,
-                    pruned_cap=dplan.pruned_cap * 2,
-                    chunk_hop_cap=dplan.chunk_hop_cap * 2,
-                    chunk_rest_cap=dplan.chunk_rest_cap * 2,
-                )
-        return out, dplan
+        plan, batch = eng.plan, ctx.batch
+        n, slack, retries = plan.n_shards, plan.shard_slack, \
+            eng.planner.max_retries
+        args = (first, batch.places, batch.lengths, ctx.tables)
+
+        retried = 0
+
+        def grown(cap, need):
+            return max(cap * 2, pow2_capacity(need, slack))
+
+        with instr.phase("plan"):
+            # the key loads of shuffle 1 (a pass of their own before the
+            # first plan), then each shard's pre-dedup join size
+            if prev is None:
+                loads = np.asarray(stages.loads()(*args)[0])
+                cap1 = pow2_capacity(loads.max(), slack)
+            else:
+                cap1 = prev.shingle_route_cap
+            for attempt in range(retries + 1):
+                rk, rid, st = stages.route(cap1)(*args)
+                route = unpack_stats(st, stages.layout("route"))
+                if route["overflow"].sum() == 0 or attempt == retries:
+                    break
+                cap1 = grown(cap1, route["loads"].max())
+                retried += 1
+            caps = plan_join(route, n, slack)
+            caps["shingle_route_cap"] = cap1
+            if prev is not None:
+                caps = {k: max(v, getattr(prev, k)) for k, v in caps.items()}
+
+        with instr.phase("execute"):
+            with instr.phase("join"):
+                pair_cap = caps["local_pair_cap"]
+                route_cap = caps["pair_route_cap"]
+                for attempt in range(retries + 1):
+                    left, right, st = stages.join(pair_cap, route_cap)(
+                        rk, rid, batch.lengths)
+                    join = unpack_stats(st, stages.layout("join"))
+                    busted = join["overflow"].sum() \
+                        + join["route_overflow"].sum()
+                    if busted == 0 or attempt == retries:
+                        break
+                    if join["overflow"].sum():
+                        pair_cap *= 2
+                    route_cap = grown(route_cap, join["route_loads"].max())
+                    retried += 1
+            H, L = eng.forest.num_levels, int(batch.places.shape[1])
+            dplan = sticky_plan(DistributedPlan(
+                n_shards=n, local_n=local_n, shingle_route_cap=cap1,
+                local_pair_cap=pair_cap, pair_route_cap=route_cap,
+                **plan_score(
+                    join, n, slack, score_mode=plan.score_mode,
+                    score_prune=eng.config.score_prune,
+                    min_chunks=max(plan.overlap_chunks,
+                                   prev.n_chunks if prev else 1),
+                    hop_row_bytes=2 * 4 * (-(-H * L // 128) * 128),
+                    memory_bytes=compat.device_memory_bytes(),
+                ),
+            ), prev)
+            with instr.phase("score"):
+                for attempt in range(retries + 1):
+                    tuning = eng.planner.plan_tuning(
+                        dplan.pruned_cap or dplan.scored_cap, H, L)
+                    *out, st = stages.score(dplan, tuning)(
+                        left, right, batch.places, batch.lengths, ctx.tables)
+                    out[-1].block_until_ready()
+                    score = unpack_stats(st, stages.layout("score"))
+                    if score["overflow"].sum() == 0 or attempt == retries:
+                        break
+                    dplan = dataclasses.replace(dplan, **{
+                        k: getattr(dplan, k) * 2 for k in (
+                            "scored_cap", "owner_route_cap", "pruned_cap",
+                            "chunk_hop_cap", "chunk_rest_cap")})
+                    retried += 1
+        instr.record(retries=retried)
+        return dplan, out, dict(route=route, join=join, score=score)
+
+    def _record(self, instr, dplan, counts, overflow):
+        """The plan and the in-mesh counters of the job."""
+        n = dplan.n_shards
+        slots = n * n
+        hop_slots = slots * dplan.n_chunks * (
+            dplan.chunk_hop_cap if dplan.n_chunks > 1
+            else dplan.owner_route_cap)
+        route, join, score = counts["route"], counts["join"], counts["score"]
+        instr.exchange("key_route", int(route["carried"][0, 0]),
+                       slots * dplan.shingle_route_cap)
+        instr.exchange("dedup_route", int(join["carried"][0, 0]),
+                       slots * dplan.pair_route_cap)
+        if self.engine.plan.score_mode == "shuffle":
+            instr.exchange("owner_hop1", int(score["carried_hop1"][0, 0]),
+                           hop_slots)
+            instr.exchange("owner_hop2", int(score["carried_hop2"][0, 0]),
+                           hop_slots)
+        scored = [int(x) for x in score["resting"][:, 0]]
+        instr.record(
+            shard_plan=dataclasses.asdict(dplan), n_shards=n,
+            join_overflow=int(overflow), scored_per_chip=scored,
+            scored_max=max(scored), scored_min=min(scored),
+        )
+        if self.engine.config.score_prune:
+            instr.record(num_pruned=int(score["pruned"].sum()))

@@ -26,11 +26,21 @@ Stats key conventions:
                  fold included)
   t_communities  phase (iv)  community detection
 
-Sharded runs fuse the join and score phases into one shard_map program;
-they record ``t_plan`` (host capacity planning) and ``t_execute`` (the fused
-device program) instead of ``t_join``/``t_score``, and ``t_candidates``
-then covers keys + plan + execute.  The score cost is inside
-``t_execute`` and cannot be split from it without extra device syncs.
+Sharded runs stage the join and score as shard_map programs; they record
+``t_plan`` (the key shuffle program, whose counts plan the rest) and
+``t_execute`` (the join and score programs and the score plan between
+them), which holds ``t_join`` (the join program, shuffle 2 and the dedup,
+ending in the copy of its counts) and ``t_score`` (the owner hops and the
+LCS, ending in a device sync); ``t_candidates`` then covers keys + plan +
+join.  ``t_keys`` is 0 there unless the backend builds its keys on the
+host.  Their counters, made in the mesh:
+
+  exchange           {round: [rows carried, slots shipped]} for each
+                     all_to_all round: key_route, dedup_route and, in
+                     shuffle mode, owner_hop1 and owner_hop2
+  scored_per_chip    the pairs scored on each chip; scored_max and
+                     scored_min their largest and smallest
+  shard_plan         every capacity of the plan the job ran; n_shards
 
 Compile counters (no ``t_`` prefix: they are not the wall time of a
 phase), over the ``with Instrumentation()`` block of one run:
@@ -146,16 +156,21 @@ class Instrumentation:
     def record(self, **values) -> None:
         self.stats.update(values)
 
+    def exchange(self, name: str, rows: int, slots: int) -> None:
+        """Count one ``all_to_all`` round: the rows it carried and the
+        slots it shipped (``n_shards**2`` times its bucket capacity)."""
+        self.stats.setdefault("exchange", {})[name] = [rows, slots]
+
     def finalize(self) -> dict:
         """Derive the composite keys and return the stats dict."""
         s = self.stats
         s.setdefault("t_keys", 0.0)
-        if "t_join" in s:
-            s["t_candidates"] = s["t_keys"] + s["t_join"]
-        elif "t_execute" in s:  # sharded: join+score fused into one program
+        if "t_execute" in s:  # sharded: the key route plans the join
             s["t_candidates"] = (
-                s["t_keys"] + s.get("t_plan", 0.0) + s["t_execute"]
+                s["t_keys"] + s.get("t_plan", 0.0) + s["t_join"]
             )
+        elif "t_join" in s:
+            s["t_candidates"] = s["t_keys"] + s["t_join"]
         by_phase = {k: list(v) for k, v in self.compiles_by_phase.items()}
         s["compiles"] = sum(n for n, _ in by_phase.values())
         s["compile_s"] = sum(t for _, t in by_phase.values())

@@ -425,7 +425,7 @@ def make_query_probe_pipeline(
             trace_counter[0] += 1  # per compile, not per query batch
         valid = keys != PAD_KEY
         dest = _positive_hash(keys) % n_shards
-        (rk, rq), o1 = _route(
+        (rk, rq), o1, _, _ = _route(
             (keys, qids), dest, valid,
             n_shards=n_shards, capacity=plan.key_route_cap,
             pads=(PAD_KEY, PAD_ID), axis_name=axis_name,

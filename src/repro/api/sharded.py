@@ -28,17 +28,24 @@ backend's driver-side wall).  Everything after the keys — route, join,
 dedup, score — is one shared implementation.
 
 Static capacities (rows per destination bucket, pairs per shard) are planned
-host-side from exact cardinalities (plan_capacities) using the *same* int32
-hashes the device program applies, and every stage carries an overflow
-counter, so a capacity bust is detected, never silent.
+from exact counts: the engine runs the job as staged programs
+(:class:`MeshStages`), each planned from the small count tables the stage
+before made in the mesh, with every capacity a power of two kept sticky
+per batch shape (:func:`plan_join`, :func:`plan_score`, :func:`sticky_plan`).
+``plan_capacities`` replays the same counts on the host with numpy copies
+of the device hashes; it is the tests' oracle, and no run plans by it.  Every stage carries an overflow counter, so a capacity
+bust is detected, never silent.
+
+Rows move between shards by sorts, not gathers: a destination bucket is a
+contiguous slice of the rows sorted by destination (:func:`_bucket`).
 
 The same code runs on 1 device (n_shards=1 degenerates to the single-device
 pipeline) and on the 512-device production mesh in the dry-run.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +53,6 @@ import numpy as np
 
 from repro.core import compat
 from repro.core.encoding import encode_codes
-from repro.core.shingling import shingles_from_types
 from repro.core.similarity import PRUNE_EPS, mss_upper_bound, score_indexed
 from repro.core.ssh import _runs, dedup_pairs, pairs_from_rows
 from repro.core.types import PAD_ID, PAD_KEY
@@ -78,34 +84,110 @@ def _pair_hash_np(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
     return np.abs(h)
 
 
+def _dest_counts(dest: jnp.ndarray, valid: jnp.ndarray, n_shards: int):
+    """int32 [n_shards]: valid rows bound for each destination."""
+    return jnp.stack([
+        jnp.sum(valid & (dest == d)) for d in range(n_shards)
+    ]).astype(jnp.int32)
+
+
+_KEY_LIMIT = np.iinfo(np.int32).max  # a packed sort key's largest value
+
+
+def _bucket(
+    values: tuple, dest: jnp.ndarray, valid: jnp.ndarray, *, n_shards: int,
+    capacity: int, pads: tuple, positions: bool = False,
+    span: int | None = None,
+):
+    """Group 1-D rows into ``[n_shards * capacity]`` destination buckets.
+
+    One sort by destination carries the rows (invalid rows sort last), and
+    each bucket is one contiguous slice of the sorted rows, so no row is
+    gathered or scattered: bucket ``d`` holds the first ``capacity`` rows
+    bound for ``d``, then pads.  The sort key packs more than the
+    destination into one int32 where it fits, as the TPU compiler builds a
+    sort several times faster with fewer operands and no stability:
+
+    * with ``span`` (the first value lies in ``[0, span)`` on valid rows)
+      the key is ``destination * span + value``, so the first value rides
+      in the key and a bucket's rows come in its order;
+    * else the key is ``destination * R + row``, unique, so a bucket's
+      rows come in their original order.
+
+    Returns (bucketed rows, overflow, counts [n_shards] of the valid rows
+    bound for each destination before the cut, rows kept); with
+    ``positions`` the bucketed rows end with each row's index in the input
+    (``-1`` in pads).
+    """
+    r = dest.shape[0]
+    key = jnp.where(valid, dest, n_shards).astype(jnp.int32)
+    row = jnp.arange(r, dtype=jnp.int32)
+    if span is not None and not positions \
+            and (n_shards + 1) * span <= _KEY_LIMIT:
+        first = jnp.where(valid, values[0], 0)
+        key, *rows = jax.lax.sort((key * span + first,) + tuple(values[1:]),
+                                  num_keys=1, is_stable=False)
+        rows.insert(0, key % span)
+        bounds = jnp.arange(n_shards + 1, dtype=jnp.int32) * span
+    elif (n_shards + 1) * r <= _KEY_LIMIT:
+        key, *rows = jax.lax.sort((key * r + row,) + tuple(values),
+                                  num_keys=1, is_stable=False)
+        bounds = jnp.arange(n_shards + 1, dtype=jnp.int32) * r
+        if positions:
+            rows.append(key % r)
+    else:
+        extra = (row,) if positions else ()
+        key, *rows = jax.lax.sort((key,) + tuple(values) + extra,
+                                  num_keys=1, is_stable=True)
+        bounds = jnp.arange(n_shards + 1, dtype=jnp.int32)
+    pads = tuple(pads) + ((-1,) if positions else ())
+    edges = jnp.searchsorted(key, bounds).astype(jnp.int32)
+    counts = edges[1:] - edges[:-1]
+    kept = jnp.minimum(counts, capacity)
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    out = []
+    for v, pad in zip(rows, pads):
+        v = jnp.concatenate([v, jnp.full((capacity,), pad, v.dtype)])
+        out.append(jnp.concatenate([
+            jnp.where(slot < kept[d],
+                      jax.lax.dynamic_slice_in_dim(v, edges[d], capacity),
+                      pad)
+            for d in range(n_shards)
+        ]))
+    return tuple(out), jnp.sum(counts - kept), counts, jnp.sum(kept)
+
+
+def _exchange(buf: jnp.ndarray, n_shards: int, axis_name: str):
+    """all_to_all of ``[n_shards * capacity, ...]`` destination buckets:
+    bucket ``d`` goes to shard ``d``; bucket ``s`` of the result came from
+    shard ``s``."""
+    shape = buf.shape
+    recv = jax.lax.all_to_all(
+        buf.reshape((n_shards, shape[0] // n_shards) + shape[1:]),
+        axis_name, split_axis=0, concat_axis=0, tiled=True,
+    )
+    return recv.reshape(shape)
+
+
 def _route(
     values: tuple, dest: jnp.ndarray, valid: jnp.ndarray, *, n_shards: int,
-    capacity: int, pads: tuple, axis_name: str,
+    capacity: int, pads: tuple, axis_name: str, positions: bool = False,
+    span: int | None = None,
 ):
-    """Scatter rows into [n_shards, capacity] buckets and all_to_all them.
+    """Bucket 1-D rows by destination (:func:`_bucket`) and all_to_all them.
 
-    values: tuple of int32 [R] or [R, W] arrays routed together (rows travel
-    with their payload columns); pads: per-array pad value.
-    Returns (tuple of [n_shards * capacity(, W)] received rows, overflow).
+    values: tuple of [R] arrays routed together; pads: per-array pad value.
+    Returns (tuple of [n_shards * capacity] received rows, overflow, counts
+    [n_shards] of the valid rows bound for each destination, rows
+    carried); with ``positions`` the received rows end with each row's
+    index in its source's input (``-1`` in pads).
     """
-    dest = jnp.where(valid, dest, n_shards)  # n_shards = drop bucket
-    order = jnp.argsort(dest, stable=True)
-    dest_s = dest[order]
-    rank, _ = _runs(jnp.where(dest_s == n_shards, PAD_KEY, dest_s))
-    ok = (dest_s < n_shards) & (rank < capacity)
-    slot = jnp.where(ok, dest_s * capacity + rank, n_shards * capacity)
-    overflow = jnp.sum((dest_s < n_shards) & (rank >= capacity))
-    outs = []
-    for v, pad in zip(values, pads):
-        width = v.shape[1:] if v.ndim > 1 else ()
-        buf = jnp.full((n_shards * capacity,) + width, pad, dtype=v.dtype)
-        buf = buf.at[slot].set(v[order], mode="drop")
-        buf = buf.reshape((n_shards, capacity) + width)
-        recv = jax.lax.all_to_all(
-            buf, axis_name, split_axis=0, concat_axis=0, tiled=True
-        )
-        outs.append(recv.reshape((n_shards * capacity,) + width))
-    return tuple(outs), overflow
+    bufs, overflow, counts, kept = _bucket(
+        values, dest, valid, n_shards=n_shards, capacity=capacity, pads=pads,
+        positions=positions, span=span,
+    )
+    recv = tuple(_exchange(b, n_shards, axis_name) for b in bufs)
+    return recv, overflow, counts, kept
 
 
 def _prune_keep(len_l, len_r, betas, prune_tau, valid):
@@ -136,50 +218,60 @@ def _fit(x: jnp.ndarray, cap: int, pad_val) -> jnp.ndarray:
 
 def _hop_gather_codes(
     left, right, codes_local, *, owner_of, slot_of, n_shards, axis_name,
-    hop_cap, out_cap,
+    hop_cap, out_cap, span=None,
 ):
     """Two-hop pair/code shuffle shared by the one-shot and streaming paths.
 
     Route pairs to owner(left), attach that shard's code rows, then to
-    owner(right), attach, and come to rest wherever owner(right) is (the
-    pairs are already globally deduped upstream).  Ownership is pluggable:
-    the one-shot pipeline owns rows in blocks (``g // local_n``), the
-    streaming world round-robins them (``g % n_shards``) so growth stays
-    balanced; ``slot_of`` maps a global id to the owner's local row.
-    Received rows sit scattered across per-source buckets, so valid rows are
-    compacted to the front before the fit to ``out_cap`` — a plain
-    truncation could drop valid pairs while keeping padding.  Returns
-    (left, right, left_codes, right_codes, overflow).
+    owner(right), and come to rest there (the pairs are already globally
+    deduped upstream).  Ownership is pluggable: the one-shot pipeline owns
+    rows in blocks (``g // local_n``), the streaming world round-robins them
+    (``g % n_shards``) so growth stays balanced; ``slot_of`` maps a global
+    id to the owner's local row.  The left code rows are gathered once, in
+    the order hop 2 ships them, and stay where they arrive: each resting
+    pair points at its left row there (``rows_l``) and at its right row in
+    this shard's own table (``rows_r``).  Each received bucket's valid rows
+    are its prefix, so laying the prefixes end to end brings the resting
+    pairs to the front without a sort.  Returns (left, right, table_l
+    [n_shards * hop_cap, H, L], rows_l, rows_r, overflow, (rows carried by
+    hop 1, rows carried by hop 2)).  ``span`` bounds the ids, if known
+    (:func:`_bucket`).
     """
     H, L = codes_local.shape[1], codes_local.shape[2]
     local_n = codes_local.shape[0]
+    pads = (PAD_ID, PAD_ID)
     # hop 1: to owner(left)
-    (l1, r1), o1 = _route(
+    (l1, r1), o1, _, c1 = _route(
         (left, right), owner_of(left), left != PAD_ID,
-        n_shards=n_shards, capacity=hop_cap, pads=(PAD_ID, PAD_ID),
-        axis_name=axis_name,
+        n_shards=n_shards, capacity=hop_cap, pads=pads, axis_name=axis_name,
+        span=span,
     )
-    safe = slot_of(jnp.where(l1 == PAD_ID, 0, l1))
-    cl = codes_local[jnp.clip(safe, 0, local_n - 1)].reshape(
-        l1.shape[0], H * L
+    # hop 2: to owner(right), with the left code rows this shard owns
+    (rb, lb), o2, _, c2 = _bucket(
+        (r1, l1), owner_of(r1), l1 != PAD_ID,
+        n_shards=n_shards, capacity=hop_cap, pads=pads, span=span,
     )
-    # hop 2: to owner(right), payload = left codes
-    (l2, r2, cl2), o2 = _route(
-        (l1, r1, cl), owner_of(r1), l1 != PAD_ID,
-        n_shards=n_shards, capacity=hop_cap,
-        pads=(PAD_ID, PAD_ID, 0), axis_name=axis_name,
-    )
-    safe_r = slot_of(jnp.where(r2 == PAD_ID, 0, r2))
-    cr = codes_local[jnp.clip(safe_r, 0, local_n - 1)]
-    cl_rows = cl2.reshape(l2.shape[0], H, L)
-    order = jnp.argsort(l2 == PAD_ID, stable=True)
-    l2, r2 = l2[order], r2[order]
-    cl_rows, cr = cl_rows[order], cr[order]
-    n_valid = jnp.sum(l2 != PAD_ID).astype(jnp.int32)
-    ovf_fit = jnp.maximum(n_valid - out_cap, 0)
-    return (_fit(l2, out_cap, PAD_ID), _fit(r2, out_cap, PAD_ID),
-            _fit(cl_rows, out_cap, 0), _fit(cr, out_cap, 0),
-            o1 + o2 + ovf_fit)
+    safe = jnp.clip(slot_of(jnp.where(lb == PAD_ID, 0, lb)), 0, local_n - 1)
+    cl = codes_local.reshape(local_n, H * L)[safe]
+    l2, r2, cl = (_exchange(x, n_shards, axis_name) for x in (lb, rb, cl))
+    # rest: the prefixes of the received buckets, end to end
+    size = n_shards * hop_cap
+    seg = jnp.sum((l2 != PAD_ID).reshape(n_shards, hop_cap), axis=1)
+    off = jnp.cumsum(seg) - seg
+    pos = jnp.arange(size, dtype=jnp.int32)
+    out = [jnp.full((size,), PAD_ID, jnp.int32)] * 2 + [
+        jnp.zeros((size,), jnp.int32)]
+    for s in range(n_shards):
+        part = slice(s * hop_cap, (s + 1) * hop_cap)
+        out = [jax.lax.dynamic_update_slice_in_dim(o, x[part], off[s], 0)
+               for o, x in zip(out, (l2, r2, pos))]
+    ovf_fit = jnp.maximum(jnp.sum(seg) - out_cap, 0)
+    left, right = _fit(out[0], out_cap, PAD_ID), _fit(out[1], out_cap, PAD_ID)
+    rows_l = _fit(out[2], out_cap, 0)
+    rows_r = jnp.clip(slot_of(jnp.where(right == PAD_ID, 0, right)),
+                      0, local_n - 1)
+    return (left, right, cl.reshape(size, H, L), rows_l, rows_r,
+            (o1 + o2 + ovf_fit).astype(jnp.int32), (c1, c2))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,6 +286,8 @@ class DistributedPlan:
     #                           owner hops; 0 -> uniform fallback
     pruned_cap: int = 0     # post-prune pairs per shard when the MSS
     #                         upper-bound pruning pass runs; 0 -> scored_cap
+    similar_cap: int = 0    # similar pairs per shard brought to the host
+    #                         (MeshStages.similar); 0 -> not planned yet
     n_chunks: int = 1       # shuffle-mode overlap: split the pair buffer
     #                         into this many chunks so chunk i+1's owner
     #                         hops run while chunk i scores; 1 -> the
@@ -242,14 +336,18 @@ def plan_capacities(
     from survivors only.
 
     ``overlap_chunks > 1`` (shuffle mode only) additionally sizes the
-    per-chunk hop/resting buffers for the overlapped gather: the pre-hop
-    pair buffer is split into that many contiguous slices, and because the
-    device buffer layout is DETERMINISTIC — ``dedup_pairs`` sorts by
-    (lo, hi) with PAD at the end, and the prune compaction preserves that
-    order — the planner can replay exactly which pairs land in which chunk
-    slice and size ``chunk_hop_cap`` / ``chunk_rest_cap`` from the actual
-    per-(chunk, owner) loads, keeping the overflow accounting exact under
-    chunking too.
+    per-chunk hop/resting buffers for the chunked gather: slot ``p`` of the
+    pre-hop pair buffer belongs to chunk ``p % overlap_chunks``, and
+    because the device buffer layout is DETERMINISTIC — ``dedup_pairs``
+    sorts by (lo, hi) with PAD at the end, and the prune compaction
+    preserves that order — the planner can replay exactly which pairs land
+    in which chunk and size ``chunk_hop_cap`` / ``chunk_rest_cap`` from the
+    actual per-(chunk, owner) loads, keeping the overflow accounting exact
+    under chunking too.
+
+    The engine no longer calls this: it plans from counts made in the mesh
+    (:func:`plan_from_counts`).  It stays as the oracle the tests hold
+    those counts to.
 
     ``windows_per_row > 1`` declares subtrajectory keys: ``keys_np`` has one
     row PER WINDOW (``n = n_traj * nw``, window id ``t * nw + j``), while
@@ -329,14 +427,17 @@ def plan_capacities(
             surv = ub > np.float32(prune_tau - PRUNE_EPS)
         else:
             surv = np.ones(ulo.shape, bool)
+        flip = (_pair_hash_np(ulo, uhi) >> 16) & 1 == 1
+        ox, oy = np.where(flip, uhi, ulo), np.where(flip, ulo, uhi)
         if score_mode == "shuffle":
             # per-owner loads of the code-gather hops: dedup shard ->
-            # owner(left) -> owner(right); pairs come to rest on
-            # owner(right).  Pruning happens before the hops, so with it on
-            # only survivors travel — hop buckets and the resting buffer
-            # are sized from the survivor subset.
-            own_lo = ((ulo // nw) // local_n)[surv]
-            own_hi = ((uhi // nw) // local_n)[surv]
+            # owner(x) -> owner(y), (x, y) the pair in hop order
+            # (_orient); pairs come to rest on owner(y).  Pruning happens
+            # before the hops, so with it on only survivors travel — hop
+            # buckets and the resting buffer are sized from the survivor
+            # subset.
+            own_lo = ((ox // nw) // local_n)[surv]
+            own_hi = ((oy // nw) // local_n)[surv]
             h1 = np.zeros((n_shards, n_shards), np.int64)
             np.add.at(h1, (ded_dst[surv], own_lo), 1)
             h2 = np.zeros((n_shards, n_shards), np.int64)
@@ -367,24 +468,21 @@ def plan_capacities(
             # buffer layout — dedup_pairs sorts by (lo, hi) with PAD at the
             # end (np.unique gives the same global order here) and the
             # prune compaction preserves it — to find which surviving pair
-            # occupies which chunk slice of which shard's buffer, then size
-            # ONE chunk's hop buckets / resting buffer from the worst chunk
+            # occupies which chunk of which shard's buffer, then size ONE
+            # chunk's hop buckets / resting buffer from the worst chunk
             if prune:
                 pruned_cap += (-pruned_cap) % overlap_chunks
-                pre_cap = pruned_cap
             else:
                 cap4 += (-cap4) % overlap_chunks
-                pre_cap = cap4
-            sub = pre_cap // overlap_chunks
             sel = np.nonzero(surv)[0]
             d_sel = ded_dst[sel]
             rank = np.zeros(sel.shape[0], np.int64)
             for s in range(n_shards):
                 m = d_sel == s
                 rank[m] = np.arange(int(m.sum()))
-            chunk_of = np.minimum(rank // sub, overlap_chunks - 1)
-            olo = (ulo[sel] // nw) // local_n
-            ohi = (uhi[sel] // nw) // local_n
+            chunk_of = rank % overlap_chunks
+            olo = (ox[sel] // nw) // local_n
+            ohi = (oy[sel] // nw) // local_n
             ch1 = np.zeros((overlap_chunks, n_shards, n_shards), np.int64)
             np.add.at(ch1, (chunk_of, d_sel, olo), 1)
             ch2 = np.zeros((overlap_chunks, n_shards, n_shards), np.int64)
@@ -410,109 +508,235 @@ def plan_capacities(
     )
 
 
-def make_sharded_pipeline(
-    mesh: jax.sharding.Mesh,
-    plan: DistributedPlan,
-    *,
-    betas: jnp.ndarray,
-    key_fn: Callable | None,
-    axis_name: str = "ex",
-    score_mode: str = "replicate",
-    lcs_impl: str = "wavefront",
-    score_prune: bool = False,
-    prune_tau: float = 0.0,
-    tuning=None,
-    subtraj: tuple[int, int, int] | None = None,
-):
-    """Build the jitted shard_map encode+join+score pipeline.
+class _OneShot:
+    """The shard-level steps of the one-shot job, for the staged programs
+    of :class:`MeshStages`.  Every method runs inside ``shard_map`` on one
+    shard's rows."""
 
-    key_fn: jax-traceable ``(local_type_codes [n, L], local_lengths [n]) ->
-      keys [n, S]`` run per shard (a backend's ``shard_key_fn``) on the
-      shard's in-mesh encoded codes, or None, in which case the first input
-      of the returned fn carries precomputed keys instead of places.
+    def __init__(self, *, n_shards, local_n, betas, key_fn, axis_name,
+                 score_mode, impl, score_prune, prune_tau, subtraj):
+        self.n = n_shards
+        self.local_n = local_n
+        self.betas = betas
+        self.key_fn = key_fn
+        self.axis = axis_name
+        self.score_mode = score_mode
+        self.score_prune = score_prune
+        self.prune_tau = prune_tau
+        self.subtraj = subtraj
+        self.W, self.stride, self.nw = subtraj or (0, 1, 1)
+        self.span = n_shards * local_n * self.nw  # ids lie in [0, span)
+        self.impl = impl
 
-    Call signature of the returned fn:
-      fn(first, places [N, L] int32, lengths [N] int32,
-         tables [n_levels, num_places] int32)
-        -> dict of per-shard stacked outputs:
-           left/right [n, scored_cap], level_lcs [n, scored_cap, H],
-           mss [n, scored_cap], overflow [n, 3]
+    # -- ids and ownership ---------------------------------------------------
 
-      first: with a key_fn, unused (pass places again); without, [N, S]
-      keys precomputed host-side and shuffled in (the "udf" driver wall).
+    def gid0(self):
+        return jax.lax.axis_index(self.axis).astype(jnp.int32) * self.local_n
 
-    Encoding runs INSIDE the shard_map program: each shard gathers its own
-    rows through the replicated forest ``tables`` (small — the semantic
-    forest, [n_levels, num_places]), so the [N, n_levels, L] code table
-    never materializes on the host, for either score mode.
+    def traj(self, g):
+        """Trajectory of an id (a window id in subtrajectory mode)."""
+        return g if self.subtraj is None else g // self.nw
 
-    score_mode:
-      "replicate" — each shard all_gathers the per-shard encodings into a
-        device-resident replica of the table and scores its deduped pairs
-        locally (fine to ~10M trajectories: the table is
-        N * levels * L * 4 bytes).
-      "shuffle"   — the table stays sharded; two extra all_to_all rounds
-        route each pair to owner(left) then owner(right), attaching the
-        owner's code rows on the way (a Spark broadcast-join vs shuffle-join
-        switch).  Per-device memory is then O(N/shards) — the 1000-node
-        regime.
+    def owner_of(self, g):
+        return self.traj(g) // self.local_n
 
-    lcs_impl selects the scoring implementation exactly as on the
-    single-device path: "wavefront" / "ref", or the Pallas kernel through
-    "fused" (auto) / "fused-pallas" (forced) / "fused-interpret" — every impl
-    scores through ``similarity.score_indexed`` (chunked) against the
-    device-resident code table ("replicate") or the hop-gathered operand
-    stacks ("shuffle").
+    # -- keys and the key shuffle --------------------------------------------
 
-    score_prune runs the MSS upper-bound pruning pass IN-MESH, right after
-    the pair dedup and before any code row moves for scoring: per-shard
-    lengths are all_gathered (an [N] int32 vector, not the code table), the
-    free bound ``sum_h beta_h * min(len_a, len_b)`` is tested against
-    ``prune_tau``, and survivors are compacted into the planned
-    ``pruned_cap`` buffer.  In "shuffle" mode this happens before the owner
-    hops, so pruned pairs never travel.
+    def keys(self, first, places, lengths, tables):
+        """(codes [local_n, H, L], flat keys, keys per row, valid, dest)
+        of this shard's rows: one key row per trajectory, or per window."""
+        codes = encode_codes(places, tables)
+        if self.key_fn is not None:
+            keys = self.key_fn(codes[:, 0, :], lengths)
+        else:
+            keys = first  # precomputed host-side
+        flat_keys = keys.reshape(-1)
+        valid = flat_keys != PAD_KEY
+        return codes, flat_keys, keys.shape[1], valid, \
+            _positive_hash(flat_keys) % self.n
 
-    With ``plan.n_chunks > 1`` (shuffle mode) the pair buffer is split into
-    chunks and the owner hops are SOFTWARE-PIPELINED: chunk 0's hops are
-    issued, then for each subsequent chunk the next hops are issued BEFORE
-    the previous chunk's resting pairs are scored, so the collective for
-    chunk i+1 and the LCS compute for chunk i have no data dependence and
-    the scheduler is free to overlap them (alpa's comm/compute overlap
-    discipline; on a single host the same split pays off as cache blocking
-    — one chunk's operands stay resident while it scores).  Chunking only
-    reorders WHICH rows travel together; every pair still hops and scores
-    exactly once with the same operands, so per-pair scores are
-    bit-identical and the overflow accounting stays exact (per-chunk
-    buffers come from the same exact-loads planner).  ``n_chunks`` is a
-    static plan field, so chunking adds zero steady-state recompiles.
+    def route_keys(self, flat_keys, width, valid, dest, cap):
+        """Shuffle 1: (keys, ids) to ``hash(key) % n_shards``.  Only the
+        keys travel through the bucket sort: a key's id is its source row,
+        ``position // width`` on this shard."""
+        (rk, pos), ovf, counts, carried = _route(
+            (flat_keys,), dest, valid, n_shards=self.n, capacity=cap,
+            pads=(PAD_KEY,), axis_name=self.axis, positions=True,
+        )
+        # (the source shard of received bucket s is s)
+        src = jnp.repeat(jnp.arange(self.n, dtype=jnp.int32), cap)
+        base = src * (self.local_n * self.nw)
+        rid = jnp.where(pos >= 0, base + pos // width, PAD_ID)
+        return (rk, rid), ovf, counts, carried
 
-    ``tuning`` (optional :class:`repro.perf.LCSTuning`) is resolved
-    EAGERLY here at build time into static kernel args via
-    ``lcs_impl_fn`` — never inside the trace.
+    # -- join and the dedup shuffle ------------------------------------------
 
-    ``subtraj=(W, stride, nw)`` switches the pipeline to subtrajectory
-    mode: the per-shard key rows are the nw sliding WINDOWS of each local
-    trajectory (``key_fn`` windows in-mesh; precomputed ``first`` keys are
-    already windowed host-side), every candidate id is a WINDOW id
-    ``t * nw + j`` carrying (traj, offset) coordinates end-to-end, shard
-    ownership stays per-TRAJECTORY (``plan.local_n`` is in trajectory
-    units, see ``plan_capacities(windows_per_row=...)``), the owner hops
-    still move the full [H, L] trajectory rows exactly once per pair side,
-    and scoring windows them in-register (fused kernel) or via a width-W
-    gather (jnp impls).  All three values are static, so subtrajectory
-    runs compile their own specialization and ``subtraj=None`` traces are
-    byte-identical to the pre-windowing pipeline.
-    """
-    from jax.sharding import PartitionSpec as P
+    def join(self, rk, rid, pair_cap, route_cap):
+        """Local sort-merge join, then shuffle 2 by pair hash and a dedup
+        that is globally exact.  Returns (left, right) deduped and
+        compacted to the front of the received buffer, their count, the
+        join's and the shuffle's overflow, the shuffle's destination counts
+        and the rows it carried."""
+        lo, hi, ovf = pairs_from_rows(rk, rid, pair_capacity=pair_cap,
+                                      presorted=True)
+        (rlo, rhi), ovf_route, counts, carried = _route(
+            (lo, hi), _pair_hash(lo, hi) % self.n, lo != PAD_ID,
+            n_shards=self.n, capacity=route_cap, pads=(PAD_ID, PAD_ID),
+            axis_name=self.axis, span=self.span,
+        )
+        cand = dedup_pairs(rlo, rhi)
+        return (cand.left, cand.right, cand.count, ovf, ovf_route, counts,
+                carried)
 
-    from repro.api.stages import lcs_impl_fn
+    def keep(self, left, right, lengths):
+        """The MSS upper-bound prune's survivors among valid pairs; only the
+        [N] lengths vector is gathered, never a code row."""
+        valid = left != PAD_ID
+        if not self.score_prune:
+            return valid
+        lengths_all = jax.lax.all_gather(lengths, self.axis, axis=0,
+                                         tiled=True)
+        sl = jnp.where(valid, left, 0)
+        sr = jnp.where(valid, right, 0)
+        if self.subtraj is None:
+            len_l, len_r = lengths_all[sl], lengths_all[sr]
+        else:
+            # per-WINDOW lengths from the [N] trajectory lengths
+            nw, st, W = self.nw, self.stride, self.W
+            len_l = jnp.clip(lengths_all[sl // nw] - (sl % nw) * st, 0, W)
+            len_r = jnp.clip(lengths_all[sr // nw] - (sr % nw) * st, 0, W)
+        return _prune_keep(len_l, len_r, self.betas, self.prune_tau, valid)
 
-    n_shards = plan.n_shards
-    if subtraj is not None:
-        W, stride, nw = subtraj
-    else:
-        W, stride, nw = 0, 1, 1
-    impl = lcs_impl_fn(lcs_impl, tuning)
+    def owner_loads(self, left, right, keep):
+        """int32 [n_shards * n_shards]: this shard's kept pairs by the
+        owners of their two ids in hop order (:func:`_orient`) — the owner
+        hops' loads."""
+        x, y = _orient(jnp.where(keep, left, 0), jnp.where(keep, right, 0))
+        cls = self.owner_of(x) * self.n + self.owner_of(y)
+        return _dest_counts(cls, keep, self.n * self.n)
+
+    def prepare(self, left, right, lengths, scored_cap, out_cap):
+        """Fit the deduped pairs to ``scored_cap``, then (with the prune on)
+        compact the survivors into ``out_cap``.  Returns (left, right,
+        overflow, pruned)."""
+        count = jnp.sum(left != PAD_ID)
+        left = _fit(left, scored_cap, PAD_ID)
+        right = _fit(right, scored_cap, PAD_ID)
+        ovf = jnp.maximum(count - scored_cap, 0)
+        n_pruned = jnp.zeros((), jnp.int32)
+        if self.score_prune:
+            keep = self.keep(left, right, lengths)
+            n_keep = jnp.sum(keep).astype(jnp.int32)
+            n_pruned = jnp.sum(left != PAD_ID).astype(jnp.int32) - n_keep
+            order = jnp.argsort(jnp.logical_not(keep), stable=True)
+            slots = jnp.arange(out_cap, dtype=jnp.int32)
+            # out_cap may exceed scored_cap (skewed owners): pad, then mask
+            left = jnp.where(slots < n_keep,
+                             _fit(left[order], out_cap, PAD_ID), PAD_ID)
+            right = jnp.where(slots < n_keep,
+                              _fit(right[order], out_cap, PAD_ID), PAD_ID)
+            ovf = ovf + jnp.maximum(n_keep - out_cap, 0)
+        return left, right, ovf.astype(jnp.int32), n_pruned
+
+    # -- scoring -------------------------------------------------------------
+
+    def _score(self, table_l, table_r, left, right, rows_l=None, rows_r=None):
+        """Score pairs ``left``/``right`` (global ids: trajectories, or
+        windows in subtrajectory mode) against the code tables.  ``rows_*``
+        index each pair's trajectory row in its table (default: the id's
+        own trajectory).  Window ids decode to (traj, offset) here, at the
+        point of scoring; the owner hops always move full rows."""
+        li = jnp.where(left == PAD_ID, 0, left)
+        ri = jnp.where(right == PAD_ID, 0, right)
+        window = {}
+        if self.subtraj is not None:
+            window = dict(window=self.W, off_a=(li % self.nw) * self.stride,
+                          off_b=(ri % self.nw) * self.stride)
+        return score_indexed(
+            table_l, _lengths_of(table_l), table_r, _lengths_of(table_r),
+            self.traj(li) if rows_l is None else rows_l,
+            self.traj(ri) if rows_r is None else rows_r,
+            self.betas, impl=self.impl, **window,
+        )
+
+    def score(self, left, right, codes, *, n_chunks, hop_cap, rest_cap):
+        """Score the pairs; in shuffle mode each first comes to rest on the
+        owner of one of its ids (:func:`_orient`).  Returns (left, right,
+        level_lcs, mss, overflow, rows carried by hop 1 and hop 2).
+
+        With ``n_chunks > 1`` slot ``p`` of the pair buffer goes in chunk
+        ``p % n_chunks``: the buffer is sorted by (left, right), so each
+        chunk is an even sample of every owner's load.  The owner hops of
+        chunk ``i + 1`` are issued before chunk ``i`` scores, so the
+        collective and the LCS compute have no data dependence."""
+        zero = jnp.zeros((), jnp.int32)
+        if self.score_mode == "replicate":
+            # on-device replication of the in-mesh encodings (never on host)
+            codes_all = jax.lax.all_gather(codes, self.axis, axis=0,
+                                           tiled=True)
+            lvl, mss = self._score(codes_all, codes_all, left, right)
+            return left, right, lvl, mss, zero, (zero, zero)
+        gid0 = self.gid0()
+
+        def hop(lr):
+            return _hop_gather_codes(
+                lr[0], lr[1], codes, owner_of=self.owner_of,
+                slot_of=lambda g: self.traj(g) - gid0, n_shards=self.n,
+                axis_name=self.axis, hop_cap=hop_cap, out_cap=rest_cap,
+                span=self.span,
+            )
+
+        def rest(h):
+            lvl, mss = self._score(h[2], codes, h[0], h[1], h[3], h[4])
+            return h[0], h[1], lvl, mss, h[5], h[6]
+
+        pair = _orient(left, right)
+        if n_chunks == 1:
+            flat = rest(hop(pair))
+        else:
+            flat = self._chunked(hop, rest, pair, n_chunks)
+        x, y = flat[0], flat[1]
+        return (jnp.minimum(x, y), jnp.maximum(x, y)) + flat[2:]
+
+    @staticmethod
+    def _chunked(hop, rest, pair, n_chunks):
+        chunks = tuple(x.reshape(-1, n_chunks).T for x in pair)
+
+        def step(pending, lr):
+            nxt = hop(lr)
+            return nxt, rest(pending)
+
+        last, parts = jax.lax.scan(step, hop(tuple(c[0] for c in chunks)),
+                                   tuple(c[1:] for c in chunks))
+        return jax.tree_util.tree_map(
+            lambda p, t: jnp.concatenate([p.reshape((-1,) + t.shape[1:]), t])
+            if t.ndim else jnp.sum(p) + t, parts, rest(last),
+        )
+
+
+def _orient(left, right):
+    """Each pair's two ids in the order the owner hops visit them, drawn
+    from a bit of the pair hash: the pair rests on the owner of the second.
+    With block ownership the larger id of a canonical pair lies on a later
+    shard far more often (7 of 16 pairs on the last of four), so resting
+    on the owner of ``right`` would load one shard almost twice the mean.
+    LCS is symmetric, so the scores do not depend on the order."""
+    flip = (_pair_hash(left, right) >> 16) & 1 == 1
+    return jnp.where(flip, right, left), jnp.where(flip, left, right)
+
+
+def _lengths_of(code_rows):
+    # lengths reconstructed from the padding sentinel in level 0
+    return jnp.sum(code_rows[:, 0, :] >= 0, axis=-1).astype(jnp.int32)
+
+
+def _psum(x, axis_name):
+    return jax.lax.psum(x.astype(jnp.int32), axis_name)
+
+
+def _score_shapes(plan: DistributedPlan, score_mode: str, score_prune: bool):
+    """(pre-hop pair buffer, chunks, hop bucket, resting buffer per chunk)
+    of a plan's score stage."""
     out_cap = (plan.pruned_cap or plan.scored_cap) if score_prune \
         else plan.scored_cap
     n_chunks = plan.n_chunks if score_mode == "shuffle" else 1
@@ -522,226 +746,304 @@ def make_sharded_pipeline(
                 f"pair buffer ({out_cap}) must divide into n_chunks="
                 f"{n_chunks} slices; plan_capacities rounds it up"
             )
-        _sub = out_cap // n_chunks
-        chunk_hop_cap = plan.chunk_hop_cap or (_sub // n_shards + 64)
-        chunk_rest_cap = plan.chunk_rest_cap or _sub
-        rest_total = n_chunks * chunk_rest_cap
-    else:
-        rest_total = out_cap
+        sub = out_cap // n_chunks
+        return (out_cap, n_chunks, plan.chunk_hop_cap or (sub // plan.n_shards
+                                                          + 64),
+                plan.chunk_rest_cap or sub)
+    return (out_cap, 1,
+            plan.owner_route_cap or (out_cap // plan.n_shards + 64), out_cap)
 
-    def shard_fn(first, places, lengths, tables):
-        # first: LOCAL keys rows (key_fn=None mode) or unused; places,
-        # lengths: LOCAL rows; tables: the replicated semantic forest.
-        me = jax.lax.axis_index(axis_name).astype(jnp.int32)
-        gid0 = me * plan.local_n
 
-        # phase (i): in-mesh encoding of OUR rows
-        codes = encode_codes(places, tables)  # [local_n, H, L]
+# the stats each staged program returns per shard, in order: (name, size)
+def _stats_layout(n: int, stage: str, score_mode: str) -> tuple:
+    if stage == "route":
+        return (("loads", n), ("pair_total", 1), ("overflow", 1),
+                ("carried", 1))
+    if stage == "join":
+        owner = (("owner_loads", n * n),) if score_mode == "shuffle" else ()
+        return (("count", 1), ("kept", 1), ("overflow", 1),
+                ("route_overflow", 1), ("route_loads", n),
+                ("carried", 1)) + owner
+    return (("overflow", 1), ("pruned", 1), ("resting", 1),
+            ("carried_hop1", 1), ("carried_hop2", 1))
 
-        # phase (ii)a: join keys of OUR rows
-        if key_fn is not None:
-            keys = key_fn(codes[:, 0, :], lengths)  # [local_n, S]
-        else:
-            keys = first  # [local_n, S] precomputed host-side
 
-        s = keys.shape[1]
-        flat_keys = keys.reshape(-1)
-        if subtraj is None:
-            flat_ids = jnp.repeat(
-                jnp.arange(plan.local_n, dtype=jnp.int32) + gid0, s
-            )
-        else:
-            # one key row per WINDOW: global window ids t * nw + j for the
-            # local trajectories t in [gid0, gid0 + local_n)
-            flat_ids = jnp.repeat(
-                jnp.arange(plan.local_n * nw, dtype=jnp.int32) + gid0 * nw, s
-            )
-        valid = flat_keys != PAD_KEY
-        dest = _positive_hash(flat_keys) % n_shards
-        (rk, rid), ovf1 = _route(
-            (flat_keys, flat_ids), dest, valid,
-            n_shards=n_shards, capacity=plan.shingle_route_cap,
-            pads=(PAD_KEY, PAD_ID), axis_name=axis_name,
+def _pack(layout, values: dict):
+    return jnp.concatenate([
+        jnp.asarray(values[k], jnp.int32).reshape(size) for k, size in layout
+    ])
+
+
+def unpack_stats(stats, layout) -> dict:
+    """{name: int64 [n_shards, size]} of a staged program's stats."""
+    stats = np.asarray(stats, np.int64)
+    width = sum(size for _, size in layout)
+    stats = stats.reshape(-1, width)
+    out, at = {}, 0
+    for k, size in layout:
+        out[k] = stats[:, at:at + size]
+        at += size
+    return out
+
+
+class MeshStages:
+    """The one-shot job as staged shard_map programs, for the engine.
+
+    Each stage is planned from counts the stage before made in the mesh,
+    so no capacity rests on a bound and nothing but small count tables
+    comes to the host:
+
+    * ``loads()`` — per-(source, destination) key loads of shuffle 1, run
+      once, before any plan exists;
+    * ``route(cap)`` — encode, keys and shuffle 1; the received keys are
+      sorted and counted into each shard's pre-dedup join size (the run
+      ranks, as ``core/ssh.exact_pair_count`` counts them on one device);
+    * ``join(pair_cap, route_cap)`` — the local join, shuffle 2 and the
+      dedup; counts each shard's deduped (and, with the prune, surviving)
+      pairs and their owner-hop loads;
+    * ``score(...)`` — the prune's compaction, the owner hops (shuffle
+      mode) and the LCS score;
+    * ``similar_count()`` and ``similar(cap)`` — each shard's similar pairs
+      counted, then moved to the front of a ``cap`` buffer, so only they
+      come to the host.
+
+    Buffers stay on the devices between stages.  Each program is compiled
+    once per set of capacities (``jax.jit`` caches, keyed by the static
+    capacities), so with power-of-two capacities kept sticky every job of
+    one size runs the same programs.
+    """
+
+    def __init__(self, mesh, *, n_shards, local_n, betas, key_fn, axis_name,
+                 score_mode, lcs_impl, score_prune, prune_tau, subtraj,
+                 rho: float = 0.0):
+        from jax.sharding import PartitionSpec as P
+
+        self.mesh = mesh
+        self.n = n_shards
+        self.threshold = _above(rho)
+        self.score_mode = score_mode
+        self.score_prune = score_prune
+        self.lcs_impl = lcs_impl
+        self.one = _OneShot(
+            n_shards=n_shards, local_n=local_n, betas=betas, key_fn=key_fn,
+            axis_name=axis_name, score_mode=score_mode, impl=None,
+            score_prune=score_prune, prune_tau=prune_tau, subtraj=subtraj,
         )
+        self.row = P(axis_name, None)
+        self.flat = P(axis_name)
+        self.rep = P(None, None)
+        self._cache: dict = {}
 
-        # local sort-merge join on received rows
-        lo, hi, ovf2 = pairs_from_rows(rk, rid, pair_capacity=plan.local_pair_cap)
+    def layout(self, stage: str) -> tuple:
+        return _stats_layout(self.n, stage, self.score_mode)
 
-        # shuffle 2: route pairs by pair hash so dedup is globally exact
-        pvalid = lo != PAD_ID
-        pdest = _pair_hash(lo, hi) % n_shards
-        (rlo, rhi), ovf3 = _route(
-            (lo, hi), pdest, pvalid,
-            n_shards=n_shards, capacity=plan.pair_route_cap,
-            pads=(PAD_ID, PAD_ID), axis_name=axis_name,
-        )
-        # dedup over the FULL received buffer (valid rows sit scattered in
-        # per-source buckets; dedup's sort compacts them to the front), then
-        # fit to scored_cap with the excess surfaced as overflow
-        cand = dedup_pairs(rlo, rhi)
-        left = _fit(cand.left, plan.scored_cap, PAD_ID)
-        right = _fit(cand.right, plan.scored_cap, PAD_ID)
-        ovf4 = jnp.maximum(cand.count - plan.scored_cap, 0)
+    def _build(self, key, shard_fn, in_specs, n_out):
+        fn = self._cache.get(key)
+        if fn is None:
+            fn = jax.jit(compat.shard_map(
+                shard_fn, mesh=self.mesh, in_specs=in_specs,
+                out_specs=(self.flat,) * n_out,
+            ))
+            self._cache[key] = fn
+        return fn
 
-        # MSS upper-bound pruning pass: drop pairs that cannot reach tau
-        # BEFORE any code row moves for scoring.  Only the [N] lengths
-        # vector is gathered (int32, tiny) — never the code table.
-        n_pruned = jnp.zeros((), jnp.int32)
-        if score_prune:
-            lengths_all = jax.lax.all_gather(
-                lengths, axis_name, axis=0, tiled=True
-            )
-            pl_valid = left != PAD_ID
-            sl = jnp.where(pl_valid, left, 0)
-            sr = jnp.where(pl_valid, right, 0)
-            if subtraj is None:
-                len_l, len_r = lengths_all[sl], lengths_all[sr]
-            else:
-                # per-WINDOW lengths from the [N] trajectory lengths
-                len_l = jnp.clip(
-                    lengths_all[sl // nw] - (sl % nw) * stride, 0, W
-                )
-                len_r = jnp.clip(
-                    lengths_all[sr // nw] - (sr % nw) * stride, 0, W
-                )
-            keep = _prune_keep(len_l, len_r, betas, prune_tau, pl_valid)
-            n_keep = jnp.sum(keep).astype(jnp.int32)
-            n_pruned = jnp.sum(pl_valid).astype(jnp.int32) - n_keep
-            order = jnp.argsort(jnp.logical_not(keep), stable=True)
-            slots = jnp.arange(out_cap, dtype=jnp.int32)
-            # out_cap may exceed scored_cap (skewed owners): pad, then mask
-            left = jnp.where(
-                slots < n_keep, _fit(left[order], out_cap, PAD_ID), PAD_ID
-            )
-            right = jnp.where(
-                slots < n_keep, _fit(right[order], out_cap, PAD_ID), PAD_ID
-            )
-            ovf4 = ovf4 + jnp.maximum(n_keep - out_cap, 0)
+    def loads(self):
+        one = self.one
 
-        # phase (iii): scoring, through the selected lcs_impl
-        if score_mode == "replicate":
-            # on-device replication of the in-mesh encodings (never on host)
-            codes_all = jax.lax.all_gather(codes, axis_name, axis=0, tiled=True)
-            level_lcs, mss = _score(codes_all, codes_all, left, right)
-            ovf5 = jnp.zeros((), jnp.int32)
-        elif n_chunks == 1:
-            left, right, codes_l, codes_r, ovf5 = _gather_pair_codes(
-                left, right, codes, gid0, plan, n_shards, axis_name, out_cap
-            )
-            level_lcs, mss = _score_gathered(codes_l, codes_r, out_cap,
-                                             left, right)
-        else:
-            # software-pipelined chunked gather+score: issue the owner hops
-            # for chunk i+1 BEFORE scoring chunk i's resting pairs, so the
-            # collective and the LCS compute have no data dependence
-            def hop(i):
-                sl = slice(i * _sub, (i + 1) * _sub)
-                return _hop_gather_codes(
-                    left[sl], right[sl], codes,
-                    owner_of=lambda g: (g if subtraj is None else g // nw)
-                    // plan.local_n,
-                    slot_of=lambda g: (g if subtraj is None else g // nw)
-                    - gid0,
-                    n_shards=n_shards, axis_name=axis_name,
-                    hop_cap=chunk_hop_cap, out_cap=chunk_rest_cap,
-                )
+        def shard_fn(first, places, lengths, tables):
+            _, _, _, valid, dest = one.keys(first, places, lengths, tables)
+            return (_dest_counts(dest, valid, one.n),)
 
-            def score_chunk(p):
-                return (
-                    p[:2]
-                    + _score_gathered(p[2], p[3], chunk_rest_cap, p[0], p[1])
-                    + (p[4],)
-                )
+        return self._build(("loads",), shard_fn,
+                           (self.row, self.row, self.flat, self.rep), 1)
 
-            parts = []
-            pending = hop(0)
-            for i in range(1, n_chunks):
-                nxt = hop(i)
-                parts.append(score_chunk(pending))
-                pending = nxt
-            parts.append(score_chunk(pending))
-            left = jnp.concatenate([p[0] for p in parts])
-            right = jnp.concatenate([p[1] for p in parts])
-            level_lcs = jnp.concatenate([p[2] for p in parts])
-            mss = jnp.concatenate([p[3] for p in parts])
-            ovf5 = sum(p[4] for p in parts)
-        mss = jnp.where(left == PAD_ID, -1.0, mss)
-        overflow = jnp.stack([ovf1 + ovf2, ovf3, ovf4 + ovf5]).astype(jnp.int32)
-        return left, right, level_lcs, mss, overflow, n_pruned.reshape(1)
+    def route(self, cap: int):
+        """fn(first, places, lengths, tables) -> (keys, ids, stats)."""
+        one, layout = self.one, self.layout("route")
 
-    def _lengths_of(code_rows):
-        # lengths reconstructed from the padding sentinel in level 0
-        return jnp.sum(code_rows[:, 0, :] >= 0, axis=-1).astype(jnp.int32)
+        def shard_fn(first, places, lengths, tables):
+            _, fk, width, valid, dest = one.keys(first, places, lengths,
+                                                 tables)
+            (rk, rid), ovf, counts, carried = one.route_keys(
+                fk, width, valid, dest, cap)
+            rk, rid = jax.lax.sort((rk, rid), num_keys=1,
+                                   is_stable=False)
+            rank, kvalid = _runs(rk)
+            stats = _pack(layout, dict(
+                loads=counts, pair_total=jnp.sum(jnp.where(kvalid, rank, 0)),
+                overflow=ovf, carried=_psum(carried, one.axis)))
+            return rk, rid, stats
 
-    def _score(table_l, table_r, left, right, rows_l=None, rows_r=None):
-        """Score pairs ``left``/``right`` (global ids: trajectories, or
-        windows in subtrajectory mode) against the code tables.  ``rows_*``
-        index each pair's trajectory row in its table (default: the id's
-        own trajectory).  Window ids decode to (traj, offset) here, at the
-        point of scoring; the owner hops always move full rows."""
-        li = jnp.where(left == PAD_ID, 0, left)
-        ri = jnp.where(right == PAD_ID, 0, right)
-        ta, tb = li // nw, ri // nw
-        window = {}
-        if subtraj is not None:
-            window = dict(window=W, off_a=(li % nw) * stride,
-                          off_b=(ri % nw) * stride)
-        return score_indexed(
-            table_l, _lengths_of(table_l), table_r, _lengths_of(table_r),
-            ta if rows_l is None else rows_l,
-            tb if rows_r is None else rows_r, betas, impl=impl, **window,
-        )
+        return self._build(("route", cap), shard_fn,
+                           (self.row, self.row, self.flat, self.rep), 3)
 
-    def _score_gathered(codes_l, codes_r, cap, left, right):
-        """Score one resting operand stack (post-hop) -> (level_lcs, mss):
-        row i of each stack is pair i's trajectory row."""
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        return _score(codes_l, codes_r, left, right, iota, iota)
+    def join(self, pair_cap: int, route_cap: int):
+        """fn(keys, ids, lengths) -> (left, right, stats)."""
+        one, layout = self.one, self.layout("join")
 
-    def _gather_pair_codes(left, right, codes_local, gid0, plan, n, axis,
-                           out_cap):
-        """Shuffle-mode scoring via the shared two-hop gather
-        (:func:`_hop_gather_codes`) with the one-shot BLOCK ownership:
-        row g lives on shard ``g // local_n`` at slot ``g - gid0``.  Hop
-        buckets are sized from the exactly-planned per-owner loads
-        (plan.owner_route_cap); without a plan the uniform fallback applies
-        and overflow counters catch skew.  ``out_cap`` is the resting
-        buffer size — the post-prune capacity when the pruning pass ran,
-        else plan.scored_cap.
-        """
-        cap = plan.owner_route_cap or (out_cap // n + 64)
-        return _hop_gather_codes(
-            left, right, codes_local,
-            owner_of=lambda g: (g if subtraj is None else g // nw)
-            // plan.local_n,
-            slot_of=lambda g: (g if subtraj is None else g // nw) - gid0,
-            n_shards=n, axis_name=axis, hop_cap=cap, out_cap=out_cap,
-        )
+        def shard_fn(rk, rid, lengths):
+            left, right, count, ovf, ovf_route, counts, carried = one.join(
+                rk, rid, pair_cap, route_cap)
+            keep = one.keep(left, right, lengths)
+            vals = dict(count=count, kept=jnp.sum(keep), overflow=ovf,
+                        route_overflow=ovf_route, route_loads=counts,
+                        carried=_psum(carried, one.axis))
+            if one.score_mode == "shuffle":
+                vals["owner_loads"] = one.owner_loads(left, right, keep)
+            return left, right, _pack(layout, vals)
 
-    spec_in = (
-        P(axis_name, None), P(axis_name, None), P(axis_name), P(None, None),
+        return self._build(("join", pair_cap, route_cap), shard_fn,
+                           (self.flat, self.flat, self.flat), 3)
+
+    def score(self, plan: DistributedPlan, tuning=None):
+        """fn(left, right, places, lengths, tables) -> (left, right,
+        level_lcs, mss, stats) of the pairs at rest.  ``tuning`` (a
+        :class:`repro.perf.LCSTuning` or None) resolves here, eagerly, into
+        the scoring impl's static arguments."""
+        from repro.api.stages import lcs_impl_fn
+        from repro.core.similarity import wavefront_dtype_from_env
+
+        one, layout = copy.copy(self.one), self.layout("score")
+        one.impl = lcs_impl_fn(self.lcs_impl, tuning)
+        out_cap, n_chunks, hop_cap, rest_cap = _score_shapes(
+            plan, self.score_mode, self.score_prune)
+        scored_cap = plan.scored_cap
+
+        def shard_fn(left, right, places, lengths, tables):
+            codes = encode_codes(places, tables)
+            left, right, ovf, n_pruned = one.prepare(
+                left, right, lengths, scored_cap, out_cap)
+            left, right, lvl, mss, ovf_hops, (c1, c2) = one.score(
+                left, right, codes, n_chunks=n_chunks, hop_cap=hop_cap,
+                rest_cap=rest_cap)
+            mss = jnp.where(left == PAD_ID, -1.0, mss)
+            stats = _pack(layout, dict(
+                overflow=ovf + ovf_hops, pruned=n_pruned,
+                resting=jnp.sum(left != PAD_ID),
+                carried_hop1=_psum(c1, one.axis),
+                carried_hop2=_psum(c2, one.axis)))
+            return left, right, lvl, mss, stats
+
+        return self._build(
+            ("score", scored_cap, out_cap, n_chunks, hop_cap, rest_cap,
+             tuning, wavefront_dtype_from_env()),
+            shard_fn,
+            (self.flat, self.flat, self.row, self.flat, self.rep), 5)
+
+
+    def similar_count(self):
+        """fn(mss) -> int32 [n_shards]: each shard's similar pairs."""
+        t = self.threshold
+
+        def shard_fn(mss):
+            return (jnp.sum(mss > t).astype(jnp.int32).reshape(1),)
+
+        return self._build(("similar_count",), shard_fn, (self.flat,), 1)
+
+    def similar(self, cap: int):
+        """fn(left, right, mss) -> (left, right) [n_shards * cap]: each
+        shard's similar pairs at the front of its ``cap`` slots, PAD after
+        them."""
+        t = self.threshold
+
+        def shard_fn(left, right, mss):
+            # the similar slots' indices to the front (a one-operand sort),
+            # then only ``cap`` rows gathered
+            sim = mss > t
+            m = sim.shape[0]
+            idx = jax.lax.sort(jnp.where(
+                sim, jnp.arange(m, dtype=jnp.int32), m), is_stable=False)
+            idx = _fit(idx, cap, m)
+            ok = idx < m
+            idx = jnp.where(ok, idx, 0)
+            return (jnp.where(ok, left[idx], PAD_ID),
+                    jnp.where(ok, right[idx], PAD_ID))
+
+        return self._build(("similar", cap), shard_fn, (self.flat,) * 3, 2)
+
+
+def _above(rho: float) -> np.float32:
+    """The float32 ``t`` with ``x > t`` exactly when ``x > rho``, for every
+    float32 ``x``: the host compares float32 scores with the float64
+    ``rho``, the device in float32."""
+    t = np.float32(rho)
+    return np.nextafter(t, np.float32(-np.inf)) if t > rho else t
+
+
+def pow2_capacity(x, slack: float) -> int:
+    """Power-of-two capacity covering ``x`` rows with ``slack``."""
+    return _pow2(int(np.ceil(max(float(x), 1.0) * slack)))
+
+
+def plan_join(route: dict, n_shards: int, slack: float) -> dict:
+    """Capacities of shuffle 1, the local join and shuffle 2, from the
+    route stage's counts (:func:`unpack_stats` of ``MeshStages.route``):
+    shuffle 1 and the join from their exact loads, shuffle 2 from its
+    source's pre-dedup pairs spread over the destinations (the join stage
+    counts its exact loads, which a retry takes)."""
+    pre = int(route["pair_total"].max())
+    return dict(
+        shingle_route_cap=pow2_capacity(route["loads"].max(), slack),
+        local_pair_cap=pow2_capacity(pre, slack),
+        pair_route_cap=pow2_capacity(pre / n_shards, slack),
     )
-    spec_out = (P(axis_name), P(axis_name), P(axis_name), P(axis_name),
-                P(axis_name), P(axis_name))
-    fn = compat.shard_map(
-        shard_fn, mesh=mesh, in_specs=spec_in, out_specs=spec_out
+
+
+def plan_score(join: dict, n_shards: int, slack: float, *, score_mode: str,
+               score_prune: bool, min_chunks: int = 1,
+               hop_row_bytes: int = 0, memory_bytes: int = 0) -> dict:
+    """Capacities of the score stage from the join stage's counts.
+
+    The pair buffer covers each shard's deduped pairs and the pruned
+    buffer its survivors.  In shuffle mode the owner hops are sized from
+    the exact (owner(left), owner(right)) loads; their code rows take
+    ``hop_row_bytes`` a hop slot, and the hops run in as many chunks
+    (a power of two, at least ``min_chunks``) as keep one chunk's hop
+    buffers within a quarter of ``memory_bytes``.  Chunk ``c`` holds slots
+    ``c, c + n_chunks, ...`` of a buffer sorted by pair, so it carries an
+    even share of every load."""
+    count = int(join["count"].max())
+    kept = int(join["kept"].max())
+    plan = dict(scored_cap=pow2_capacity(count, slack), pruned_cap=0,
+                owner_route_cap=0, n_chunks=1, chunk_hop_cap=0,
+                chunk_rest_cap=0)
+    if score_prune:
+        plan["pruned_cap"] = pow2_capacity(kept, slack)
+    if score_mode != "shuffle":
+        return plan
+    h = join["owner_loads"].reshape(-1, n_shards, n_shards)
+    hop_need = max(int(h.sum(axis=2).max()), int(h.sum(axis=0).max()))
+    rest_need = int(h.sum(axis=(0, 1)).max())
+    pre_key = "pruned_cap" if score_prune else "scored_cap"
+    hop_rows = n_shards * pow2_capacity(hop_need, slack)
+    n_chunks = max(min_chunks, 1)
+    if memory_bytes and hop_row_bytes:
+        n_chunks = max(n_chunks, _pow2(
+            -(-hop_rows * hop_row_bytes // (memory_bytes // 4)), 0))
+    n_chunks = min(n_chunks, plan[pre_key])
+    if n_chunks == 1:
+        plan[pre_key] = max(plan[pre_key], pow2_capacity(rest_need, slack))
+        plan["owner_route_cap"] = pow2_capacity(hop_need, slack)
+        return plan
+    plan.update(
+        n_chunks=n_chunks,
+        chunk_hop_cap=pow2_capacity(-(-hop_need // n_chunks), slack),
+        chunk_rest_cap=pow2_capacity(-(-rest_need // n_chunks), slack),
     )
+    return plan
 
-    @jax.jit
-    def run(first, places, lengths, tables):
-        left, right, level_lcs, mss, overflow, pruned = fn(
-            first, places, lengths, tables
-        )
-        return {
-            "left": left.reshape(n_shards, -1),
-            "right": right.reshape(n_shards, -1),
-            "level_lcs": level_lcs.reshape(n_shards, rest_total, -1),
-            "mss": mss.reshape(n_shards, -1),
-            "overflow": overflow.reshape(n_shards, -1),
-            "pruned": pruned.reshape(n_shards),
-        }
 
-    return run
+def sticky_plan(plan: DistributedPlan, prev: DistributedPlan | None
+                ) -> DistributedPlan:
+    """Monotone max over every capacity, so jobs of one size resolve to
+    one plan and reuse its compiled programs."""
+    if prev is None:
+        return plan
+    return dataclasses.replace(plan, **{
+        f.name: max(getattr(plan, f.name), getattr(prev, f.name))
+        for f in dataclasses.fields(plan)
+        if f.name not in ("n_shards", "local_n")
+    })
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1000,7 +1302,7 @@ def make_streaming_join_pipeline(
             trace_counter[0] += 1  # executes per compile, not per update
         valid = keys != PAD_KEY
         dest = _positive_hash(keys) % n_shards
-        (rk, rr), o1 = _route(
+        (rk, rr), o1, _, _ = _route(
             (keys, rows), dest, valid,
             n_shards=n_shards, capacity=plan.key_route_cap,
             pads=(PAD_KEY, PAD_ID), axis_name=axis_name,
@@ -1010,7 +1312,7 @@ def make_streaming_join_pipeline(
         )
         pvalid = lo != PAD_ID
         pdest = _pair_hash(lo, hi) % n_shards
-        (rlo, rhi), o3 = _route(
+        (rlo, rhi), o3, _, _ = _route(
             (lo, hi), pdest, pvalid,
             n_shards=n_shards, capacity=plan.pair_route_cap,
             pads=(PAD_ID, PAD_ID), axis_name=axis_name,
@@ -1074,8 +1376,8 @@ def make_streaming_score_pipeline(
 ):
     """Build the jitted shard_map DELTA score program for streaming updates.
 
-    Unlike :func:`make_sharded_pipeline` there is no join here: candidate
-    generation is incremental (the host bucket index emits only
+    Unlike the one-shot job (:class:`MeshStages`) there is no join here:
+    candidate generation is incremental (the host bucket index emits only
     new-vs-world pairs), so the device program just encodes each shard's
     resident world rows in-mesh and scores the already-deduped delta pairs
     through the selected ``lcs_impl``.
@@ -1116,10 +1418,10 @@ def make_streaming_score_pipeline(
 
     With ``plan.n_chunks > 1`` (shuffle mode) each shard's pair slice is
     split into sub-chunks and the owner hops software-pipeline against
-    scoring exactly as in :func:`make_sharded_pipeline`: chunk i+1's hops
-    are issued before chunk i scores, per-pair results stay bit-identical,
-    and ``n_chunks`` is static in the plan so the zero-steady-state-
-    recompile contract (``trace_counter``) is untouched.
+    scoring exactly as in the one-shot job (:meth:`_OneShot.score`): chunk
+    i+1's hops are issued before chunk i scores, per-pair results stay
+    bit-identical, and ``n_chunks`` is static in the plan so the
+    zero-steady-state-recompile contract (``trace_counter``) is untouched.
 
     ``tuning`` (optional :class:`repro.perf.LCSTuning`) resolves eagerly
     at build time into static kernel args via ``lcs_impl_fn``.
@@ -1143,16 +1445,12 @@ def make_streaming_score_pipeline(
     else:
         rest_total = out_cap
 
-    def _lengths_of(code_rows):
-        # lengths reconstructed from the padding sentinel in level 0
-        return jnp.sum(code_rows[:, 0, :] >= 0, axis=-1).astype(jnp.int32)
-
-    def _score_gathered(codes_l, codes_r, cap):
-        """Score one resting operand stack (post-hop) -> (level_lcs, mss)."""
-        iota = jnp.arange(cap, dtype=jnp.int32)
+    def _score_hopped(h, codes):
+        """Score one hop's resting pairs -> (level_lcs, mss): left rows in
+        the received code rows, right rows in this shard's own."""
         return score_indexed(
-            codes_l, _lengths_of(codes_l), codes_r, _lengths_of(codes_r),
-            iota, iota, betas, impl=impl,
+            h[2], _lengths_of(h[2]), codes, _lengths_of(codes), h[3], h[4],
+            betas, impl=impl,
         )
 
     def _phys(g, valid):
@@ -1212,8 +1510,9 @@ def make_streaming_score_pipeline(
                 )
 
             if n_chunks == 1:
-                out_l, out_r, codes_l, codes_r, ovf = hop(left, right)
-                level_lcs, mss = _score_gathered(codes_l, codes_r, out_cap)
+                h = hop(left, right)
+                out_l, out_r, ovf = h[0], h[1], h[5]
+                level_lcs, mss = _score_hopped(h, codes)
             else:
                 # software pipeline: issue chunk i+1's owner hops BEFORE
                 # scoring chunk i's resting pairs (no data dependence
@@ -1223,17 +1522,11 @@ def make_streaming_score_pipeline(
                 for i in range(1, n_chunks):
                     sl = slice(i * _sub, (i + 1) * _sub)
                     nxt = hop(left[sl], right[sl])
-                    parts.append(
-                        pending[:2]
-                        + _score_gathered(pending[2], pending[3], out_cap)
-                        + (pending[4],)
-                    )
+                    parts.append(pending[:2] + _score_hopped(pending, codes)
+                                 + (pending[5],))
                     pending = nxt
-                parts.append(
-                    pending[:2]
-                    + _score_gathered(pending[2], pending[3], out_cap)
-                    + (pending[4],)
-                )
+                parts.append(pending[:2] + _score_hopped(pending, codes)
+                             + (pending[5],))
                 out_l = jnp.concatenate([p[0] for p in parts])
                 out_r = jnp.concatenate([p[1] for p in parts])
                 level_lcs = jnp.concatenate([p[2] for p in parts])
@@ -1267,51 +1560,13 @@ def make_streaming_score_pipeline(
     return run
 
 
-def make_distributed_anotherme(
-    mesh: jax.sharding.Mesh,
-    plan: DistributedPlan,
-    *,
-    tables: jnp.ndarray,
-    k: int,
-    num_types: int,
-    betas: jnp.ndarray,
-    axis_name: str = "ex",
-    dedup: bool = True,
-    score_mode: str = "replicate",
-    lcs_impl: str = "wavefront",
-):
-    """Legacy entry point: the SSH-shingle sharded pipeline.
-
-    Thin adapter over :func:`make_sharded_pipeline` with the shingle key_fn;
-    prefer ``AnotherMeEngine`` with ``ExecutionPlan(n_shards=...)``.  The
-    forest ``tables`` are required because encoding runs in-mesh; the
-    returned fn takes ``(places [N, L], lengths [N])``.
-    """
-
-    def key_fn(local_types, local_lengths):
-        return shingles_from_types(
-            local_types, local_lengths, k=k, num_types=num_types, dedup=dedup
-        )
-
-    inner = make_sharded_pipeline(
-        mesh, plan, betas=betas, key_fn=key_fn,
-        axis_name=axis_name, score_mode=score_mode, lcs_impl=lcs_impl,
-    )
-    tables = jnp.asarray(tables)
-
-    def run(places, lengths):
-        return inner(places, places, lengths, tables)
-
-    return run
-
-
 def gather_similar_pairs(out: dict, rho: float) -> set[tuple[int, int]]:
     """Host-side collection of the globally-deduped similar pair set."""
     left = np.asarray(out["left"]).reshape(-1)
     right = np.asarray(out["right"]).reshape(-1)
     mss = np.asarray(out["mss"]).reshape(-1)
     keep = (left != PAD_ID) & (mss > rho)
-    return {(int(a), int(b)) for a, b in zip(left[keep], right[keep])}
+    return set(zip(left[keep].tolist(), right[keep].tolist()))
 
 
 def pad_to_shards(places: np.ndarray, lengths: np.ndarray, n_shards: int):

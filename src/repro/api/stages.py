@@ -4,12 +4,13 @@ Each stage is a small object with a ``run(ctx)`` method that reads and
 writes one :class:`PipelineContext`.  Stages hold no timing code (that is
 the instrumentation wrapper's job) and no capacity policy (that is the
 planner's), so the same stage objects serve the single-device engine, the
-sharded engine (which swaps the middle stages for a fused shard_map stage,
-see api/sharded.py), and any future composition.
+sharded engine (which swaps the middle stages for staged shard_map
+programs, see api/sharded.MeshStages), and any future composition.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Protocol
 
 import jax.numpy as jnp
@@ -264,10 +265,11 @@ class CommunitiesStage:
             if cfg.community_mode == "cliques":
                 ctx.communities = comm.maximal_cliques(pairs)
             elif cfg.community_mode == "components":
-                if pairs:
-                    sl, sr = map(np.asarray, zip(*sorted(pairs)))
-                else:
-                    sl = sr = np.empty((0,), np.int32)
+                # the pairs as sorted arrays, without a sort of tuples
+                flat = np.fromiter(itertools.chain.from_iterable(pairs),
+                                   np.int32, 2 * len(pairs)).reshape(-1, 2)
+                flat = flat[np.lexsort((flat[:, 1], flat[:, 0]))]
+                sl, sr = flat[:, 0], flat[:, 1]
                 labels = comm.connected_components(
                     jnp.asarray(sl, jnp.int32), jnp.asarray(sr, jnp.int32),
                     num_nodes=ctx.batch.num_trajectories,

@@ -87,10 +87,13 @@ def connected_components(
 def components_as_sets(labels: np.ndarray, min_size: int = 2) -> set[frozenset]:
     """Host conversion: labels -> {frozenset(member ids)} of size >= min_size."""
     labels = np.asarray(labels)
-    groups: dict[int, list[int]] = {}
-    for node, lab in enumerate(labels):
-        groups.setdefault(int(lab), []).append(node)
-    return {frozenset(g) for g in groups.values() if len(g) >= min_size}
+    order = np.argsort(labels, kind="stable")
+    lab = labels[order]
+    starts = np.flatnonzero(np.r_[True, lab[1:] != lab[:-1]])
+    sizes = np.diff(np.r_[starts, lab.size])
+    big = sizes >= min_size
+    return {frozenset(order[s:s + z].tolist())
+            for s, z in zip(starts[big].tolist(), sizes[big].tolist())}
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,9 @@
 """Deprecated location — the sharded pipeline moved to ``repro.api.sharded``.
 
 This module re-exports the old names so existing imports keep working.
-``make_distributed_anotherme`` is now a thin adapter over
-:func:`repro.api.sharded.make_sharded_pipeline` with the SSH-shingle key
-function; prefer ``AnotherMeEngine`` with ``ExecutionPlan(n_shards=...)``,
-which also supports the "minhash"/"brp"/"udf" candidate backends on the
-same shard_map machinery.
+The one-shot job on a mesh runs through ``AnotherMeEngine`` with
+``ExecutionPlan(n_shards=...)`` (its staged programs are
+:class:`repro.api.sharded.MeshStages`).
 """
 from repro.api.sharded import (  # noqa: F401
     DistributedPlan,
@@ -13,8 +11,6 @@ from repro.api.sharded import (  # noqa: F401
     _positive_hash,
     _route,
     gather_similar_pairs,
-    make_distributed_anotherme,
-    make_sharded_pipeline,
     pad_to_shards,
     plan_capacities,
 )

@@ -106,12 +106,13 @@ def shingles_from_types(
     keys = pack_keys(safe, num_types)
     keys = jnp.where(valid, keys, PAD_KEY)
     if dedup:
-        keys = jnp.sort(keys, axis=-1)
+        # one operand: stability cannot show, and costs the TPU compiler
+        keys = jnp.sort(keys, axis=-1, stable=False)
         dup = jnp.concatenate(
             [jnp.zeros((n, 1), dtype=bool), keys[:, 1:] == keys[:, :-1]], axis=1
         )
         keys = jnp.where(dup, PAD_KEY, keys)
-        keys = jnp.sort(keys, axis=-1)
+        keys = jnp.sort(keys, axis=-1, stable=False)
     return keys
 
 

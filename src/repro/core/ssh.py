@@ -32,6 +32,36 @@ import jax.numpy as jnp
 from repro.core.types import CandidatePairs, PAD_ID, PAD_KEY
 
 
+_SCAN_ROW = 1024
+
+
+def running(op, x: jnp.ndarray, *, reverse: bool = False) -> jnp.ndarray:
+    """Inclusive running ``op`` ("sum", "min" or "max") of a 1-D array.
+
+    A two-level scan: within rows of 1,024 entries, then over the rows'
+    totals.  The results equal ``jnp.cumsum`` / ``lax.cummin`` /
+    ``lax.cummax`` exactly (int32 sums wrap alike); the TPU compiler builds
+    one flat scan of 2^26 entries in seconds to minutes (a reverse one, or
+    one whose length is not a power of two, in over half a minute), and
+    this one in about a second."""
+    ident = {"sum": 0, "min": jnp.iinfo(x.dtype).max,
+             "max": jnp.iinfo(x.dtype).min}[op]
+    scan = {"sum": jax.lax.cumsum, "min": jax.lax.cummin,
+            "max": jax.lax.cummax}[op]
+    comb = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[op]
+    n = x.shape[0]
+    if reverse:
+        x = jnp.flip(x)
+    rows = jnp.concatenate(
+        [x, jnp.full((-n % _SCAN_ROW,), ident, x.dtype)]
+    ).reshape(-1, _SCAN_ROW)
+    inner = scan(rows, axis=1)
+    tot = scan(inner[:, -1])
+    before = jnp.concatenate([jnp.full((1,), ident, x.dtype), tot[:-1]])
+    out = comb(inner, before[:, None]).reshape(-1)[:n]
+    return jnp.flip(out) if reverse else out
+
+
 def _runs(sorted_keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-row (rank within equal-key run, validity) for ascending keys."""
     r = sorted_keys.shape[0]
@@ -39,13 +69,14 @@ def _runs(sorted_keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     start = jnp.concatenate(
         [jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]]
     )
-    run_start = jax.lax.cummax(jnp.where(start, idx, -1))
+    run_start = running("max", jnp.where(start, idx, -1))
     rank = idx - run_start
     return rank, sorted_keys != PAD_KEY
 
 
 def pairs_from_rows(
-    keys: jnp.ndarray, ids: jnp.ndarray, *, pair_capacity: int
+    keys: jnp.ndarray, ids: jnp.ndarray, *, pair_capacity: int,
+    presorted: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Exact-compact pair enumeration over flat (key, id) rows.
 
@@ -55,14 +86,15 @@ def pairs_from_rows(
     contain it: that row with the ``t``-th member of its run, ``t = p -
     excl``.  Past ``pair_capacity`` the row-major tail is cut and counted
     in ``overflow``.  Shared by the single-device join and the distributed
-    post-shuffle local join.
+    post-shuffle local join, whose rows come ``presorted`` by key.
     """
-    with jax.named_scope("ssh/sort"):
-        keys, ids = jax.lax.sort((keys, ids), num_keys=1)
+    if not presorted:
+        with jax.named_scope("ssh/sort"):
+            keys, ids = jax.lax.sort((keys, ids), num_keys=1)
     with jax.named_scope("ssh/runs"):
         rank, valid = _runs(keys)
         contrib = jnp.where(valid, rank, 0)
-        excl = jnp.cumsum(contrib) - contrib  # exclusive prefix
+        excl = running("sum", contrib) - contrib  # exclusive prefix
         total = excl[-1] + contrib[-1]
 
     with jax.named_scope("ssh/pairs_from_rows"):
@@ -88,9 +120,9 @@ def pairs_from_rows(
         )
         is_slot = (key & 1) == 1
         half = (key >> 1).astype(jnp.int32)
-        a = jnp.cumsum(step)  # wraps in int32 and still telescopes exactly
-        row = jnp.cumsum(~is_slot, dtype=jnp.int32) - 1
-        end = jax.lax.cummin(jnp.where(is_slot, total, half), reverse=True)
+        a = running("sum", step)  # wraps in int32, still telescopes exactly
+        row = running("sum", (~is_slot).astype(jnp.int32)) - 1
+        end = running("min", jnp.where(is_slot, total, half), reverse=True)
         partner = row + half - end
         # the slot entries, to the front in slot order
         _, a, partner = jax.lax.sort(
@@ -135,14 +167,17 @@ def dedup_pairs(
     lo: jnp.ndarray, hi: jnp.ndarray, overflow: jnp.ndarray | int = 0
 ) -> CandidatePairs:
     """Canonicalize + deduplicate pair lists (PAD_ID slots sort to the end)."""
-    lo, hi = jax.lax.sort((lo, hi), num_keys=2)
+    # ties are equal in both operands, so stability cannot show; unstable
+    # sorts compile several times faster on the TPU
+    lo, hi = jax.lax.sort((lo, hi), num_keys=2, is_stable=False)
     dup = jnp.concatenate(
         [jnp.zeros((1,), bool), (lo[1:] == lo[:-1]) & (hi[1:] == hi[:-1])]
     )
     bad = dup | (lo == hi) | (lo == PAD_ID)
     lo = jnp.where(bad, PAD_ID, lo)
     hi = jnp.where(bad, PAD_ID, hi)
-    lo, hi = jax.lax.sort((lo, hi), num_keys=2)  # compact valid slots to front
+    # compact valid slots to front
+    lo, hi = jax.lax.sort((lo, hi), num_keys=2, is_stable=False)
     count = jnp.sum(lo != PAD_ID).astype(jnp.int32)
     return CandidatePairs(
         left=lo, right=hi, count=count, overflow=jnp.asarray(overflow, jnp.int32)
@@ -151,6 +186,6 @@ def dedup_pairs(
 
 def exact_pair_count(shingle_keys: jnp.ndarray) -> int:
     """Host helper: the true (pre-dedup) join size, for capacity planning."""
-    keys = jnp.sort(shingle_keys.reshape(-1))
+    keys = jnp.sort(shingle_keys.reshape(-1), stable=False)
     rank, valid = _runs(keys)
     return int(jnp.sum(jnp.where(valid, rank, 0)))

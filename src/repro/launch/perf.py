@@ -136,11 +136,11 @@ def probe_lm(arch, shape_name, cfg_over, grad_accum):
 
 
 def probe_anotherme(cfg_over):
-    from repro.core.distributed import DistributedPlan, make_distributed_anotherme
-    from repro.core.similarity import default_betas
+    from repro.api import DistributedPlan
+    from repro.core.shingling import shingles_from_types
+    from repro.launch.dryrun import compile_mesh_stages, stages_costs
     from repro.launch.mesh import make_executor_mesh
     from repro.launch import hlo_analysis as H
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     n_traj, L = 1_048_576, 16
     mesh = make_executor_mesh(256)
@@ -151,29 +151,20 @@ def probe_anotherme(cfg_over):
         shingle_route_cap=int(local_n * 560 / n_shards * 1.3) + 64,
         local_pair_cap=1 << 18, pair_route_cap=1 << 12, scored_cap=1 << 18,
     )
-    # real forest tables (paper scale: 300 types, 10k places): the adapter
-    # closes over them and encoding runs in-mesh — no code-table input
-    from repro.core.encoding import forest_tables, make_random_forest
+    dedup = cfg_over.get("dedup", True)
 
-    tables = forest_tables(make_random_forest(300, 10, 10_000))
-    run = make_distributed_anotherme(
-        mesh, plan, tables=tables, k=3, num_types=300, betas=default_betas(3),
-        dedup=cfg_over.get("dedup", True),
-    )
-    places = jax.ShapeDtypeStruct((n_traj, L), jnp.int32,
-                                  sharding=NamedSharding(mesh, P("ex", None)))
-    lengths = jax.ShapeDtypeStruct((n_traj,), jnp.int32,
-                                   sharding=NamedSharding(mesh, P("ex")))
-    compiled = jax.jit(run).lower(places, lengths).compile()
-    ca = compiled.cost_analysis()
-    coll = H.collective_bytes(compiled.as_text())
-    mem = H.memory_summary(compiled)
+    def key_fn(local_types, local_lengths):
+        return shingles_from_types(local_types, local_lengths, k=3,
+                                   num_types=300, dedup=dedup)
+
+    costs = stages_costs(compile_mesh_stages(mesh, plan, key_fn, L=L))
+    coll = costs["collectives"]
     return {
-        "compute_s": float(ca.get("flops", 0)) / H.PEAK_FLOPS,
-        "memory_s": float(ca.get("bytes accessed", 0)) / H.HBM_BW,
+        "compute_s": costs["flops"] / H.PEAK_FLOPS,
+        "memory_s": costs["bytes"] / H.HBM_BW,
         "collective_s": coll["total_bytes"] / H.ICI_BW,
         "coll_by_kind": coll["bytes"],
-        "mem_per_dev": mem["peak_bytes_est"],
+        "mem_per_dev": costs["memory"]["peak_bytes_est"],
     }
 
 

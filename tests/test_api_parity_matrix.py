@@ -738,10 +738,13 @@ for impl in IMPLS:
             assert res.communities == want.communities, cell
             assert score_map(res) == score_map(want), cell
             if n_shards > 1:
-                # steady state: a same-shape re-run must reuse the ONE
-                # cached compiled runner — zero recompiles in windowed mode
+                # steady state: a same-shape re-run keeps the ONE sticky
+                # plan and compiles no mesh program in windowed mode
+                plans = dict(eng._sticky)
                 res2 = eng.run(batch)
-                assert len(eng._runner_cache) == 1, cell
+                assert len(eng._sticky) == 1 and eng._sticky == plans, cell
+                assert not {"plan", "join", "score", "results"} & set(
+                    res2.stats["compiles_by_phase"]), cell
                 assert score_map(res2) == score_map(res), cell
 print("OK subtraj", backend)
 """

@@ -177,3 +177,23 @@ def test_pairs_from_rows_slot_for_slot(name):
     np.testing.assert_array_equal(np.asarray(lo), want_lo)
     np.testing.assert_array_equal(np.asarray(hi), want_hi)
     assert int(overflow) == want_overflow
+
+
+@pytest.mark.parametrize("n", [1, 7, 1023, 1024, 1025, 5 * 1024 + 3])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_running_scan_equals_flat_scan(n, op, reverse):
+    """The two-level running scan equals jnp's flat one entry for entry,
+    int32 wraparound and uint32 keys included, forwards and backwards."""
+    from repro.core.ssh import running
+
+    rng = np.random.default_rng(n)
+    flat = {"sum": jnp.cumsum, "min": jax.lax.cummin,
+            "max": jax.lax.cummax}[op]
+    for dtype in (np.int32, np.uint32):
+        info = np.iinfo(dtype)
+        x = jnp.asarray(rng.integers(info.min, info.max, n, dtype=dtype))
+        want = (jnp.flip(flat(jnp.flip(x))) if reverse else flat(x))
+        got = running(op, x, reverse=reverse)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

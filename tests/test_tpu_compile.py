@@ -134,3 +134,63 @@ def test_ssh_pair_enumeration_has_no_binary_search(one_chip):
     assert any(re.search(r"\) sort\(", line) for line in ops)
     assert not any("searchsorted" in line or " while(" in line for line in ops)
     assert sum(bool(re.search(r"= \S+ gather\(", line)) for line in ops) <= 1
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    from jax.experimental import topologies
+
+    from repro.core import compat
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return compat.make_mesh((4,), ("ex",), devices=list(topo.devices))
+
+
+def test_mesh_programs_sort_unstably(four_chips):
+    """The one-shot mesh programs (route, join, score, similar), compiled
+    for a described v5e 2x2 at a small size, hold no stable sort: the TPU
+    compiler gives a stable sort an iota operand, and at 2^26 entries such
+    a sort takes it several times as long to build as an unstable one, so
+    a cold job's set-up would grow by minutes."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.api import BackendContext, DistributedPlan, MeshStages, get_backend
+    from repro.core.similarity import default_betas
+
+    mesh, n, local_n, L = four_chips, 4, 256, 10
+    plan = DistributedPlan(
+        n_shards=n, local_n=local_n, shingle_route_cap=1 << 12,
+        local_pair_cap=1 << 14, pair_route_cap=1 << 12, scored_cap=1 << 14,
+        n_chunks=4, chunk_hop_cap=1 << 11, chunk_rest_cap=1 << 13)
+    stages = MeshStages(
+        mesh, n_shards=n, local_n=local_n, betas=default_betas(3),
+        key_fn=get_backend("ssh").shard_key_fn(
+            BackendContext(k=3, num_types=300)),
+        axis_name="ex", score_mode="shuffle", lcs_impl="fused",
+        score_prune=False, prune_tau=2.0, subtraj=None, rho=2.0)
+    rows, flat = NamedSharding(mesh, P("ex", None)), NamedSharding(mesh, P("ex"))
+    places = _spec(rows, (n * local_n, L))
+    lengths = _spec(flat, (n * local_n,))
+    tables = _spec(NamedSharding(mesh, P()), (3, 10_000))
+
+    def sharded(outs):
+        return [_spec(flat, x.shape, x.dtype) for x in outs]
+
+    route = stages.route(plan.shingle_route_cap)
+    join = stages.join(plan.local_pair_cap, plan.pair_route_cap)
+    rk, rid, _ = sharded(jax.eval_shape(route, places, places, lengths,
+                                        tables))
+    left, right, _ = sharded(jax.eval_shape(join, rk, rid, lengths))
+    mss = _spec(flat, left.shape, jnp.float32)
+    programs = {
+        "route": route.lower(places, places, lengths, tables),
+        "join": join.lower(rk, rid, lengths),
+        "score": stages.score(plan).lower(left, right, places, lengths,
+                                          tables),
+        "similar": stages.similar(1 << 10).lower(left, right, mss),
+    }
+    for name, lowered in programs.items():
+        sorts = [line for line in lowered.compile().as_text().splitlines()
+                 if re.search(r" sort\(", line)]
+        assert sorts, name
+        assert not any("is_stable=true" in line for line in sorts), name
