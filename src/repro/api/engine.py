@@ -45,7 +45,7 @@ from repro.api.sharded import (
 )
 from repro.api.stages import (
     CandidateStage, CommunitiesStage, EncodeStage, PipelineContext, ScoreStage,
-    validate_lcs_impl,
+    record_similar, validate_lcs_impl,
 )
 from repro.core import compat
 from repro.core.encoding import SemanticForest, encode_types, forest_tables
@@ -220,7 +220,7 @@ class AnotherMeEngine:
             )
         self._mesh = None
         self._stages_cache: dict = {}
-        self._sticky: dict = {}        # batch shape -> sticky plan
+        self._sticky: dict = {}        # plans and capacities, by batch shape
 
     # -- public entry point --------------------------------------------------
 
@@ -233,7 +233,7 @@ class AnotherMeEngine:
                 batch=batch, forest=self.forest, tables=self.tables,
                 betas=self.betas, config=self.config, backend=self.backend,
                 backend_ctx=self.backend_ctx, planner=self.planner,
-                instr=instr,
+                instr=instr, sticky=self._sticky,
             )
             for stage in self._stages:
                 stage.run(ctx)
@@ -369,11 +369,8 @@ class _ShardedEncodeJoinScoreStage:
                     left=tl, right=tr, level_lcs=tlvl, mss=tmss,
                     count=np.int32(tl.shape[0]), overflow=overflow,
                 )
-                ctx.similar_pairs = {
-                    (int(a), int(b))
-                    for a, b, m in zip(tl, tr, tmss)
-                    if m > np.float32(config.rho)
-                }
+                keep = tmss > np.float32(config.rho)
+                record_similar(ctx, tl[keep], tr[keep])
         eng._sticky[shape] = dplan
         self._record(instr, dplan, counts, overflow)
         instr.record(
@@ -388,10 +385,11 @@ class _ShardedEncodeJoinScoreStage:
             )
 
     def _similar(self, ctx, stages, dplan, out):
-        """The similar-pair set on the host: each shard's similar pairs are
+        """The similar pairs on the host: each shard's similar pairs are
         counted and moved to the front of a sticky power-of-two buffer in
-        the mesh, and only that buffer is copied.  Returns the plan with
-        the buffer's capacity."""
+        the mesh, and only that buffer is copied; it is the communities
+        program's edge buffer as it is.  Returns the plan with the
+        buffer's capacity."""
         n = dplan.n_shards
         sims = np.asarray(stages.similar_count()(out[3])[0])
         # the count is exact, so the slack only keeps the buffer sticky
@@ -399,9 +397,9 @@ class _ShardedEncodeJoinScoreStage:
         cap = max(pow2_capacity(sims.max(), slack), dplan.similar_cap)
         left, right = (np.asarray(x).reshape(n, cap)
                        for x in stages.similar(cap)(out[0], out[1], out[3]))
-        ctx.similar_pairs = set(zip(*(
-            np.concatenate([x[s, :k] for s, k in enumerate(sims)]).tolist()
-            for x in (left, right))))
+        record_similar(ctx, *(
+            np.concatenate([x[s, :k] for s, k in enumerate(sims)])
+            for x in (left, right)), buffer=(left.ravel(), right.ravel()))
         return dataclasses.replace(dplan, similar_cap=cap)
 
     @staticmethod

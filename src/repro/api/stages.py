@@ -10,7 +10,6 @@ programs, see api/sharded.MeshStages), and any future composition.
 from __future__ import annotations
 
 import dataclasses
-import itertools
 from typing import Any, Protocol
 
 import jax.numpy as jnp
@@ -81,7 +80,10 @@ class PipelineContext:
     candidates: CandidatePairs | None = None
     scored: ScoredPairs | None = None
     similar_pairs: set | None = None
+    similar_edges: tuple | None = None  # (left, right): see record_similar
     communities: set | None = None
+    # capacities kept across the engine's jobs, by batch shape
+    sticky: dict = dataclasses.field(default_factory=dict)
 
 
 class Stage(Protocol):
@@ -215,11 +217,8 @@ class ScoreStage:
                 tl, tr, tlvl, tmss = aggregate_window_pairs(
                     cand.left, cand.right, level_lcs, mss, nw=subtraj[2]
                 )
-                ctx.similar_pairs = {
-                    (int(a), int(b))
-                    for a, b, m in zip(tl, tr, tmss)
-                    if m > np.float32(cfg.rho)
-                }
+                keep = tmss > np.float32(cfg.rho)
+                record_similar(ctx, tl[keep], tr[keep])
                 ctx.scored = ScoredPairs(
                     left=jnp.asarray(tl), right=jnp.asarray(tr),
                     level_lcs=jnp.asarray(tlvl), mss=jnp.asarray(tmss),
@@ -238,10 +237,7 @@ class ScoreStage:
             left_np = np.asarray(cand.left)
             right_np = np.asarray(cand.right)
             similar_mask = (left_np != PAD_ID) & (np.asarray(mss) > cfg.rho)
-            ctx.similar_pairs = {
-                (int(a), int(b))
-                for a, b in zip(left_np[similar_mask], right_np[similar_mask])
-            }
+            record_similar(ctx, left_np[similar_mask], right_np[similar_mask])
         ctx.scored = ScoredPairs(
             left=cand.left, right=cand.right, level_lcs=level_lcs, mss=mss,
             count=cand.count, overflow=cand.overflow,
@@ -249,32 +245,56 @@ class ScoreStage:
         ctx.instr.record(num_similar=len(ctx.similar_pairs))
 
 
+def record_similar(ctx: PipelineContext, left, right, buffer=None) -> None:
+    """Leave the similar pairs ``zip(left, right)`` on the context twice:
+    as the set of tuples the result returns (``ctx.similar_pairs``), and as
+    the communities program's edge buffer (``ctx.similar_edges``), two
+    int32 arrays of a fixed capacity with PAD_ID in the slots no pair
+    fills.  ``buffer`` is such a pair of arrays the caller already holds
+    (the mesh's similar buffers); else the pairs go to the front of a new
+    one, whose capacity is a power of two over the count with the
+    planner's slack, kept per batch shape while it holds the pairs, so
+    jobs of one size run one compiled communities program."""
+    left = np.asarray(left, np.int32)
+    right = np.asarray(right, np.int32)
+    ctx.similar_pairs = set(zip(left.tolist(), right.tolist()))
+    if buffer is None:
+        key = ("similar_edges", ctx.batch.num_trajectories)
+        cap = ctx.sticky.get(key, 0)
+        if cap < left.size:
+            cap = ctx.sticky[key] = ctx.planner.initial_capacity(left.size)
+        buffer = tuple(np.full(cap, PAD_ID, np.int32) for _ in range(2))
+        buffer[0][:left.size] = left
+        buffer[1][:left.size] = right
+    ctx.similar_edges = buffer
+
+
 class CommunitiesStage:
     """Phase (iv): communities of interest from the similar-pair graph.
 
-    Operates on the host-side similar-pair set, so it is shared verbatim by
-    the single-device and sharded execution paths.
+    Components run over the edge buffer ``ctx.similar_edges`` (edge order
+    and PAD_ID slots change no label), cliques over the similar-pair set;
+    both are on the context on the single-device and the sharded paths.
     """
 
     name = "communities"
 
     def run(self, ctx: PipelineContext) -> None:
         cfg = ctx.config
-        pairs = ctx.similar_pairs
         with ctx.instr.phase("communities"):
             if cfg.community_mode == "cliques":
-                ctx.communities = comm.maximal_cliques(pairs)
+                ctx.communities = comm.maximal_cliques(ctx.similar_pairs)
             elif cfg.community_mode == "components":
-                # the pairs as sorted arrays, without a sort of tuples
-                flat = np.fromiter(itertools.chain.from_iterable(pairs),
-                                   np.int32, 2 * len(pairs)).reshape(-1, 2)
-                flat = flat[np.lexsort((flat[:, 1], flat[:, 0]))]
-                sl, sr = flat[:, 0], flat[:, 1]
+                left, right = ctx.similar_edges
                 labels = comm.connected_components(
-                    jnp.asarray(sl, jnp.int32), jnp.asarray(sr, jnp.int32),
+                    jnp.asarray(left), jnp.asarray(right),
                     num_nodes=ctx.batch.num_trajectories,
                 )
                 ctx.communities = comm.components_as_sets(np.asarray(labels))
+                ctx.instr.record(
+                    communities_edges=int(np.count_nonzero(left != PAD_ID)),
+                    communities_edge_cap=int(left.shape[0]),
+                )
             else:
                 raise ValueError(
                     f"unknown community_mode {cfg.community_mode!r}; "

@@ -39,7 +39,11 @@ def connected_components(
 
     Returns int32 [num_nodes] component labels (the min node id reachable).
     Convergence in O(diameter) rounds, accelerated by pointer jumping; the
-    while_loop exits early on fixpoint.
+    while_loop exits early on fixpoint, at the same labels in any edge
+    order.  An edge with a PAD_ID end, in slot ``j``, becomes a self-loop
+    of node ``j mod (num_nodes + 1)``: a self-loop changes no label,
+    and a padded buffer spreads its pad slots over every index rather
+    than sending them all to one.
 
     ``init_labels`` (int32 [num_nodes]) warm-starts the propagation — the
     streaming engine seeds it with the previous update's fixpoint, so one
@@ -52,9 +56,11 @@ def connected_components(
     Seeds are clamped to ``min(init_labels[v], v)`` so a cold-start-shaped
     seed (``arange``) is always valid.
     """
-    lo = jnp.where(left == PAD_ID, num_nodes, left)
-    hi = jnp.where(right == PAD_ID, num_nodes, right)
     iota = jnp.arange(num_nodes + 1, dtype=jnp.int32)
+    pad = (left == PAD_ID) | (right == PAD_ID)
+    loop = jnp.arange(left.shape[0], dtype=jnp.int32) % (num_nodes + 1)
+    lo = jnp.where(pad, loop, left)
+    hi = jnp.where(pad, loop, right)
     if init_labels is None:
         init = iota
     else:

@@ -325,3 +325,74 @@ def test_engine_bridge_expiry_splits_then_reforms(components_impl):
     assert got_pairs == score_map(fresh)
     assert {frozenset(trans[v] for v in c) for c in res.communities} \
         == fresh.communities
+
+
+def _uf_labels(n, edges):
+    uf = UnionFind(n)
+    for a, b in edges:
+        uf.union(int(a), int(b))
+    return uf.labels()
+
+
+@pytest.mark.parametrize("fill", ["empty", "partial", "full"])
+@pytest.mark.parametrize("seed", range(8))
+def test_cc_padded_unsorted_buffer_matches_sorted_edges(seed, fill):
+    """The communities edge buffer: pairs in any order, duplicates among
+    them, at any slots of a fixed capacity with PAD_ID in the rest, label
+    as the bare sorted edges do and as a union-find does."""
+    rng = np.random.default_rng(seed)
+    n, cap = int(rng.integers(2, 60)), 64
+    m = {"empty": 0, "partial": int(rng.integers(1, cap)), "full": cap}[fill]
+    base = rng.integers(0, n, size=(m - m // 4, 2), dtype=np.int32)
+    dups = base[rng.integers(0, max(len(base), 1), size=m // 4)] \
+        if len(base) else base
+    edges = rng.permutation(np.concatenate([base, dups]))
+    slots = rng.choice(cap, size=m, replace=False)
+    left = np.full(cap, PAD_ID, np.int32)
+    right = np.full(cap, PAD_ID, np.int32)
+    left[slots], right[slots] = edges[:, 0], edges[:, 1]
+    padded = connected_components(
+        jnp.asarray(left), jnp.asarray(right), num_nodes=n)
+
+    bare = np.unique(edges, axis=0)  # sorted rows, no duplicate
+    sorted_ = connected_components(
+        jnp.asarray(bare[:, 0]), jnp.asarray(bare[:, 1]), num_nodes=n)
+    np.testing.assert_array_equal(np.asarray(padded), np.asarray(sorted_))
+    np.testing.assert_array_equal(np.asarray(padded), _uf_labels(n, edges))
+
+
+def _set_based_communities(pairs, n):
+    """Components from the similar-pair set: the pairs as sorted arrays,
+    as the communities stage once built them."""
+    flat = np.asarray(sorted(pairs), np.int32).reshape(-1, 2)
+    labels = connected_components(
+        jnp.asarray(flat[:, 0]), jnp.asarray(flat[:, 1]), num_nodes=n)
+    return components_as_sets(np.asarray(labels))
+
+
+@pytest.mark.parametrize("path", ["whole", "subtraj"])
+def test_engine_communities_from_edge_buffer_match_set_path(path):
+    """CommunitiesStage over the similar pairs' edge buffer gives the
+    communities the set-based path gives, with the result's types kept;
+    the stats count the buffer's real edges and its slots."""
+    from repro.api import AnotherMeEngine, EngineConfig
+    from repro.data import synthetic_setup
+
+    batch, forest = synthetic_setup(
+        120, num_types=8, classes_per_type=4, num_places=60, seed=5)
+    extra = dict(subtraj_window=5, subtraj_stride=1) \
+        if path == "subtraj" else {}
+    res = AnotherMeEngine(forest, EngineConfig(
+        k=2, rho=1.5, community_mode="components", **extra)).run(batch)
+    n = batch.num_trajectories
+    assert len(res.similar_pairs) > 20
+    assert all(type(a) is int and type(b) is int
+               for a, b in res.similar_pairs)
+    assert all(isinstance(c, frozenset) for c in res.communities)
+    assert res.communities == _set_based_communities(res.similar_pairs, n)
+    assert res.communities == components_as_sets(
+        _uf_labels(n, res.similar_pairs))
+    s = res.stats
+    assert s["communities_edges"] == len(res.similar_pairs)
+    cap = s["communities_edge_cap"]
+    assert cap >= s["communities_edges"] and cap & (cap - 1) == 0

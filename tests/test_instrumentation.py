@@ -7,10 +7,12 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.api import AnotherMeEngine, EngineConfig, Instrumentation
 from repro.api.instrumentation import BACKEND_COMPILE
+from repro.core.types import TrajectoryBatch
 from repro.data import synthetic_setup
 
 PIPELINE_PHASES = ("encode", "keys", "join", "score", "results",
@@ -158,6 +160,27 @@ def test_engine_run_reports_spans_results_and_compiles(world, tmp_path):
     assert warm.stats["compiles"] == seen.n == 0
     assert warm.stats["compile_s"] == 0
     assert warm.similar_pairs == cold.similar_pairs
+
+
+def test_jobs_of_one_size_compile_communities_once(world):
+    """Two jobs of one size whose similar-pair counts differ: the second
+    runs the communities program the first compiled, its edge buffer of
+    the same capacity."""
+    batch, forest = world
+    engine = AnotherMeEngine(forest,
+                             EngineConfig(community_mode="components"))
+    first = engine.run(batch).stats
+    places = np.asarray(batch.places).copy()
+    places[-8:] = places[:8]  # eight rows repeated: more similar pairs
+    second = engine.run(TrajectoryBatch(
+        places=jnp.asarray(places), lengths=batch.lengths,
+        user_id=batch.user_id)).stats
+    assert second["num_similar"] > first["num_similar"]
+    assert first["communities_edges"] == first["num_similar"]
+    assert second["communities_edges"] == second["num_similar"]
+    assert second["communities_edge_cap"] == first["communities_edge_cap"]
+    assert "communities" not in second["compiles_by_phase"], \
+        second["compiles_by_phase"]
 
 
 def test_streaming_update_reports_compiles(world):

@@ -1,9 +1,9 @@
 """The one-shot job on the mesh, planned from counts made in the mesh.
 
 * back-to-back shuffle-mode jobs of one size equal the single-device
-  engine and the benchmark's plain reference, and the second compiles no
-  program of its own (only the communities program, whose shape is the
-  similar-pair count);
+  engine and the benchmark's plain reference, and the second compiles
+  nothing (its communities program runs over the sticky similar buffer
+  the first compiled for);
 * the counts the staged programs make (key loads of shuffle 1, each
   shard's pre-dedup join size, the dedup shuffle's loads, the deduped
   pairs and the owner-hop loads) equal the host-numpy oracle's, on uniform
@@ -56,7 +56,10 @@ for j in range(2):
     if j == 0:
         assert {"plan", "join", "score"} <= phases, phases
     else:
-        assert phases <= {"communities"}, got.stats["compiles_by_phase"]
+        assert got.stats["compiles_by_phase"] == {}, phases
+    assert got.stats["communities_edges"] == len(got.similar_pairs)
+    assert got.stats["communities_edge_cap"] == \
+        4 * got.stats["shard_plan"]["similar_cap"]
     scored = got.stats["scored_per_chip"]
     assert sum(scored) == got.stats["num_candidates"], scored
     assert got.stats["n_shards"] == 4
