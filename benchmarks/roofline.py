@@ -1,23 +1,24 @@
-"""Roofline summary: reads experiments/dryrun.json (produced by
-launch/dryrun.py) and emits the per-(arch x shape x mesh) table for
-EXPERIMENTS.md §Roofline, plus a validation row comparing HLO flops against
-the analytic 6*N*D model.
+"""Roofline summary and LCS autotune sweep.
 
-``python -m benchmarks.roofline --tune`` additionally runs the LCS
-autotune sweep: for each (P, H, L) cell it measures every candidate
-diagonal dtype of the score stage's jnp wavefront, asserts each candidate's LCS matrix is bit-identical to the untuned
-default, and records the throughput winner into the
-:mod:`repro.perf` tuning table (``TUNING.json`` or
+``python -m benchmarks.roofline`` prints the roofline table of the
+records ``python -m repro.launch.dryrun`` appends to
+``experiments/dryrun.json``.
+
+``python -m benchmarks.roofline --tune`` runs the LCS autotune sweep:
+for each (P, H, L) cell it measures every candidate diagonal dtype of the
+score stage's jnp wavefront, asserts each candidate's LCS matrix is
+bit-identical to the untuned default, and records the throughput winner
+into the :mod:`repro.perf` tuning table (``TUNING.json`` or
 ``$REPRO_TUNING_PATH``).  The engine consults that table when
-``ExecutionPlan(autotune=True)``.
+``ExecutionPlan(autotune=True)``.  Run both from the repo root with
+``PYTHONPATH=src:.``.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import pathlib
-
-from benchmarks.common import Row
+import time
 
 
 def load(path="experiments/dryrun.json"):
@@ -27,70 +28,62 @@ def load(path="experiments/dryrun.json"):
     return json.loads(p.read_text())
 
 
-def run(full: bool = False) -> list[Row]:
-    rows = []
-    recs = load()
-    if not recs:
-        return [Row("roofline/missing", 0.0,
-                    "run: python -m repro.launch.dryrun first")]
-    for r in recs:
-        if r.get("status") != "ok":
-            rows.append(Row(
-                f"roofline/{r['arch']}/{r['shape']}/{r['mesh']}", -1.0,
-                str(r.get("status"))[:80],
-            ))
-            continue
-        mem = r.get("memory", {})
-        if "roofline" not in r:
-            rows.append(Row(
-                f"dryrun/{r['arch']}/{r['shape']}/{r['mesh']}",
-                r.get("compile_s", 0) * 1e6,
-                f"bytes_per_dev={mem.get('peak_bytes_est', 0):.3e}",
-            ))
-            continue
-        rf = r["roofline"]
-        rows.append(Row(
-            f"roofline/{r['arch']}/{r['shape']}/{r['mesh']}",
-            rf["step_time_bound_s"] * 1e6,
-            f"dom={rf['dominant']};compute={rf['compute_s']:.3g}s;"
-            f"memory={rf['memory_s']:.3g}s;coll={rf['collective_s']:.3g}s;"
-            f"mfu_bound={rf['mfu_bound']:.3f};"
-            f"useful={rf['useful_flops_ratio']:.2f};"
-            f"mem_per_dev={mem.get('peak_bytes_est', 0):.3e}",
-        ))
-    return rows
-
-
 def summarize(path="experiments/dryrun.json"):
-    """Human-readable table (used to draft EXPERIMENTS.md)."""
+    """Human-readable roofline table of the dry-run records."""
     recs = [r for r in load(path) if r.get("status") == "ok"]
     hdr = (f"{'arch':22s} {'shape':12s} {'mesh':8s} {'dom':10s} "
            f"{'compute_s':>10s} {'memory_s':>10s} {'coll_s':>10s} "
-           f"{'MFU@bound':>9s} {'useful':>7s} {'mem/dev':>9s}")
+           f"{'mem/dev':>9s}")
     lines = [hdr, "-" * len(hdr)]
     for r in recs:
-        rf = r.get("roofline")
+        rf = r["roofline"]
         mem = r.get("memory", {}).get("peak_bytes_est", 0)
-        if rf is None:
-            lines.append(
-                f"{r['arch']:22s} {r['shape']:12s} {r['mesh']:8s} "
-                f"{'(multi-pod)':10s} {'-':>10s} {'-':>10s} {'-':>10s} "
-                f"{'-':>9s} {'-':>7s} {mem/1e9:8.2f}G"
-            )
-            continue
         lines.append(
             f"{r['arch']:22s} {r['shape']:12s} {r['mesh']:8s} "
             f"{rf['dominant']:10s} {rf['compute_s']:10.4f} "
             f"{rf['memory_s']:10.4f} {rf['collective_s']:10.4f} "
-            f"{rf['mfu_bound']:9.4f} {rf['useful_flops_ratio']:7.2f} "
             f"{mem/1e9:8.2f}G"
         )
     return "\n".join(lines)
 
 
+def _make_inputs(P, H, L, *, seed=0):
+    """A synthetic score-stage workload: code table + pair list.
+
+    Lengths are skewed (heavy short head), matching real trajectory
+    length distributions.
+    """
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    N = max(256, P // 8)
+    w = 1.0 / np.arange(1, L + 1)
+    lengths = rng.choice(np.arange(1, L + 1), size=N, p=w / w.sum())
+    lengths = lengths.astype(np.int32)
+    codes = rng.integers(0, 30, size=(N, H, L)).astype(np.int32)
+    pad = np.arange(L)[None, None, :] >= lengths[:, None, None]
+    codes = np.where(pad, -1, codes)
+    left = rng.integers(0, N, size=P).astype(np.int32)
+    right = rng.integers(0, N, size=P).astype(np.int32)
+    betas = np.full((H,), 1.0 / H, np.float32)
+    return (jnp.asarray(codes), jnp.asarray(lengths), jnp.asarray(left),
+            jnp.asarray(right), jnp.asarray(betas))
+
+
+def _time_call(call, repeats):
+    call().block_until_ready()  # compile + warm
+    t0 = time.perf_counter()
+    out = None
+    for _ in range(repeats):
+        out = call()
+    out.block_until_ready()
+    return (time.perf_counter() - t0) / repeats
+
+
 def _tune_grid(smoke: bool):
-    """(P, H, L) cells to tune.  Smoke covers the shapes the smoke bench
-    and the parity tests hit; full adds the paper-scale cells."""
+    """(P, H, L) cells to tune.  Smoke covers the shapes the parity tests
+    hit; full adds the paper-scale cells."""
     if smoke:
         return [(1024, 3, 16), (4096, 3, 32)]
     return [
@@ -103,7 +96,7 @@ def tune(*, smoke=False, full=False, repeats=3, out_path=None):
     """Sweep the wavefront's diagonal dtype and persist the winners.
 
     For every grid cell the sweep builds one synthetic score-stage
-    workload (same generator as bench_score), computes the untuned
+    workload (:func:`_make_inputs`), computes the untuned
     reference LCS matrix once, then measures every candidate
     ``wavefront_dtype``: int8 vs int32 anti-diagonal carries (int8 only
     where L < 127, where the two are bit-identical).
@@ -117,7 +110,6 @@ def tune(*, smoke=False, full=False, repeats=3, out_path=None):
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks.bench_score import _make_inputs, _time_call
     from repro.core.encoding import PAD_CODE_A, PAD_CODE_B
     from repro.core.similarity import repad
     from repro.kernels.lcs import ops as lcs_ops

@@ -344,7 +344,7 @@ class StreamingEngine:
             # bounded-memory accounting: the live row count, the resident
             # device footprint, the tombstone fraction awaiting
             # compaction, and the compaction history (count + cumulative
-            # stall latency) — the BENCH_stream v3 columns
+            # stall latency)
             world_live=self.live_size, world_base=self._base,
             num_expired=num_expired, retired_total=self.retired_total,
             resident_bytes=self.resident_bytes(),
@@ -443,8 +443,7 @@ class StreamingEngine:
 
     def resident_bytes(self) -> int:
         """Bytes of device-resident world state (code/place tables +
-        join slabs) — the quantity ``max_resident_bytes`` bounds and
-        BENCH_stream v3 tracks."""
+        join slabs) — the quantity ``max_resident_bytes`` bounds."""
         total = 0
         if self._codes_dev is not None:
             total += self._codes_dev.size * 4 + self._len_dev.size * 4
@@ -853,10 +852,12 @@ class StreamingEngine:
         pad_lengths[:d] = lengths
         if not self._mesh_world:
             if rebuild or self._codes_dev is None:
+                # copies: on the CPU backend jnp.asarray of an aligned
+                # numpy array aliases it, and these mirrors change in place
                 self._codes_dev = encode_codes(
-                    jnp.asarray(self._places_np), self.tables
+                    jnp.array(self._places_np), self.tables
                 )
-                self._len_dev = jnp.asarray(self._lengths_np)
+                self._len_dev = jnp.array(self._lengths_np)
                 self._xfer["bytes_in"] += (
                     self._places_np.nbytes + self._lengths_np.nbytes
                 )

@@ -7,6 +7,4 @@ Each kernel package ships three files:
 
 Paper hot spots (section IV): lcs (phase iii similarity DP), shingle
 (phase ii, the O(N*L^3) hash), minhash (the Spark-builtin baseline).
-Model-plane hot spots: attention (flash, GQA/causal), ssd (Mamba-2 chunk
-scan) for the assigned architectures.
 """

@@ -155,24 +155,6 @@ class Roofline:
         }
 
 
-def roofline_from_compiled(compiled, *, chips: int, model_flops: float) -> Roofline:
-    ca = compiled.cost_analysis()
-    flops = float(ca.get("flops", 0.0))
-    bytes_acc = float(ca.get("bytes accessed", 0.0))
-    coll = collective_bytes(compiled.as_text())
-    coll_b = float(coll["total_bytes"])
-    return Roofline(
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=bytes_acc / HBM_BW,
-        collective_s=coll_b / ICI_BW,
-        hlo_flops=flops,
-        hlo_bytes=bytes_acc,
-        coll_bytes=coll_b,
-        model_flops=model_flops,
-        chips=chips,
-    )
-
-
 def memory_summary(compiled) -> dict:
     ma = compiled.memory_analysis()
     return {

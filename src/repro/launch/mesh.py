@@ -2,7 +2,7 @@
 
 ``make_production_mesh`` is a FUNCTION (never a module-level constant) so
 importing this module touches no jax device state; the dry-run sets
-XLA_FLAGS before any jax import and only then builds meshes.
+XLA_FLAGS before jax first touches a device and only then builds meshes.
 
 Mesh shapes (assignment spec): single pod = (data=16, model=16) — 256 chips
 (one v5e pod); multi-pod = (pod=2, data=16, model=16) — 512 chips.  The
@@ -28,8 +28,3 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_executor_mesh(n_devices: int | None = None):
     n = n_devices or len(jax.devices())
     return compat.make_mesh((n,), ("ex",))
-
-
-def make_smoke_mesh():
-    """1-device mesh with the production axis names (CPU tests)."""
-    return compat.make_mesh((1, 1), ("data", "model"))

@@ -32,8 +32,9 @@ parameters.  Misses fall back to the nearest recorded ``P`` for the same
 ``(H, L, backend)`` (the winner varies slowly in P), then to ``None`` —
 callers keep their current defaults on a total miss.
 
-The table is populated by ``python -m benchmarks.roofline --tune`` and
-invalidated wholesale when the schema, jax version, or backend it was
+The table's one writer is ``benchmarks/roofline.py --tune``
+(``PYTHONPATH=src:. python -m benchmarks.roofline --tune``).  The table
+is invalidated wholesale when the schema, jax version, or backend it was
 measured on changes — a stale table silently tuning a different machine is
 worse than no table.
 """

@@ -2,11 +2,13 @@ import os
 import subprocess
 import sys
 
-import pytest
+import jax.numpy as jnp
+import numpy as np
+
+from repro.core.types import PAD_ID, TrajectoryBatch
 
 # Tests run on the default single CPU device; multi-device tests spawn
-# subprocesses with XLA_FLAGS (dryrun.py is the only in-process user of
-# forced host device counts, and it is never imported here).
+# subprocesses with XLA_FLAGS (the device count binds at jax init).
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 
@@ -27,8 +29,23 @@ def run_subprocess(code: str, devices: int = 8, timeout: int = 900):
     return proc.stdout
 
 
-@pytest.fixture
-def smoke_mesh():
-    from repro.core import compat
+def make_batch(places, lengths):
+    """A TrajectoryBatch of the given rows, user ids 0..n-1."""
+    return TrajectoryBatch(
+        places=jnp.asarray(np.asarray(places, np.int32)),
+        lengths=jnp.asarray(np.asarray(lengths, np.int32)),
+        user_id=jnp.arange(np.asarray(places).shape[0], dtype=jnp.int32),
+    )
 
-    return compat.make_mesh((1, 1), ("data", "model"))
+
+def score_map(res):
+    """``{(left, right): (mss, level LCS)}`` of a result's scored pairs."""
+    left = np.asarray(res.scored.left)
+    right = np.asarray(res.scored.right)
+    mss = np.asarray(res.scored.mss)
+    lvl = np.asarray(res.scored.level_lcs)
+    keep = left != PAD_ID
+    return {
+        (int(a), int(b)): (float(m), tuple(int(x) for x in lv))
+        for a, b, m, lv in zip(left[keep], right[keep], mss[keep], lvl[keep])
+    }

@@ -48,10 +48,6 @@ ISSUE 10 adds the SUBTRAJECTORY axis: every backend x SHARDS x
 subtrajectory engine (itself pinned to the brute-force windowed oracle in
 ``test_subtrajectory.py``), and a re-run of the same batch must reuse the
 cached sharded runner — zero steady-state recompiles in windowed mode.
-
-All subprocess sweeps here are marked ``slow`` (tier-1 deselects them via
-pytest.ini's ``-m "not slow"``); CI runs them in a dedicated full-matrix
-step.
 """
 import os
 
@@ -129,7 +125,6 @@ print("OK", backend)
 """
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_parity_matrix(backend):
     out = run_subprocess(
@@ -169,7 +164,6 @@ print("OK", len(calls))
 """
 
 
-@pytest.mark.slow
 def test_sharded_pallas_dispatch_is_real():
     """ExecutionPlan(lcs_impl=...) must route the Pallas kernel into the
     shard_map score stage — not silently fall back to the wavefront."""
@@ -214,7 +208,6 @@ print("OK", len(calls))
 """
 
 
-@pytest.mark.slow
 def test_fused_dispatch_is_real():
     """lcs_impl="fused-interpret" must route the gather-free fused kernel
     into BOTH score paths — not silently fall back to the gather+wavefront
@@ -282,7 +275,6 @@ print("OK stream matrix")
 """
 
 
-@pytest.mark.slow
 def test_streaming_parity_matrix():
     """Streaming axis of the parity matrix: SHARDS x
     {replicate, shuffle} x {wavefront, fused-interpret} micro-batched runs
@@ -365,7 +357,6 @@ print("OK stream recompile", traces, len(calls), hist[2])
 """
 
 
-@pytest.mark.slow
 def test_streaming_updates_reuse_cached_sharded_runner():
     """Real-dispatch proof for streaming: the fused kernel is traced into
     the sharded streaming runner exactly once (compilation-counting hook =
@@ -453,7 +444,6 @@ print("OK autotune overlap matrix")
 """
 
 
-@pytest.mark.slow
 def test_autotune_overlap_parity_matrix():
     """Autotune + overlap axis: non-default tuned kernel parameters and
     chunked hop/score overlap stay bit-identical to the untuned serial
@@ -530,7 +520,6 @@ print("OK stream autotune overlap")
 """
 
 
-@pytest.mark.slow
 def test_streaming_autotune_overlap_parity():
     """Streaming axis of the autotune + overlap matrix: tuned parameters
     plus chunked shuffle scoring stay bit-identical to the single-device
@@ -598,7 +587,6 @@ print("OK delta_join matrix")
 """
 
 
-@pytest.mark.slow
 def test_streaming_delta_join_parity_matrix():
     """delta_join axis of the parity matrix: {host, device} x
     {replicate, shuffle} x {wavefront, fused-interpret} streaming runs are
@@ -653,7 +641,6 @@ print("OK device join dispatch", len(calls))
 """
 
 
-@pytest.mark.slow
 def test_device_join_never_calls_bucket_index():
     """Real-dispatch proof for delta_join="device": the join state lives
     in-mesh — BucketIndex.insert (the driver-side join) is never invoked,
@@ -739,10 +726,14 @@ for impl in IMPLS:
             assert score_map(res) == score_map(want), cell
             if n_shards > 1:
                 # steady state: a same-shape re-run keeps the ONE sticky
-                # plan and compiles no mesh program in windowed mode
+                # mesh plan (beside it sits the communities edge buffer's
+                # ("similar_edges", N) capacity), changes no sticky entry
+                # and compiles no mesh program in windowed mode
                 plans = dict(eng._sticky)
                 res2 = eng.run(batch)
-                assert len(eng._sticky) == 1 and eng._sticky == plans, cell
+                mesh_plans = [k for k in eng._sticky
+                              if k[0] != "similar_edges"]
+                assert len(mesh_plans) == 1 and eng._sticky == plans, cell
                 assert not {"plan", "join", "score", "results"} & set(
                     res2.stats["compiles_by_phase"]), cell
                 assert score_map(res2) == score_map(res), cell
@@ -750,7 +741,6 @@ print("OK subtraj", backend)
 """
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_subtraj_parity_matrix(backend):
     """Subtrajectory axis of the parity matrix: SHARDS x

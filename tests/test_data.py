@@ -1,11 +1,10 @@
-"""Data plane: synthetic generator stats, GeoLife surrogate (incl. the GPS
-round-trip through stay-point detection), SSH token dedup recall."""
+"""Data plane: synthetic generator stats and the GeoLife surrogate (incl.
+the GPS round-trip through stay-point detection)."""
 import numpy as np
 
 from repro.core.types import PAD_PLACE
 from repro.data.geolife import _stay_points, geolife_surrogate
 from repro.data.synthetic import synthetic_setup, synthetic_trajectories
-from repro.data.tokens import TokenDataset, ssh_dedup, synthetic_corpus, vocab_forest
 
 
 def test_synthetic_matches_paper_spec():
@@ -63,36 +62,3 @@ def test_geolife_gps_roundtrip():
     batch, forest = geolife_surrogate(num_users=5, num_traj=64, seed=1, fast=False)
     lengths = np.asarray(batch.lengths)
     assert (lengths > 0).all()
-
-
-def test_vocab_forest_consistency():
-    f = vocab_forest(32_000)
-    maps = f.level_maps()
-    assert len(maps) == 3
-    np.testing.assert_array_equal(f.parents[0][maps[1]], maps[0])
-
-
-def test_ssh_dedup_recall():
-    corpus, dup_source = synthetic_corpus(
-        256, 257, 32_000, dup_fraction=0.2, edit_prob=0.05, seed=0
-    )
-    keep, stats = ssh_dedup(corpus, vocab_size=32_000)
-    planted = dup_source >= 0
-    # near-dupes overwhelmingly detected; originals overwhelmingly kept
-    dup_dropped = (~keep[planted]).mean()
-    orig_kept = keep[~planted].mean()
-    assert dup_dropped > 0.9, dup_dropped
-    assert orig_kept > 0.95, orig_kept
-
-
-def test_token_dataset_deterministic():
-    corpus, _ = synthetic_corpus(64, 33, 1000, seed=0)
-    ds1 = TokenDataset(corpus, global_batch=8, seed=3)
-    ds2 = TokenDataset(corpus, global_batch=8, seed=3)
-    b1, b2 = ds1.batch(17), ds2.batch(17)
-    np.testing.assert_array_equal(np.asarray(b1["tokens"]), np.asarray(b2["tokens"]))
-    # sharded batches partition the global batch
-    shard0 = TokenDataset(corpus, global_batch=8, n_shards=2, shard=0, seed=3).batch(17)
-    shard1 = TokenDataset(corpus, global_batch=8, n_shards=2, shard=1, seed=3).batch(17)
-    both = np.concatenate([np.asarray(shard0["tokens"]), np.asarray(shard1["tokens"])])
-    np.testing.assert_array_equal(both, np.asarray(b1["tokens"]))

@@ -75,56 +75,3 @@ class TestMinhash:
                               num_perm=P, block_b=256))
         want = np.asarray(ref(jnp.asarray(types), jnp.asarray(lengths), num_perm=P))
         np.testing.assert_array_equal(got, want)
-
-
-class TestFlashAttention:
-    @pytest.mark.parametrize(
-        "B,Sq,H,KH,D,causal",
-        [(2, 128, 4, 2, 64, True), (1, 256, 8, 8, 32, True),
-         (2, 128, 4, 1, 64, False), (3, 64, 6, 2, 128, True)],
-    )
-    def test_sweep(self, B, Sq, H, KH, D, causal):
-        from repro.kernels.attention.ops import flash_attention
-        from repro.kernels.attention.ref import attention as ref
-
-        rng = np.random.default_rng(B * Sq + H)
-        q = jnp.asarray(rng.normal(size=(B, Sq, H, D)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(B, Sq, KH, D)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(B, Sq, KH, D)).astype(np.float32))
-        got = np.asarray(flash_attention(q, k, v, causal=causal, blk_q=64, blk_k=64))
-        want = np.asarray(ref(q, k, v, causal=causal))
-        np.testing.assert_allclose(got, want, atol=3e-5)
-
-    def test_bf16(self):
-        from repro.kernels.attention.ops import flash_attention
-        from repro.kernels.attention.ref import attention as ref
-
-        rng = np.random.default_rng(7)
-        q = jnp.asarray(rng.normal(size=(2, 128, 4, 64)), jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(2, 128, 2, 64)), jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(2, 128, 2, 64)), jnp.bfloat16)
-        got = np.asarray(flash_attention(q, k, v, blk_q=64, blk_k=64), np.float32)
-        want = np.asarray(ref(q, k, v), np.float32)
-        np.testing.assert_allclose(got, want, atol=3e-2)
-
-
-class TestSSD:
-    @pytest.mark.parametrize(
-        "B,S,H,P,N,chunk",
-        [(2, 64, 4, 32, 16, 16), (1, 128, 8, 64, 32, 32), (2, 96, 2, 16, 8, 48)],
-    )
-    def test_sweep(self, B, S, H, P, N, chunk):
-        from repro.kernels.ssd.ops import ssd_chunked as kern
-        from repro.kernels.ssd.ref import ssd_chunked as ref
-
-        rng = np.random.default_rng(S + H)
-        x = jnp.asarray(rng.normal(size=(B, S, H, P)).astype(np.float32))
-        dt = jnp.asarray(rng.uniform(0.001, 0.1, size=(B, S, H)).astype(np.float32))
-        A = jnp.asarray(-rng.uniform(0.5, 4.0, size=(H,)).astype(np.float32))
-        B_ = jnp.asarray(rng.normal(size=(B, S, 1, N)).astype(np.float32))
-        C_ = jnp.asarray(rng.normal(size=(B, S, 1, N)).astype(np.float32))
-        D = jnp.asarray(rng.normal(size=(H,)).astype(np.float32))
-        y1, s1 = kern(x, dt, A, B_, C_, D, chunk=chunk)
-        y2, s2 = ref(x, dt, A, B_, C_, D, chunk=chunk)
-        np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-4)
-        np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-4)

@@ -1,10 +1,6 @@
 """Launch plane: production mesh construction (512 virtual devices,
-subprocess), HLO collective parsing, input/cache specs, dry-run cell
-enumeration and skip rules."""
-import jax
-import jax.numpy as jnp
-import numpy as np
-
+subprocess), HLO collective parsing, roofline arithmetic, and what
+importing the dry-run module leaves behind."""
 from conftest import run_subprocess
 
 
@@ -53,47 +49,6 @@ print("OK", cb["total_bytes"])
     assert "OK" in run_subprocess(code, devices=8)
 
 
-def test_input_specs_all_cells():
-    from repro.configs import SHAPES, all_archs, get_config, shape_applicable
-    from repro.launch.inputs import input_specs, train_input_shapes
-
-    for arch in all_archs():
-        cfg = get_config(arch)
-        for sname, shape in SHAPES.items():
-            ok, _ = shape_applicable(cfg, shape)
-            if not ok or shape.kind == "decode":
-                continue
-            specs = input_specs(cfg, shape)
-            for name, sds in specs.items():
-                assert sds.shape[0] == shape.global_batch, (arch, sname, name)
-
-
-def test_cache_specs_families():
-    from repro.configs import get_config
-    from repro.serve.kvcache import cache_shapes, cache_bytes
-
-    gqa = cache_shapes(get_config("granite-3-8b"), 4, 128)
-    assert set(gqa) == {"pos", "k", "v"}
-    mla = cache_shapes(get_config("deepseek-v2-236b"), 4, 128)
-    assert set(mla) == {"pos", "c_kv", "k_rope"}
-    ssm = cache_shapes(get_config("mamba2-1.3b"), 4, 128)
-    assert set(ssm) == {"pos", "conv_x", "conv_bc", "ssm"}
-    hyb = cache_shapes(get_config("zamba2-2.7b"), 4, 128)
-    assert set(hyb) == {"pos", "conv_x", "conv_bc", "ssm", "sk", "sv"}
-    # MLA latent cache is dramatically smaller than full GQA KV would be
-    ds = get_config("deepseek-v2-236b")
-    full_kv_bytes = 2 * ds.num_layers * 4 * 128 * ds.num_heads * (ds.qk_nope_head_dim + ds.qk_rope_head_dim) * 2
-    assert cache_bytes(ds, 4, 128) < full_kv_bytes / 10
-
-
-def test_ssm_cache_constant_in_context():
-    from repro.configs import get_config
-    from repro.serve.kvcache import cache_bytes
-
-    m = get_config("mamba2-1.3b")
-    assert cache_bytes(m, 1, 32_768) == cache_bytes(m, 1, 524_288)
-
-
 def test_roofline_math():
     from repro.launch.hlo_analysis import Roofline
 
@@ -108,3 +63,22 @@ def test_roofline_math():
     assert 0 < r.mfu < 1
     d = r.as_dict()
     assert d["dominant"] == "compute"
+
+
+DRYRUN_IMPORT_CODE = r"""
+import os, sys
+before = os.environ["XLA_FLAGS"]
+import repro.launch.dryrun
+assert os.environ["XLA_FLAGS"] == before, os.environ["XLA_FLAGS"]
+gone = ("repro.models", "repro.train", "repro.serve", "repro.configs")
+loaded = [m for m in sys.modules if m.split(".")[:2] in
+          [g.split(".") for g in gone]]
+assert not loaded, loaded
+print("OK")
+"""
+
+
+def test_dryrun_import_leaves_xla_flags_alone():
+    """Importing ``repro.launch.dryrun`` (for ``compile_mesh_stages``) keeps
+    the caller's ``XLA_FLAGS``: only the CLI sets its 512-device count."""
+    assert "OK" in run_subprocess(DRYRUN_IMPORT_CODE, devices=8)
